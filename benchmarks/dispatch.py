@@ -108,7 +108,7 @@ def _peak_rss_subprocess(mode: str, size: int) -> float:
 import os, resource, sys
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
-jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
 import heat_tpu as ht
 x = ht.zeros(({size}, {size}), split=0)
@@ -144,16 +144,13 @@ def _peak_rss_resplit(shape, budget_bytes, mode: str) -> dict:
 import json, os, resource, sys
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
-jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
 import heat_tpu as ht
 from heat_tpu.utils import profiler
 shape, budget, mode = {tuple(shape)!r}, {int(budget_bytes)}, {mode!r}
 x = ht.zeros(shape, split=0)
 x += 1.0  # touch every page
-# completion fence WITHOUT materialization: profiler.sync would device_get
-# the sharded array — a host-side full copy (~1 GB on this mesh) that has
-# nothing to do with the transfer being measured
 jax.block_until_ready(x._parray)
 base_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
 profiler.reset_counters()
@@ -213,7 +210,7 @@ import json, os, statistics, sys, time
 os.environ.pop("HEAT_TPU_GRAD_BUCKET_BYTES", None)  # arms pin their own plans
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
-jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
 import numpy as np
 import heat_tpu as ht
@@ -381,7 +378,7 @@ def main(argv=None) -> int:
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
     import jax
 
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import jax.numpy as jnp
 

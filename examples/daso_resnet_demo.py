@@ -4,23 +4,18 @@ The reference's DASO baseline trains ResNet-50/ImageNet with node-local NCCL
 sync every step + async global MPI parameter averaging every k steps
 (``heat/optim/dp_optimizer.py::DASO``).  The TPU-native equivalent runs the
 same schedule over a ('dcn', 'ici') mesh.  This demo uses a small ResNet on
-synthetic image data so it runs anywhere (8 virtual CPU devices by default).
+synthetic image data so it runs anywhere: on the live JAX backend, like the
+other examples (``JAX_PLATFORMS=cpu`` gives 8 virtual CPU devices).
 
 Run: python examples/daso_resnet_demo.py
 """
 
 import os
+import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
-
-# default to the virtual CPU mesh; set HEAT_TPU_DEMO_DEVICE=tpu to run on TPU
-if os.environ.get("HEAT_TPU_DEMO_DEVICE", "cpu") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
-import sys
-
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))

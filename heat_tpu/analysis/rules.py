@@ -1070,8 +1070,7 @@ class UnledgeredDeviceBufferRule(Rule):
     - a ``device_put`` whose placement argument lexically mentions mesh
       sharding machinery (``NamedSharding``/``comm.sharding(...)``) —
       a mesh buffer minted outside the choke points.  ``device_put`` onto
-      a plain *device* (the hosted-complex transport commit) is not a
-      mesh buffer and is not flagged.
+      a plain *device* is not a mesh buffer and is not flagged.
 
     An enclosing function that itself registers the buffer with the
     ledger (``memledger.register(...)`` / ``_MEMLEDGER.register(...)``)
@@ -1090,7 +1089,6 @@ class UnledgeredDeviceBufferRule(Rule):
         "core/io.py",
         "core/redistribution.py",
         "core/_operations.py",
-        "core/_complexsafe.py",  # host-backend commit — not a mesh buffer
         "utils/memledger.py",
     )
     SHARDING_MARKERS = {"sharding", "NamedSharding", "PositionalSharding"}

@@ -246,9 +246,10 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             return self
 
         jx = x._jarray
-        if x.split is not None and x.comm.is_distributed():
-            # global-path fits on a distributed non-row split: pallas_call
-            # has no SPMD rule and would gather X — keep the jnp program
+        if x.comm.is_distributed():
+            # global-path fits on a multi-device mesh: a Mosaic kernel cannot
+            # be auto-partitioned (jax refuses to lower it inside a jit over
+            # more than one device) — keep the jnp program
             use_kernel = False
         centers, labels, inertia, n_iter = self._fit_program(use_kernel)(
             jx, centers0, jnp.asarray(self.max_iter), jnp.asarray(self.tol, centers0.dtype)
@@ -271,12 +272,10 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         from ..core.sanitation import sanitize_in
 
         sanitize_in(x)
-        use_kernel = getattr(self, "_kernel_enabled", False) and not (
-            # pallas_call has no SPMD partitioning rule: on a distributed
-            # split array it would gather X onto every device — the jnp
-            # path stays GSPMD-partitioned
-            x.split is not None and x.comm.is_distributed()
-        )
+        # a Mosaic kernel cannot be auto-partitioned: on a multi-device mesh
+        # the jnp path stays GSPMD-partitioned
+        use_kernel = (getattr(self, "_kernel_enabled", False)
+                      and not x.comm.is_distributed())
         if use_kernel:
             from ..ops.kmeans_kernels import fused_assign
 

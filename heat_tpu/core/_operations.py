@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import _cache, _complexsafe, sanitation, types
+from . import _cache, sanitation, types
 from .dndarray import DNDarray
 from .stride_tricks import broadcast_shape, sanitize_axis
 
@@ -105,15 +105,10 @@ def _sig(j) -> Tuple:
 
 def _cacheable(*js) -> bool:
     """True when every array may go through a cached mesh-sharded program:
-    concrete (not a tracer — traced dispatch belongs to the surrounding jit)
-    and not a hosted-complex array (which must stay OFF the mesh)."""
+    concrete (not a tracer — traced dispatch belongs to the surrounding jit)."""
     for j in js:
         if isinstance(j, jax.core.Tracer) or not isinstance(j, jax.Array):
             return False
-    if not _complexsafe.native_complex_supported():  # lru-cached, cheap
-        for j in js:
-            if _complexsafe.is_complex(j):
-                return False
     return True
 
 
@@ -305,8 +300,8 @@ def _binary_op(
     # ONE dict lookup replaces the whole dispatch prologue: the plan keyed
     # on (op, operand descriptors, donate) pre-resolved broadcasting, split
     # alignment and the result metadata, and holds the compiled executable.
-    # Ineligible signatures (pads, mismatched splits, hosted complex,
-    # tracers) are negative-cached as _SLOW and take the general path below.
+    # Ineligible signatures (pads, mismatched splits, tracers) are
+    # negative-cached as _SLOW and take the general path below.
     if out is None and where is None and not fn_kwargs and not _FORCE_SLOW and _stable_op(op):
         d1 = isinstance(t1, DNDarray)
         proto = t1 if d1 else t2 if isinstance(t2, DNDarray) else None
@@ -421,7 +416,6 @@ def _binary_op(
         ):
             pj1 = a1._parray if d1 else a1
             pj2 = a2._parray if d2 else a2
-            pj1, pj2 = _complexsafe.colocate(pj1, pj2) if (d1 and d2) else (pj1, pj2)
             phys = op(pj1, pj2, **fn_kwargs)
             ret = DNDarray(
                 phys,
@@ -436,7 +430,6 @@ def _binary_op(
 
     j1 = a1._jarray if isinstance(a1, DNDarray) else a1
     j2 = a2._jarray if isinstance(a2, DNDarray) else a2
-    j1, j2 = _complexsafe.colocate(j1, j2)
     result = op(j1, j2, **fn_kwargs)
     if res_split is not None and res_split >= result.ndim:
         res_split = None
@@ -445,16 +438,13 @@ def _binary_op(
     if out is not None:
         if where is not None:
             w = where._jarray if isinstance(where, DNDarray) else jnp.asarray(where)
-            w, result = _complexsafe.colocate(w, result)
-            ob, result = _complexsafe.colocate(out._jarray, result)
-            result = jnp.where(w, result, ob)
+            result = jnp.where(w, result, out._jarray)
             result = comm.shard(result, res_split)
         sanitation.sanitize_out(out, result.shape, res_split, device)
         out._jarray = result.astype(out.dtype.jax_dtype())
         return out if _CHECKS is None else _CHECKS(out, "dispatch.binary.out")
     if where is not None:
         w = where._jarray if isinstance(where, DNDarray) else jnp.asarray(where)
-        w, result = _complexsafe.colocate(w, result)
         result = comm.shard(jnp.where(w, result, jnp.zeros_like(result)), res_split)
     ret = DNDarray(
         result,
@@ -480,7 +470,7 @@ _FORCE_SLOW = False
 
 def _plan_desc(t, comm):
     """Plan-cache key for one operand, or None when the operand can't key a
-    plan (tracer, hosted complex, foreign comm, numpy/list coercions)."""
+    plan (tracer, foreign comm, numpy/list coercions)."""
     if isinstance(t, DNDarray):
         if t.comm is not comm:
             return None
@@ -506,10 +496,6 @@ def _plan_binary(op, t1, t2, donate, comm):
     j2 = t2._jarray if d2 else t2
     if not _cacheable(*(j for j, d in ((j1, d1), (j2, d2)) if d)):
         return _SLOW
-    if not _complexsafe.native_complex_supported() and any(
-        isinstance(s, complex) for s in (j1, j2) if not isinstance(s, jax.Array)
-    ):
-        return _SLOW  # hosted-complex mode: scalar-complex ops stay eager
     sh1 = t1.shape if d1 else ()
     sh2 = t2.shape if d2 else ()
     s1 = t1.split if d1 else None

@@ -361,8 +361,6 @@ def _daso_avg_program(comm, mesh, sig, n_leaves: int, d: int, i: int):
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from .communication import _jax_shard_map
-
         def body(*leaves):
             outs = []
             for g in leaves:
@@ -386,7 +384,7 @@ def _daso_avg_program(comm, mesh, sig, n_leaves: int, d: int, i: int):
                 outs.append(full.reshape(g.shape))
             return tuple(outs)
 
-        fn = _jax_shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P("dcn"),) * n_leaves,
@@ -533,8 +531,6 @@ def _grad_mean_program(comm, sig, n_leaves: int, p: int, d: int):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from .communication import _jax_shard_map
-
         def body(*leaves):
             outs = []
             for g in leaves:
@@ -542,7 +538,7 @@ def _grad_mean_program(comm, sig, n_leaves: int, p: int, d: int):
                 outs.append(_hierarchical_body(g[0], axis, p, d, mean=True))
             return tuple(outs)
 
-        fn = _jax_shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis),) * n_leaves,

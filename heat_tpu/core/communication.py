@@ -120,28 +120,10 @@ def _array_from_callback(host: "np.ndarray", sh: NamedSharding) -> jax.Array:
     """Global array from host data, one slice per addressable device.
 
     The explicit dtype matters on sub-meshes that leave this process with
-    ZERO addressable shards (inference has no data there), but the kwarg is
-    newer than some supported jax versions — fall back to inference, which
-    is correct whenever at least one shard is local."""
-    try:
-        return jax.make_array_from_callback(
-            host.shape, sh, lambda idx: host[idx], dtype=host.dtype
-        )
-    except TypeError:
-        return jax.make_array_from_callback(host.shape, sh, lambda idx: host[idx])
-
-
-def _jax_shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: the public entry point (with
-    ``check_vma``) when present, else the pre-0.5 experimental one (where
-    the same knob is named ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
+    ZERO addressable shards (inference has no data there)."""
+    return jax.make_array_from_callback(
+        host.shape, sh, lambda idx: host[idx], dtype=host.dtype
+    )
 
 
 class Communication:
@@ -381,11 +363,6 @@ class Communication:
         computation-follows-data propagation and ``split`` remains *logical*
         metadata (SURVEY §7, hard part #1 — padding-free best-effort design).
         """
-        from ._complexsafe import guard
-
-        hosted = guard(array)
-        if hosted is not None:
-            return hosted  # complex on a transport without native complex
         if split is not None:
             split = split % array.ndim if array.ndim else None
         if split is not None and (
@@ -418,18 +395,6 @@ class Communication:
         ``DNDarray.gshape``; the pad region is dead data masked at reduction
         boundaries.  Returns the padded, sharded physical array.
         """
-        from ._complexsafe import guard
-
-        hosted = guard(array)
-        if hosted is not None:
-            # complex on a transport without native complex: stays host-side,
-            # pad for shape consistency but skip device placement
-            n = hosted.shape[split]
-            pad = self.padded_extent(n) - n
-            if pad:
-                widths = [(0, pad if i == split else 0) for i in range(hosted.ndim)]
-                hosted = jnp.pad(hosted, widths)
-            return hosted
         split = split % array.ndim
         n = array.shape[split]
         pad = self.padded_extent(n) - n
@@ -493,7 +458,7 @@ class Communication:
         can free the source as soon as the all-to-all has consumed it, so
         peak memory stays at ~one copy instead of two.  The caller must not
         use ``array`` afterwards.  Donation falls back to the plain path
-        for tracers, hosted-complex arrays, ragged extents and
+        for tracers, ragged extents and
         multi-process meshes (where placement goes through host assembly
         anyway) — counted under ``comm.resplit.donate_fallbacks`` when the
         running jax lacks the ``donate`` kwarg, so a peak-memory regression
@@ -674,12 +639,8 @@ class Communication:
 
     def _donatable(self, array, split: Optional[int]) -> bool:
         """True when the donating reshard program may be used for ``array``."""
-        from ._complexsafe import guard
-
         if isinstance(array, jax.core.Tracer) or not isinstance(array, jax.Array):
             return False
-        if guard(array) is not None:
-            return False  # hosted complex: stays off the mesh
         if self.n_processes > 1:
             return False  # placement goes through host assembly (see shard())
         if split is not None and (
@@ -1038,7 +999,7 @@ class Communication:
 
         in_specs = jax.tree.map(to_spec, in_splits, is_leaf=is_leaf)
         out_specs = jax.tree.map(to_spec, out_splits, is_leaf=is_leaf)
-        return _jax_shard_map(
+        return jax.shard_map(
             fn, mesh=self.__mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
         )
 

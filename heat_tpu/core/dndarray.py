@@ -166,11 +166,6 @@ class DNDarray:
                 return array
         except Exception:
             pass
-        from ._complexsafe import guard
-
-        hosted = guard(array)
-        if hosted is not None:
-            return hosted  # complex on a non-native transport: keep host-side
         return jax.device_put(array, sh)
 
     # ------------------------------------------------------------------ #
@@ -390,14 +385,8 @@ class DNDarray:
     # basic conversions
     # ------------------------------------------------------------------ #
     def astype(self, dtype, copy: bool = True) -> "DNDarray":
-        from . import _complexsafe
-
         dtype = types.canonical_heat_type(dtype)
-        jdt = dtype.jax_dtype()
-        src = self.__array
-        if jnp.issubdtype(jdt, jnp.complexfloating) and not _complexsafe.native_complex_supported():
-            src = _complexsafe.to_host_backend(src)
-        casted = src.astype(jdt)
+        casted = self.__array.astype(dtype.jax_dtype())
         # honor JAX canonicalization (64→32-bit when x64 is off) in metadata
         dtype = types.canonical_heat_type(casted.dtype)
         if copy:

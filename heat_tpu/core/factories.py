@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import _complexsafe, devices, sanitation, types
+from . import devices, sanitation, types
 from .communication import Communication, sanitize_comm
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis, sanitize_shape
@@ -110,13 +110,9 @@ def array(
         npa = np.asarray(obj)
         if npa.dtype == object:
             raise TypeError("invalid data of type object")
-        with _complexsafe.creation_ctx(npa.dtype):
-            jarr = jnp.asarray(npa)
+        jarr = jnp.asarray(npa)
     if dtype is not None:
-        jdt = types.canonical_heat_type(dtype).jax_dtype()
-        if jnp.issubdtype(jdt, jnp.complexfloating) and not _complexsafe.native_complex_supported():
-            jarr = _complexsafe.to_host_backend(jarr)
-        jarr = jarr.astype(jdt)
+        jarr = jarr.astype(types.canonical_heat_type(dtype).jax_dtype())
     while jarr.ndim < ndmin:
         jarr = jarr[jnp.newaxis]
     eff_split = split if split is not None else is_split
@@ -133,17 +129,13 @@ def _filled(shape, value, dtype, split, device, comm, like=None) -> DNDarray:
     comm_s = sanitize_comm(comm)
     split_s = sanitize_axis(shape, split)
     jdt = dtype.jax_dtype()
-    if jnp.issubdtype(jdt, jnp.complexfloating) and not _complexsafe.native_complex_supported():
-        with _complexsafe.creation_ctx(jdt):
-            jarr = jnp.full(shape, value, dtype=jdt)
-    else:
-        sharding = comm_s.sharding(len(shape), split_s)
-        # jnp.full with out_sharding materializes each shard on its own device —
-        # no host round-trip, no full replica (TPU-friendly for huge arrays)
-        try:
-            jarr = jnp.full(shape, value, dtype=jdt, out_sharding=sharding)
-        except (TypeError, ValueError):
-            jarr = comm_s.shard(jnp.full(shape, value, dtype=jdt), split_s)
+    sharding = comm_s.sharding(len(shape), split_s)
+    # jnp.full with out_sharding materializes each shard on its own device —
+    # no host round-trip, no full replica (TPU-friendly for huge arrays)
+    try:
+        jarr = jnp.full(shape, value, dtype=jdt, out_sharding=sharding)
+    except (TypeError, ValueError):
+        jarr = comm_s.shard(jnp.full(shape, value, dtype=jdt), split_s)
     ret = DNDarray(jarr, shape, dtype, split_s, devices.sanitize_device(device), comm_s, True)
     if _MEMLEDGER is not None:
         _MEMLEDGER.register(ret._parray, op=None, site="factory")

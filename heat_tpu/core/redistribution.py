@@ -44,8 +44,8 @@ once at import; suffixes K/M/G accepted).  ``None``/``0`` means unbounded
 tile — best effort, recorded as the plan's ``reason``.
 
 Transitions that cannot tile fall back to K=1 monolithic, recorded in
-``ResplitPlan.reason``: tracers (nothing concrete to stream), hosted-complex
-arrays, ragged source/destination extents (their placement is XLA's, not the
+``ResplitPlan.reason``: tracers (nothing concrete to stream), ragged
+source/destination extents (their placement is XLA's, not the
 canonical sharding tiles are built from), 0-d/1-d arrays and 2-d k→j (no
 non-split axis to tile along — the general basis-change decompositions of
 arXiv 2112.01075 §5 are future work), and arrays whose total size already
@@ -238,7 +238,7 @@ def plan_resplit(
 # ---------------------------------------------------------------------- #
 def make_plan(comm, array, dst_split: Optional[int], memory_budget=None) -> Optional[ResplitPlan]:
     """Plan the redistribution of a CONCRETE array, or None when the tiled
-    pipeline cannot apply (tracer, hosted complex, non-canonical current
+    pipeline cannot apply (tracer, non-canonical current
     placement) — the caller then takes the monolithic path unconditionally.
 
     ``memory_budget=None`` resolves to the process default
@@ -254,10 +254,6 @@ def make_plan(comm, array, dst_split: Optional[int], memory_budget=None) -> Opti
         return None
     if isinstance(array, jax.core.Tracer) or not isinstance(array, jax.Array):
         return None
-    from . import _complexsafe
-
-    if _complexsafe.guard(array) is not None:
-        return None  # hosted complex: stays off the mesh
     ndim = array.ndim
     src_split = comm.split_of(array)
     # the per-tile slice programs assume the source carries exactly the

@@ -256,10 +256,7 @@ def validate_metadata(x, where: str = "") -> DNDarray:
         and pad == 0
         and gshape[split] % comm.size == 0
     ):
-        from . import _complexsafe
-
-        if _complexsafe.guard(arr) is None:  # hosted-complex stays off-mesh
-            check_placement(arr, comm, split, where=where)
+        check_placement(arr, comm, split, where=where)
     return x
 
 
@@ -279,9 +276,9 @@ def check(x, where: str = "") -> DNDarray:
 def check_placement(array, comm, split: Optional[int], where: str = ""):
     """Raise unless a concrete array carries the canonical sharding of
     ``split`` over ``comm`` (resplit-boundary hook target,
-    ``communication._RESPLIT_CHECK``).  Tracers, ragged extents and hosted-
-    complex arrays are skipped — their placement is legitimately not the
-    canonical one.  Returns ``array``."""
+    ``communication._RESPLIT_CHECK``).  Tracers and ragged extents are
+    skipped — their placement is legitimately not the canonical one.
+    Returns ``array``."""
     if _is_tracer(array) or not isinstance(array, jax.Array):
         return array
     ndim = array.ndim
@@ -289,10 +286,6 @@ def check_placement(array, comm, split: Optional[int], where: str = ""):
         split = split % ndim if ndim else None
     if split is not None and (ndim == 0 or array.shape[split] % comm.size != 0):
         return array  # ragged: split stays logical
-    from . import _complexsafe
-
-    if _complexsafe.guard(array) is not None:
-        return array
     want = comm.sharding(ndim, split)
     cur = getattr(array, "sharding", None)
     if cur == want:

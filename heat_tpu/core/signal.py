@@ -115,18 +115,12 @@ def convolve(a: DNDarray, v: DNDarray, mode: str = "full", stride: int = 1) -> D
     # needs all of it (reference: Bcast of v)
     jv = (v.resplit(None) if v.split is not None else v)._jarray.astype(work_dt.jax_dtype())
 
-    from . import _complexsafe
-
     comm = a.comm
     c_blk = comm.padded_extent(n) // comm.size if comm.size else n
-    is_hosted_complex = jnp.issubdtype(
-        work_dt.jax_dtype(), jnp.complexfloating
-    ) and not _complexsafe.native_complex_supported()
     use_halo = (
         a.split == 0
         and comm.is_distributed()
         and m - 1 <= c_blk  # halo must fit in one neighbor block
-        and not is_hosted_complex  # host-resident complex cannot ride shard_map
     )
 
     if use_halo:

@@ -37,31 +37,15 @@ def _wrap(jarr, split, proto: DNDarray) -> DNDarray:
     )
 
 
-def _fft_in(x: DNDarray):
-    """FFT compute involves complex intermediates for every transform; on
-    transports without native complex the whole transform runs on the host
-    backend (real results migrate back at the next placement)."""
-    from ..core import _complexsafe
-
-    if _complexsafe.native_complex_supported():
-        return x._jarray
-    return _complexsafe.to_host_backend(x._jarray)
-
-
 # eager routing counters (tests assert the transpose method engages)
 fft_paths = {"transpose": 0, "direct": 0}
 
 
 def _transpose_axis(x: DNDarray, busy_axes) -> Optional[int]:
     """A reshard target for the explicit transpose method — the shared
-    ``manipulations.reshard_axis_for`` rule, plus FFT's extra gates: the
-    transform must actually hit the split axis, and hosted-complex mode is
-    excluded (host arrays have no mesh placement to preserve)."""
+    ``manipulations.reshard_axis_for`` rule, plus FFT's extra gate: the
+    transform must actually hit the split axis."""
     if x.split not in busy_axes:
-        return None
-    from ..core import _complexsafe
-
-    if not _complexsafe.native_complex_supported():
         return None
     from ..core.manipulations import reshard_axis_for
 
@@ -84,7 +68,7 @@ def _fft_op(op_name: str, x: DNDarray, n=None, axis=-1, norm=None) -> DNDarray:
         res = op(xr._jarray, n=n, axis=axis, norm=norm)
         return resplit(_wrap(res, t, x), x.split)
     fft_paths["direct"] += 1
-    res = op(_fft_in(x), n=n, axis=axis, norm=norm)
+    res = op(x._jarray, n=n, axis=axis, norm=norm)
     return _wrap(res, x.split, x)
 
 
@@ -108,7 +92,7 @@ def _fftn_op(op_name: str, x: DNDarray, s=None, axes=None, norm=None) -> DNDarra
         res = op(xr._jarray, s=s, axes=axes, norm=norm)
         return resplit(_wrap(res, t, x), x.split)
     fft_paths["direct"] += 1
-    res = op(_fft_in(x), s=s, axes=axes, norm=norm)
+    res = op(x._jarray, s=s, axes=axes, norm=norm)
     return _wrap(res, x.split, x)
 
 
@@ -198,7 +182,7 @@ def _hfftn_op(x: DNDarray, s, axes, norm, inverse: bool) -> DNDarray:
         xr = resplit(x, t)
         return resplit(_wrap(run(xr._jarray), t, x), x.split)
     fft_paths["direct"] += 1
-    return _wrap(run(_fft_in(x)), x.split, x)
+    return _wrap(run(x._jarray), x.split, x)
 
 
 def hfft2(x, s=None, axes=(-2, -1), norm=None) -> DNDarray:

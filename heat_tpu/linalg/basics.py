@@ -109,11 +109,11 @@ def _matmul_result_split(sa: Optional[int], sb: Optional[int], nd_out: int) -> O
 # 1.14x.  r4d's recorded 0.708 at 2048 was a one-shot ordering artifact —
 # the pair is at parity there.  p=4 cpu mesh (same methodology): 1.20 at
 # 1024, 1.01 at 2048/4096 — GSPMD wins or ties everywhere, so no entry
-# (ties go to GSPMD, the fused default).  No TPU entry: multi-chip
-# hardware is not measurable in this environment, and GSPMD's
-# collective-matmul fusion is the principled TPU default; bench.py
-# re-measures the pair every round, and `scripts/bench_compare.py` flags
-# drift.
+# (ties go to GSPMD, the fused default).  No TPU entry: the pair has
+# not been measured on chips (ROADMAP S6), and GSPMD's collective-matmul
+# fusion is the principled TPU default.  These are CPU-mesh timings from
+# before PR 1; PR 21 took the CPU-subprocess row that re-measured them
+# out of bench.py, which is a device benchmark.
 _SUMMA_DISPATCH = {("cpu", 8): 4096}
 
 
@@ -194,8 +194,8 @@ def matmul_summa(a: DNDarray, b: DNDarray) -> DNDarray:
     GSPMD wins below ~4096 (1.32× at 1024, 1.04-1.14× at 2048), SUMMA wins
     ~1.14× at 4096.  ``ht.matmul`` now auto-dispatches per the measured
     table (``_SUMMA_DISPATCH``); this entry point remains for forcing the
-    ring path and for the per-round bench re-measurement
-    (``BENCH summa_vs_gspmd``).
+    ring path and for re-measuring the pair (on chips: not measured,
+    ROADMAP S6).
     """
     sanitize_in(a)
     sanitize_in(b)

@@ -251,7 +251,6 @@ class DataParallel:
         from jax.sharding import PartitionSpec as P
 
         from ..core import collectives as _coll
-        from ..core.communication import _jax_shard_map
 
         apply = self.module.apply
         opt = self.optimizer
@@ -289,7 +288,7 @@ class DataParallel:
         )
         # params NOT donated here — the update program reads them again
         grad_prog = jax.jit(
-            _jax_shard_map(
+            jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs,
                 out_specs=(P(ax), P(ax)), check_vma=False,
             )

@@ -19,10 +19,16 @@ Numerics match ``_dense_attention`` (same online-softmax recurrence the
 ring uses), including fully-masked rows (0, not NaN) and the top-left
 aligned causal convention (torch ``is_causal``).
 
-Dispatch: Pallas on TPU, interpreter on CPU at test scale, dense-jnp
-fallback everywhere else — the same auto/gate/fallback scheme as
-``kmeans_kernels`` (``cluster.KMeans.assign_kernel``), so importing this
-module never requires a TPU.
+Dispatch (``_pallas_gate``): the Pallas kernel on TPU, its interpreter on
+CPU at test scale, the dense jnp form where the gate says the kernel does
+not apply (other platforms, interpreter past test scale, blocks past the
+VMEM budget).  A kernel the gate selected either runs or raises — there is
+no fallback from a failed kernel to the dense form.
+
+The per-row logsumexp travels between the forward and the backward sweeps
+as a ``(B, 1, S)`` array blocked ``(1, 1, blk)``: Mosaic requires the last
+two block dims to be multiples of (8, 128) or the full extent, which a
+``(B, S)`` array blocked ``(1, blk)`` violates as soon as B > 1.
 """
 
 from __future__ import annotations
@@ -32,21 +38,17 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:  # pragma: no cover - import guard mirrors kmeans_kernels
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from ..core.devices import get_default_mesh, platform_of
 
 __all__ = ["flash_attention", "flash_attention_block", "flash_attention_gqa"]
 
-# 512x512 measured best-in-family on v5e at (B,H,S,d)=(4,8,4096,64) causal
-# bf16: ~2.1 ms/iter slope-timed vs ~5.2 at 256x256 and ~9.5 for the dense
-# XLA path (the (S,S) HBM materialization) — a ~4.5x kernel win.  Blocks are
-# always rounded to a 128 multiple (Mosaic lane alignment).
+# 512x512 was the best of the block sizes hand-timed on a v5e before PR 1;
+# on the current code: not measured.  Blocks are always rounded to a 128
+# multiple (Mosaic lane alignment).
 _BLK_Q = 512
 _BLK_K = 512
 
@@ -54,8 +56,9 @@ _BLK_K = 512
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
-# eager engagement counter, same contract as ring_attention.path_counts:
-# tests assert which implementation a given call took
+# engagement counter, same contract as ring_attention.path_counts: tests and
+# chip_smoke.py assert which implementation a call took (counted per call,
+# at trace time under an outer jit)
 path_counts = {"pallas": 0, "dense": 0}
 
 
@@ -126,7 +129,7 @@ def _finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
     lse = jnp.where(
         jnp.isfinite(m_scr[:, 0]), m_scr[:, 0], 0.0
     ) + jnp.log(jnp.maximum(l_scr[:, 0], 1e-30))
-    lse_ref[0] = jnp.where(l_scr[:, 0] > 0.0, lse, -1e30)
+    lse_ref[0, 0] = jnp.where(l_scr[:, 0] > 0.0, lse, -1e30)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -277,14 +280,14 @@ def _flash_pos_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     @pl.when(live)
     def _():
         p = _recompute_p_pos(
-            q_ref[0], k_ref[0], lse_ref[0], qpos_col=qpos, kpos_row=kpos,
+            q_ref[0], k_ref[0], lse_ref[0, 0], qpos_col=qpos, kpos_row=kpos,
             scale=scale, causal=causal, masked=masked, s_valid=s_valid,
         )
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dd_ref[0][:, None]) * scale
+        ds = p * (dp - dd_ref[0, 0][:, None]) * scale
         dq_scr[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -313,7 +316,7 @@ def _flash_pos_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     @pl.when(live)
     def _():
         p = _recompute_p_pos(
-            q_ref[0], k_ref[0], lse_ref[0], qpos_col=qpos, kpos_row=kpos,
+            q_ref[0], k_ref[0], lse_ref[0, 0], qpos_col=qpos, kpos_row=kpos,
             scale=scale, causal=causal, masked=masked, s_valid=s_valid,
         )
         dv_scr[:] += jax.lax.dot_general(
@@ -324,7 +327,7 @@ def _flash_pos_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dd_ref[0][:, None]) * scale
+        ds = p * (dp - dd_ref[0, 0][:, None]) * scale
         dk_scr[:] += jax.lax.dot_general(
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -354,7 +357,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     @pl.when(live)
     def _():
         p = _recompute_p(
-            q_ref[0], k_ref[0], lse_ref[0], scale=scale, causal=causal,
+            q_ref[0], k_ref[0], lse_ref[0, 0], scale=scale, causal=causal,
             masked=masked, s_valid=s_valid, q_lo=q_lo, k_lo=k_lo,
             blk_q=blk_q, blk_k=blk_k,
         )
@@ -362,7 +365,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dd_ref[0][:, None]) * scale
+        ds = p * (dp - dd_ref[0, 0][:, None]) * scale
         dq_scr[:] += jax.lax.dot_general(  # dSᵢⱼ · Kⱼ
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -399,7 +402,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     @pl.when(live)
     def _():
         p = _recompute_p(
-            q_ref[0], k_ref[0], lse_ref[0], scale=scale, causal=causal,
+            q_ref[0], k_ref[0], lse_ref[0, 0], scale=scale, causal=causal,
             masked=masked, s_valid=s_valid, q_lo=q_lo, k_lo=k_lo,
             blk_q=blk_q, blk_k=blk_k,
         )
@@ -411,7 +414,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dd_ref[0][:, None]) * scale
+        ds = p * (dp - dd_ref[0, 0][:, None]) * scale
         dk_scr[:] += jax.lax.dot_general(  # dSᵀ · Qᵢ  (blk_k, d)
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -427,39 +430,81 @@ def _blocks(Sp: int):
     return _blocks_rect(Sp, Sp)
 
 
-def _run_flash_padded(flat_ops, S: int, blk: int, call, dense_fallback):
+def _row_dot(a, b):
+    """Σ_d aᵢ ⊙ bᵢ in f32 as a ``(B, 1, S)`` row carrier."""
+    return jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32),
+                   axis=-1)[:, None, :]
+
+
+def _kernel_mesh(q):
+    """Mesh to ``shard_map`` a Mosaic kernel on ``q`` over, or None to call
+    it directly.  Mosaic kernels cannot be auto-partitioned: inside a jit
+    over more than one device jax refuses to lower them unless every mesh
+    axis is manual.  So on the TPU a call that is not already inside a
+    ``shard_map`` runs the kernel per shard of the leading (batch·heads)
+    axis — over ``q``'s own mesh when it is concrete, over the default mesh
+    when it is a tracer (same convention as ``platform_of``).  The CPU
+    interpreter has no such limit and is called directly."""
+    if platform_of(q) != "tpu":
+        return None
+    if isinstance(q, jax.core.Tracer):
+        if jax.sharding.get_abstract_mesh().manual_axes:
+            return None
+        mesh = get_default_mesh()
+    else:
+        mesh = getattr(getattr(q, "sharding", None), "mesh", None)
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def _per_shard(call, mesh, n_batched: int):
+    """``call`` run per shard of its first ``n_batched`` operands' leading
+    axis, split over every axis of ``mesh``; later operands are replicated
+    and every output is sharded on its leading axis like the inputs."""
+    rows = P(mesh.axis_names)
+
+    def wrapped(*ops):
+        specs = tuple(rows if i < n_batched else P() for i in range(len(ops)))
+        return jax.shard_map(call, mesh=mesh, in_specs=specs, out_specs=rows,
+                             check_vma=False)(*ops)
+
+    return wrapped
+
+
+def _run_flash_padded(flat_ops, S: int, blk: int, call):
     """THE kernel-dispatch tail shared by the flash entry points: pad the
-    sequence axis of the flattened (B, S, d) operands to a block multiple,
-    run ``call`` (falling back to ``dense_fallback`` if the kernel path
-    raises), keep the path counters, and slice the pad rows back off.
-    ``dense_fallback`` must NOT touch the counters — this helper does."""
-    Sp = -(-S // blk) * blk
-    if Sp != S:
-        pad = ((0, 0), (0, Sp - S), (0, 0))
-        flat_ops = tuple(jnp.pad(t, pad) for t in flat_ops)
-    try:
-        out = call(*flat_ops)
-    except Exception:
-        path_counts["dense"] += 1
-        return dense_fallback()
+    sequence axis of the flattened (B, S, d) operands to a block multiple
+    and — when the kernel runs per mesh shard (``_kernel_mesh``) — the
+    leading axis to a multiple of the mesh size, run ``call`` (a kernel
+    failure propagates), count the path, and slice the pad back off."""
+    B, Bk = flat_ops[0].shape[0], flat_ops[1].shape[0]
+    Sp = _round_up(S, blk)
+    mesh = _kernel_mesh(flat_ops[0])
+    if mesh is not None:
+        call = _per_shard(call, mesh, len(flat_ops))
+    Bkp = _round_up(Bk, mesh.size) if mesh is not None else Bk
+
+    def padded(t):
+        # Q carries B // Bk rows per K/V row (> 1 under GQA): pad in step
+        rows = t.shape[0] // Bk * Bkp
+        if rows == t.shape[0] and Sp == S:
+            return t
+        return jnp.pad(t, ((0, rows - t.shape[0]), (0, Sp - S), (0, 0)))
+
+    out = call(*(padded(t) for t in flat_ops))
     path_counts["pallas"] += 1
-    if Sp != S:
-        out = out[:, :S]
-    return out
+    return out if out.shape[:2] == (B, S) else out[:B, :S]
 
 
-def _pallas_gate(S: int, d: int):
+def _pallas_gate(q, S: int, d: int):
     """THE kernel-dispatch gate, shared by every flash entry point so the
-    platform policy and VMEM budget cannot drift between them.  CPU runs
-    the interpreter (slow): test scale only, like the kmeans kernels'
+    platform policy and VMEM budget cannot drift between them.  The
+    platform is that of ``q``'s devices (``platform_of``).  CPU runs the
+    interpreter (slow): test scale only, like the kmeans kernels'
     16384-row gate.  The VMEM estimate covers Q/K/V/O blocks + scores +
-    accumulator in f32 (conservative, as in kmeans_kernels; Mosaic
-    failures under an outer jit cannot be caught at call time, so oversize
-    shapes bail here).  Returns ``(use_pallas, blk, platform)``."""
-    platform = jax.devices()[0].platform
-    use_pallas = _HAS_PALLAS and (
-        platform == "tpu" or (platform == "cpu" and S <= 512)
-    )
+    accumulator in f32; shapes past it take the dense form.  Returns
+    ``(use_pallas, blk, platform)``."""
+    platform = platform_of(q)
+    use_pallas = platform == "tpu" or (platform == "cpu" and S <= 512)
     blk = min(_BLK_Q, _BLK_K, _round_up(S, 128))
     if use_pallas:
         vmem = 4 * (3 * blk * d + 2 * blk * d + blk * blk + 2 * blk)
@@ -495,11 +540,11 @@ def _flash_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, iq, ik: (b, iq)),
+            pl.BlockSpec((1, 1, blk_q), lambda b, iq, ik: (b, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Sp, d), q.dtype),
-            jax.ShapeDtypeStruct((B, Sp), jnp.float32),  # logsumexp
+            jax.ShapeDtypeStruct((B, 1, Sp), jnp.float32),  # logsumexp
         ],
         scratch_shapes=[
             # (blk_q, 1) not (blk_q,): TPU scratch wants >=2-D tiles
@@ -519,12 +564,13 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     B, Sp, d = q.shape
     blk_q, blk_k, nq, nk = _blocks(Sp)
     masked = causal or (Sp != s_valid)
-    # D_i = Σ_d dOᵢ ⊙ Oᵢ — one cheap fused elementwise pass, fine in XLA
-    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    # D_i = Σ_d dOᵢ ⊙ Oᵢ — one cheap fused elementwise pass, fine in XLA;
+    # (B, 1, Sp) like lse (see module docstring)
+    dd = _row_dot(do, out)
 
     qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i))
+    rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, causal=causal, s_valid=s_valid,
@@ -541,7 +587,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     # dk/dv sweep: K/V block fixed per middle grid index, Q blocks stream
     qspec2 = pl.BlockSpec((1, blk_q, d), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0))
-    rowspec2 = pl.BlockSpec((1, blk_q), lambda b, j, i: (b, i))
+    rowspec2 = pl.BlockSpec((1, 1, blk_q), lambda b, j, i: (b, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -602,7 +648,7 @@ def _flash_pos_fwd_impl(q, k, v, qpos, kpos, causal: bool, scale: float,
         _flash_pos_kernel, scale=scale, causal=causal, s_valid=s_valid,
         nk=nk, masked=masked,
     )
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=(B, nq, nk),
         in_specs=[
@@ -614,11 +660,11 @@ def _flash_pos_fwd_impl(q, k, v, qpos, kpos, causal: bool, scale: float,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, iq, ik: (b, iq)),
+            pl.BlockSpec((1, 1, blk_q), lambda b, iq, ik: (b, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Sq, d), q.dtype),
-            jax.ShapeDtypeStruct((B, Sq), jnp.float32),  # logsumexp
+            jax.ShapeDtypeStruct((B, 1, Sq), jnp.float32),  # logsumexp
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),
@@ -627,6 +673,7 @@ def _flash_pos_fwd_impl(q, k, v, qpos, kpos, causal: bool, scale: float,
         ],
         interpret=interpret,
     )(q, k, v, qpos, kpos)
+    return out, lse[:, 0, :]
 
 
 @functools.partial(
@@ -641,12 +688,12 @@ def _flash_pos_bwd_impl(q, k, v, qpos, kpos, out, lse, do, glse,
     blk_q, blk_k, nq, nk = _blocks_rect(Sq, Sk)
     # D_i = Σ_d dOᵢ ⊙ Oᵢ − g_lseᵢ: the lse cotangent folds into the same
     # row term (∂lse/∂s = p, so ds += p·g ≡ ds = p·(dp − (dd − g)))
-    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    dd = dd - glse.astype(jnp.float32)
+    dd = _row_dot(do, out) - glse.astype(jnp.float32)[:, None, :]
+    lse = lse[:, None, :]
 
     qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i))
+    rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     qpspec = pl.BlockSpec((blk_q, 1), lambda b, i, j: (i, 0))
     kpspec = pl.BlockSpec((1, blk_k), lambda b, i, j: (0, j))
     dq = pl.pallas_call(
@@ -666,7 +713,7 @@ def _flash_pos_bwd_impl(q, k, v, qpos, kpos, out, lse, do, glse,
     # dk/dv sweep: K/V block fixed per middle grid index, Q blocks stream
     qspec2 = pl.BlockSpec((1, blk_q, d), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0))
-    rowspec2 = pl.BlockSpec((1, blk_q), lambda b, j, i: (b, i))
+    rowspec2 = pl.BlockSpec((1, 1, blk_q), lambda b, j, i: (b, 0, i))
     qpspec2 = pl.BlockSpec((blk_q, 1), lambda b, j, i: (i, 0))
     kpspec2 = pl.BlockSpec((1, blk_k), lambda b, j, i: (0, j))
     dk, dv = pl.pallas_call(
@@ -722,10 +769,10 @@ _flash_pos.defvjp(_flash_pos_fwd_rule, _flash_pos_bwd_rule)
 
 def _dense_block_pos(q, k, v, q_pos, k_pos, causal: bool, scale: float,
                      s_valid: int, masked: bool):
-    """jnp reference/fallback for the positions block: same masking
+    """jnp reference for the positions block (``impl='dense'``): same masking
     convention and the same finite-lse sentinel for fully-masked rows
     (log(1e-30) ≈ −69 with a zero output row), so the cross-block merge
-    treats kernel and fallback results identically.  Differentiable via
+    treats kernel and reference results identically.  Differentiable via
     plain autodiff (the −inf rows are sanitized before the softmax)."""
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     if masked:
@@ -757,7 +804,7 @@ def flash_attention_block(q, k, v, q_pos, k_pos, *, causal: bool,
     normalized block output (q's dtype) and the per-row logsumexp (f32,
     finite even for fully-masked rows — their output row is 0).  ``impl``:
     ``'pallas'`` (TPU kernel), ``'interpret'`` (kernel under the CPU
-    interpreter, test scale), ``'dense'`` (jnp fallback).  This is ring
+    interpreter, test scale), ``'dense'`` (the jnp block).  This is ring
     attention's per-step building block; blocks over disjoint key sets
     merge exactly via ``lse = logaddexp(lse_a, lse_b)``,
     ``out = Σ out_b·exp(lse_b − lse)``.
@@ -769,8 +816,10 @@ def flash_attention_block(q, k, v, q_pos, k_pos, *, causal: bool,
     s_valid = min(int(s_valid), 2**30)
     masked = bool(causal) or bool(s_valid < 2**30)
     if impl == "dense":
+        path_counts["dense"] += 1
         return _dense_block_pos(q, k, v, q_pos, k_pos, causal, scale,
                                 s_valid, masked)
+    path_counts["pallas"] += 1
     lead = q.shape[:-2]
     B = 1
     for a in lead:
@@ -795,13 +844,21 @@ def flash_attention_block(q, k, v, q_pos, k_pos, *, causal: bool,
         vf = jnp.pad(vf, ((0, 0), (0, k_p - blk_k), (0, 0)))
         kpos = jnp.pad(kpos, (0, k_p - blk_k), constant_values=2**30)
         masked = True
-    out, lse = _flash_pos(
-        qf, kf, vf, qpos.reshape(q_p, 1), kpos.reshape(1, k_p),
-        causal, scale, s_valid, masked, impl == "interpret",
-    )
-    if q_p != blk_q:
-        out = out[:, :blk_q]
-        lse = lse[:, :blk_q]
+
+    def call(a, b, c, qp, kp):
+        return _flash_pos(a, b, c, qp, kp, causal, scale, s_valid, masked,
+                          impl == "interpret")
+
+    mesh = _kernel_mesh(q)
+    if mesh is not None:
+        Bp = _round_up(B, mesh.size)
+        qf, kf, vf = (jnp.pad(t, ((0, Bp - B), (0, 0), (0, 0)))
+                      for t in (qf, kf, vf))
+        call = _per_shard(call, mesh, 3)
+    out, lse = call(qf, kf, vf, qpos.reshape(q_p, 1), kpos.reshape(1, k_p))
+    if out.shape[:2] != (B, blk_q):
+        out = out[:B, :blk_q]
+        lse = lse[:B, :blk_q]
     return out.reshape(q.shape), lse.reshape(q.shape[:-1])
 
 
@@ -825,7 +882,7 @@ def flash_attention(q, k, v, causal: bool = False,
         scale = 1.0 / (d**0.5)
     scale = float(scale)
 
-    use_pallas, blk, platform = _pallas_gate(S, d)
+    use_pallas, blk, platform = _pallas_gate(q, S, d)
     if not use_pallas:
         path_counts["dense"] += 1
         return _dense_attention(q, k, v, causal, scale, S)
@@ -841,7 +898,6 @@ def flash_attention(q, k, v, causal: bool = False,
         (q.reshape((B, S, d)), k.reshape((B, S, d)), v.reshape((B, S, d))),
         S, blk,
         lambda a, b, c: _flash(a, b, c, causal, scale, S, platform == "cpu"),
-        lambda: _dense_attention(q, k, v, causal, scale, S),
     )
     return out.reshape(q.shape)
 
@@ -888,11 +944,11 @@ def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, iq, ik: (b, iq)),
+            pl.BlockSpec((1, 1, blk_q), lambda b, iq, ik: (b, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
-            jax.ShapeDtypeStruct((BHq, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((BHq, 1, Sp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),
@@ -914,13 +970,13 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     g = hq // hk
     blk_q, blk_k, nq, nk = _blocks(Sp)
     masked = causal or (Sp != s_valid)
-    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    dd = _row_dot(do, out)
     kvrow = functools.partial(_gqa_kv_row, hq=hq, hk=hk)
 
     # dq sweep: identical to the square kernel, K/V rows mapped per group
     qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (kvrow(b), j, 0))
-    rowspec = pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i))
+    rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, causal=causal, s_valid=s_valid,
@@ -941,7 +997,8 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
 
     qspec2 = pl.BlockSpec((1, blk_q, d), lambda b, j, i: (qrow(b, i), i % nq, 0))
     kspec2 = pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0))
-    rowspec2 = pl.BlockSpec((1, blk_q), lambda b, j, i: (qrow(b, i), i % nq))
+    rowspec2 = pl.BlockSpec((1, 1, blk_q),
+                            lambda b, j, i: (qrow(b, i), 0, i % nq))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -1020,17 +1077,14 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
     if hq == hk:
         return flash_attention(q, k, v, causal=causal, scale=scale)
 
-    def _dense_fallback():
+    use_pallas, blk, platform = _pallas_gate(q, S, d)
+    if not use_pallas:
+        path_counts["dense"] += 1
         g = hq // hk
         return _dense_attention(
             q, jnp.repeat(k, g, axis=-3), jnp.repeat(v, g, axis=-3),
             causal, scale, S,
         )
-
-    use_pallas, blk, platform = _pallas_gate(S, d)
-    if not use_pallas:
-        path_counts["dense"] += 1
-        return _dense_fallback()
 
     lead = q.shape[:-3]
     B = 1
@@ -1042,6 +1096,5 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
         S, blk,
         lambda a, b, c: _flash_gqa(a, b, c, causal, scale, S, hq, hk,
                                    platform == "cpu"),
-        _dense_fallback,
     )
     return out.reshape(q.shape)

@@ -23,13 +23,13 @@ so ``'auto'`` stays ``'jnp'`` and the kernel remains an opt-in, A-B'd by
 tuner: (1) a ``d < 128`` input forces Pallas to relayout X into the
 128-lane tiled layout — a ``128/d``× padded HBM copy per call (at
 1e8×32 bf16 that copy alone is 25.6 GiB — OOM; the `_relayout_copy_bytes`
-gate below falls back to jnp before that happens), while XLA's fused path
+gate below takes the jnp form before that happens), while XLA's fused path
 keeps X in its native packed layout; (2) at the E-step's shapes the MXU
 contraction is shallow (k=64 output, d-deep) and XLA's pipelining of the
 two fused GEMM passes beats the kernel's sequential grid.  Contrast
-``flash_attention``, where the same Pallas treatment WINS ~4.5× — the
-difference is attention's (S, S) intermediate actually disappears,
-whereas KMeans' (n, k) intermediate was already fused away by XLA.
+``flash_attention``: attention's (S, S) intermediate actually disappears
+in the kernel, whereas KMeans' (n, k) intermediate was already fused away
+by XLA.  (Hand timings from before PR 1; on the current code: not measured.)
 """
 
 from __future__ import annotations
@@ -38,14 +38,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from ..core.devices import platform_of
 
 __all__ = ["fused_assign", "fused_em_stats"]
 
@@ -168,7 +164,7 @@ def _fused_em_stats_impl(x, centers, n, interpret: bool):
         _em_stats_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM if _HAS_PALLAS and not interpret else None),
+            pl.BlockSpec(memory_space=None if interpret else pltpu.SMEM),
             pl.BlockSpec((tile, d), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
@@ -197,19 +193,16 @@ def fused_em_stats(x, centers, n=None):
     The Lloyd-iteration E+M kernel (round-4): assignment and per-cluster
     statistics in ONE grid sweep — labels never reach HBM.  Rows at index
     ≥ ``n`` (pad) contribute nothing.  Pallas on TPU, interpreter on small
-    CPU shards, jnp fallback otherwise.
+    CPU shards; the jnp form where the gates below say the kernel does not
+    apply.  A selected kernel runs or raises.
     """
     rows = x.shape[0]
     n = rows if n is None else n
-    if not _HAS_PALLAS:
-        return _jnp_em_stats(x, centers, n)
-    platform = jax.devices()[0].platform
+    platform = platform_of(x)
     if platform not in ("tpu", "cpu") or (platform == "cpu" and rows > 16384):
         return _jnp_em_stats(x, centers, n)
     # conservative VMEM budget at trace time: the accumulator + centers +
     # one tile must fit comfortably; oversize problems take the jnp path
-    # HERE because a Mosaic failure under an OUTER jit surfaces at that
-    # jit's compile, where the try below cannot catch it
     k, d = centers.shape
     tile = min(_TILE, rows)
     vmem = 4 * (2 * k * d + tile * d + 2 * tile * k)
@@ -218,10 +211,7 @@ def fused_em_stats(x, centers, n=None):
     # the narrow-d relayout copy (see module docstring) must also fit HBM
     if _relayout_copy_bytes(rows, d, x.dtype.itemsize) > 6 * 2**30:
         return _jnp_em_stats(x, centers, n)
-    try:
-        return _fused_em_stats_impl(x, centers, n, interpret=(platform == "cpu"))
-    except Exception:
-        return _jnp_em_stats(x, centers, n)
+    return _fused_em_stats_impl(x, centers, n, interpret=(platform == "cpu"))
 
 
 def _jnp_em_stats(x, centers, n):
@@ -243,17 +233,15 @@ def _jnp_assign(x, centers):
 def fused_assign(x, centers):
     """(labels, min_d2) of each row of ``x`` against ``centers``.
 
-    Pallas-fused on TPU; interpreter mode on CPU shards; jnp fallback when
-    Pallas is unavailable or the VMEM estimate says the blocks won't fit.
+    Pallas-fused on TPU; interpreter mode on CPU shards; the jnp form when
+    the VMEM estimate says the blocks won't fit.
     Ragged row counts ride the clipped final grid block — no padded copy
     of X is ever made (a concatenate would double peak HBM at the 1e8×32
     scale this kernel exists for); garbage values in the clipped tail are
     discarded with the sliced outputs.
     """
-    if not _HAS_PALLAS:
-        return _jnp_assign(x, centers)
     n = x.shape[0]
-    platform = jax.devices()[0].platform
+    platform = platform_of(x)
     if platform not in ("tpu", "cpu"):
         return _jnp_assign(x, centers)
     if platform == "cpu" and n > 16384:
@@ -265,8 +253,5 @@ def fused_assign(x, centers):
         return _jnp_assign(x, centers)  # VMEM-gated (see fused_em_stats)
     if _relayout_copy_bytes(n, d, x.dtype.itemsize) > 6 * 2**30:
         return _jnp_assign(x, centers)  # narrow-d relayout copy must fit HBM
-    try:
-        labels, d2 = _fused_assign_impl(x, centers, interpret=(platform == "cpu"))
-    except Exception:
-        return _jnp_assign(x, centers)
+    labels, d2 = _fused_assign_impl(x, centers, interpret=(platform == "cpu"))
     return labels[:n], d2[:n]

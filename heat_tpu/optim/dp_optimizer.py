@@ -521,9 +521,7 @@ class DASO:
             in_specs = [P("dcn"), P("dcn"), P("dcn", "ici"), P("dcn", "ici")]
             if with_keys:
                 in_specs.append(P("dcn", "ici"))
-            from ..core.communication import _jax_shard_map
-
-            return _jax_shard_map(
+            return jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=tuple(in_specs),

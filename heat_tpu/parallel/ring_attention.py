@@ -55,17 +55,13 @@ def _global_attention(q, k, v, causal, scale):
 def _block_impl(comm, kernel: str) -> str:
     """Resolve the per-ring-step attention implementation (static — baked
     into the cached ring program).  ``kernel='auto'`` uses the Pallas flash
-    kernel when the comm's devices are TPUs and falls back to the dense jnp
-    block elsewhere; ``'flash'`` forces the kernel (interpreter off-TPU —
+    kernel when the comm's devices are TPUs and the dense jnp block
+    elsewhere; ``'flash'`` forces the kernel (interpreter off-TPU —
     test scale only); ``'dense'`` forces the jnp block."""
-    from ..ops.flash_attention import _HAS_PALLAS
-
     platform = next(iter(comm.mesh.devices.flat)).platform
     if kernel == "auto":
-        return "pallas" if (_HAS_PALLAS and platform == "tpu") else "dense"
+        return "pallas" if platform == "tpu" else "dense"
     if kernel == "flash":
-        if not _HAS_PALLAS:
-            raise RuntimeError("kernel='flash' requires pallas")
         return "pallas" if platform == "tpu" else "interpret"
     if kernel == "dense":
         return "dense"
@@ -125,7 +121,7 @@ def ring_attention(q, k, v, comm, causal: bool = False, scale: Optional[float] =
     axis, size = comm.axis, comm.size
     if size == 1:
         # degenerate ring: one chip holds the whole sequence — run the
-        # flash-fused local kernel (Pallas on TPU, dense fallback elsewhere)
+        # flash-fused local kernel (Pallas on TPU, dense elsewhere)
         path_counts["global"] += 1
         if k.shape == q.shape:
             from ..ops.flash_attention import flash_attention

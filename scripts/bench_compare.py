@@ -4,15 +4,15 @@ reference tracks per-PR benchmark regressions; VERDICT r4 item 7).
     python scripts/bench_compare.py BENCH_rA.json BENCH_rB.json [--threshold 0.10]
 
 Loads two bench payloads (either the driver wrapper ``{n, cmd, rc, tail,
-parsed}`` or a direct ``{metric, value, unit, vs_baseline, extra}`` object,
-e.g. the ``BENCH_r*_manual.json`` captures), flattens every numeric row
+parsed}`` or a direct ``{metric, value, unit, vs_baseline, extra}`` object
+as ``bench.py`` prints it), flattens every numeric row
 (top-level value + ``extra`` recursively), prints a per-row delta table,
 and flags regressions beyond the threshold.  Direction (higher/lower is
 better) is inferred from the metric name; rows with unknown direction are
 reported but never flagged.  Understands the ``rows_expected`` /
-``rows_captured`` manifest (watchdog-cut captures are machine-readable)
-and prints each payload's platform/provenance so cpu-fallback artifacts
-can't masquerade as chip numbers.
+``rows_captured`` manifest (a payload whose rows failed says which) and
+prints each payload's platform/provenance so a number is never read
+without the device it came from.
 
 Exit code: 0 clean, 2 if any regression was flagged (CI-friendly), 1 on
 unusable input.
@@ -88,8 +88,6 @@ def provenance(d: dict) -> str:
     for k in ("provenance", "note"):
         if e.get(k):
             bits.append(str(e[k])[:140])
-    if e.get("watchdog_timeout"):
-        bits.append("WATCHDOG-CUT")
     return " | ".join(bits)
 
 

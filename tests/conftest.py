@@ -40,11 +40,11 @@ if _MP:
 # CI host (measured 54 s -> 31 s for test_linalg.py on a warm cache), and the
 # CI matrix re-runs the same programs across device-count/python lanes.
 # Cache entries key on topology + HLO, so lanes coexist in one directory.
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get("HEAT_TPU_JAX_CACHE", "/tmp/heat_tpu_jax_cache"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+# Only compiles over half a second are kept: the suite makes thousands of
+# tiny CPU programs that are cheaper to rebuild than to write and read back.
+from heat_tpu.utils import compile_cache
+
+compile_cache.configure(min_compile_secs=0.5)
 
 if _MP:
     # watchdog (robustness tier): a rank wedged in a collective must dump

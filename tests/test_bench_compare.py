@@ -24,12 +24,12 @@ def _payload(value, extra):
 class TestUnits:
     def test_flatten_recurses_and_skips_bools(self):
         rows = bench_compare.flatten(_payload(100.0, {
-            "mfu_bf16": 0.8, "watchdog_timeout": True,
+            "mfu_bf16": 0.8, "flag": True,
             "summa_vs_gspmd_cpu8dev": {"summa_over_gspmd": 0.7},
         }))
         assert rows["dist_matmul_16384_bf16_tflops_per_chip"] == 100.0
         assert rows["summa_vs_gspmd_cpu8dev.summa_over_gspmd"] == 0.7
-        assert "watchdog_timeout" not in rows
+        assert "flag" not in rows
 
     def test_direction(self):
         d = bench_compare.direction
@@ -93,28 +93,8 @@ class TestEndToEnd:
     def test_manifest_reported(self, tmp_path):
         a = _payload(100.0, {"rows_expected": ["headline", "flash_ab"],
                              "rows_captured": ["headline"],
-                             "platform": "tpu", "watchdog_timeout": True})
+                             "platform": "tpu"})
         b = _payload(99.0, {})
         r = self._run(tmp_path, a, b)
         assert "1/2 expected rows captured" in r.stdout
         assert "MISSING: flash_ab" in r.stdout
-        assert "WATCHDOG-CUT" in r.stdout
-
-    def test_committed_round_payloads(self):
-        """The real r4 artifacts load and compare (wrapper r03 vs manual
-        r4b), and the comparator surfaces the f32 default-precision swing
-        VERDICT r4 weak #2 is about (r4b vs r4d)."""
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", "bench_compare.py"),
-             os.path.join(REPO, "BENCH_r03.json"),
-             os.path.join(REPO, "BENCH_r4b_manual.json")],
-            capture_output=True, text=True, timeout=120)
-        assert r.returncode in (0, 2)
-        assert "dist_matmul_16384_bf16_tflops_per_chip" in r.stdout
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", "bench_compare.py"),
-             os.path.join(REPO, "BENCH_r4b_manual.json"),
-             os.path.join(REPO, "BENCH_r4d_manual.json")],
-            capture_output=True, text=True, timeout=120)
-        assert r.returncode == 2
-        assert "matmul_16384_f32_default_precision_tflops_per_chip" in r.stdout

@@ -487,10 +487,6 @@ class TestFFTTransposeMethod(TestCase):
         comm = ht.communication.get_comm()
         if not comm.is_distributed():
             pytest.skip("needs a multi-device mesh")
-        from heat_tpu.core import _complexsafe
-
-        if not _complexsafe.native_complex_supported():
-            pytest.skip("hosted-complex mode: no mesh placement to preserve")
         x = np.random.default_rng(0).standard_normal((1000, 2 * comm.size)).astype(np.float32)
         hx = ht.array(x, split=0)
         before = dict(F.fft_paths)
@@ -516,10 +512,6 @@ class TestFFTTransposeMethod(TestCase):
         comm = ht.communication.get_comm()
         if not comm.is_distributed():
             pytest.skip("needs a multi-device mesh")
-        from heat_tpu.core import _complexsafe
-
-        if not _complexsafe.native_complex_supported():
-            pytest.skip("hosted-complex mode")
         p = comm.size
         x = np.random.default_rng(2).standard_normal((8 * p, 2 * p, 6)).astype(np.float32)
         hx = ht.array(x, split=0)

@@ -1,0 +1,161 @@
+"""Every ``pallas_call`` in ``heat_tpu/ops/`` lowers for the TPU, checked from
+the CPU host through ``jax.export`` (Pallas -> Mosaic lowering, where block
+shapes are validated; no chip and no Mosaic compile needed), and a kernel
+that was selected raises instead of quietly returning the reference.
+
+The lowering half fails at the parent of PR 21: the logsumexp carrier was a
+``(B, S)`` array blocked ``(1, blk)``, which Mosaic refuses once B > 1.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+fa = importlib.import_module("heat_tpu.ops.flash_attention")
+km = importlib.import_module("heat_tpu.ops.kmeans_kernels")
+
+B, S, D = 6, 1024, 64  # batch x heads > 1; two 512-blocks per sequence
+
+
+def _lowers(fn, *avals):
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+def _vg(kernel_call):
+    """forward + both backward sweeps of one flash variant as one function"""
+    def f(q, k, v, *rest):
+        def loss(a, b, c):
+            out = kernel_call(a, b, c, *rest)
+            out = out if isinstance(out, tuple) else (out,)
+            return sum(jnp.sum(o.astype(jnp.float32)) for o in out)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return f
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+class TestFlashLowers:
+    def test_static(self, dtype):
+        q = jax.ShapeDtypeStruct((B, S, D), dtype)
+        _lowers(_vg(lambda a, b, c: fa._flash(a, b, c, True, D**-0.5, S - 24, False)),
+                q, q, q)
+
+    def test_gqa(self, dtype):
+        q = jax.ShapeDtypeStruct((B, S, D), dtype)
+        kv = jax.ShapeDtypeStruct((B // 3, S, D), dtype)
+        _lowers(_vg(lambda a, b, c: fa._flash_gqa(a, b, c, True, D**-0.5, S, 3, 1, False)),
+                q, kv, kv)
+
+    def test_positions(self, dtype):
+        q = jax.ShapeDtypeStruct((B, S, D), dtype)
+        kv = jax.ShapeDtypeStruct((B, S // 2, D), dtype)  # rectangular block
+        qpos = jax.ShapeDtypeStruct((S, 1), jnp.int32)
+        kpos = jax.ShapeDtypeStruct((1, S // 2), jnp.int32)
+        _lowers(_vg(lambda a, b, c, qp, kp: fa._flash_pos(
+            a, b, c, qp, kp, True, D**-0.5, S // 2, True, False)), q, kv, kv, qpos, kpos)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kmeans_kernels_lower(dtype):
+    x = jax.ShapeDtypeStruct((2**15, 32), dtype)
+    c = jax.ShapeDtypeStruct((64, 32), jnp.float32)
+    _lowers(lambda x, c: km._fused_em_stats_impl(x, c, x.shape[0] - 5, interpret=False), x, c)
+    _lowers(lambda x, c: km._fused_assign_impl(x, c, interpret=False), x, c)
+
+
+def _gqa_case():
+    import numpy as np
+
+    S, d = 40, 8
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, 6, S, d)), jnp.float32)  # 6 rows / 3 rows:
+    k, v = (jnp.asarray(rng.normal(size=(1, 3, S, d)), jnp.float32)  # not multiples of 8
+            for _ in range(2))
+
+    def dense(q, k, v):
+        g = q.shape[1] // k.shape[1]
+        return fa._dense_attention(q, jnp.repeat(k, g, 1), jnp.repeat(v, g, 1),
+                                   True, d**-0.5, S)
+
+    def close(got, want, what):
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                       atol=2e-5, err_msg=what)
+
+    vg = lambda f: jax.value_and_grad(lambda *o: jnp.sum(f(*o) ** 2), argnums=(0, 1, 2))
+    return q, k, v, dense, close, vg
+
+
+def test_per_shard_rows_pad_in_step(monkeypatch):
+    """On more than one chip the kernels run per shard of the batch·heads
+    axis (``_kernel_mesh``: a Mosaic kernel cannot be auto-partitioned).
+    The dispatch tail alone, over the CPU mesh, with a jnp stand-in for the
+    kernel that uses the kernel's row mapping (query row b reads K/V row
+    b // g, per shard): Q and K/V rows pad in step and the output comes
+    back in order and unpadded (forward only; gradients in the slow test)."""
+    from heat_tpu.core.devices import get_default_mesh
+
+    q, k, v, dense, close, vg = _gqa_case()
+    mesh = get_default_mesh()
+    monkeypatch.setattr(fa, "_kernel_mesh", lambda q: mesh)
+
+    def stand_in(qf, kf, vf, causal, scale, s_valid, hq, hk, interpret):
+        assert qf.shape[0] == (hq // hk) * kf.shape[0]  # whole groups per shard
+        grouped = qf.reshape(kf.shape[0], hq // hk, *qf.shape[1:])
+        out = fa._dense_attention(grouped, kf[:, None], vf[:, None], causal, scale, s_valid)
+        return out.reshape(qf.shape)
+
+    monkeypatch.setattr(fa, "_flash_gqa", stand_in)
+    close(fa.flash_attention_gqa(q, k, v, causal=True), dense(q, k, v), "gqa dispatch tail")
+
+
+@pytest.mark.slow
+def test_per_shard_kernels_match_dense(monkeypatch):
+    """The same, with the real kernels in interpret mode: GQA forward and
+    backward, and the positions block."""
+    from heat_tpu.core.devices import get_default_mesh
+
+    q, k, v, dense, close, vg = _gqa_case()
+    mesh = get_default_mesh()
+    monkeypatch.setattr(fa, "_kernel_mesh", lambda q: mesh)
+    close(vg(lambda a, b, c: fa.flash_attention_gqa(a, b, c, causal=True))(q, k, v),
+          vg(dense)(q, k, v), "gqa")
+    S, d = q.shape[-2:]
+    pos = jnp.arange(S, dtype=jnp.int32)
+    out, lse = fa.flash_attention_block(q, q[:, ::-1], q * 0.5, pos, pos, causal=True,
+                                        scale=d**-0.5, s_valid=S, impl="interpret")
+    assert lse.shape == q.shape[:-1]
+    close(out, dense(q, q[:, ::-1], q * 0.5), "block")
+
+
+class TestNoQuietFallback:
+    """On the platform the gate selected the kernel for, a kernel failure is
+    the caller's failure: no ``except`` turns it into the dense/jnp result."""
+
+    @staticmethod
+    def _boom(*a, **k):
+        raise RuntimeError("kernel refused")
+
+    def test_flash_raises(self, monkeypatch):
+        q = jnp.ones((2, 2, 64, 8), jnp.float32)
+        monkeypatch.setattr(fa, "_flash", self._boom)
+        monkeypatch.setattr(fa, "_flash_gqa", self._boom)
+        dense = fa.path_counts["dense"]
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            fa.flash_attention(q, q, q, causal=True)
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            fa.flash_attention_gqa(q, q[:, :1], q[:, :1], causal=True)
+        assert fa.path_counts["dense"] == dense
+
+    def test_kmeans_raises(self, monkeypatch):
+        x, c = jnp.ones((256, 8), jnp.float32), jnp.ones((4, 8), jnp.float32)
+        monkeypatch.setattr(km, "_fused_em_stats_impl", self._boom)
+        monkeypatch.setattr(km, "_fused_assign_impl", self._boom)
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            km.fused_em_stats(x, c)
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            km.fused_assign(x, c)
