@@ -1,0 +1,8 @@
+"""``matmul_ms``: median of the benchmark's ``ht.matmul`` span, which ends in
+``block_until_ready``, over the traced jobs.  Layer: comm."""
+
+from chipbench.harness import trace as tr
+
+
+def read(ctx):
+    return tr.span_ms(ctx.trace, "ht.matmul")
