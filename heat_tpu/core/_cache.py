@@ -23,12 +23,37 @@ from __future__ import annotations
 import functools
 from collections import OrderedDict
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "comm_cached",
     "cached_program",
     "cache_stats",
     "reset_cache_stats",
+    "recording",
+    "launch",
 ]
+
+# ---------------------------------------------------------------------- #
+# program spans.  A span of the dispatch layer is a profiler annotation
+# named ``ht.<layer>.<what>``, made only while a profile records: the
+# profiler itself answers "is anyone listening" (one C call, no flag of
+# ours), and its annotations share the device trace's clock.
+# ---------------------------------------------------------------------- #
+recording = TraceAnnotation.is_enabled
+
+LAUNCH_SPAN = "ht.dispatch.launch"
+
+
+def launch(prog, *args):
+    """Call a program that came out of :func:`cached_program` (jax's jit
+    call, which holds the runtime's launch), under ``ht.dispatch.launch``
+    while a profile records."""
+    if recording():
+        with TraceAnnotation(LAUNCH_SPAN):
+            return prog(*args)
+    return prog(*args)
+
 
 # ---------------------------------------------------------------------- #
 # global hit/miss accounting for every program table (dispatch cache +

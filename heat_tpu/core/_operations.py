@@ -15,6 +15,12 @@ signature per comm, with the output sharding compiled in as a
 or pays an eager post-op ``device_put``.  The in-place dunders additionally
 donate their left operand's buffer to the executable (``donate_argnums``),
 letting XLA alias input and output storage.
+
+Program spans: while a profile records (``_cache.recording()``, the
+profiler's own answer — no flag here), each helper runs entry to return
+under ``ht.dispatch.<kind>`` (stat ``op``) and each cached program's call
+under ``ht.dispatch.launch``; with nothing recording an eager op pays two
+``is_enabled()`` calls and one frame.  See design.md "Telemetry & metrics".
 """
 
 from __future__ import annotations
@@ -73,18 +79,24 @@ _FLIGHTREC = None
 _MEMLEDGER = None
 
 
+def _op_name(op) -> str:
+    return getattr(op, "__name__", str(op))
+
+
 def _run_prog(tel, name: str, op, prog, args, cache_hit: bool):
-    """Run a cached dispatch executable with the telemetry tail around it
-    (only reached when telemetry is armed): a leaf span named
-    ``dispatch.<kind>`` carrying the op name and cache hit/miss.  ``tel`` is
-    the caller's captured module reference — re-reading the ``_TELEMETRY``
-    global here would race a concurrent ``disable()`` into an AttributeError
-    mid-op (record_dispatch itself re-checks the enabled flag)."""
+    """Run a cached dispatch executable when anything listens: under
+    ``ht.dispatch.launch`` while a profile records (``_cache.launch``), and
+    with a ``dispatch.<kind>`` leaf record in telemetry's ring, carrying the
+    op name and cache hit/miss, when telemetry is armed.  ``tel`` is the
+    caller's captured module reference or ``None`` — re-reading the
+    ``_TELEMETRY`` global here would race a concurrent ``disable()`` into an
+    AttributeError mid-op (record_dispatch itself re-checks the enabled
+    flag)."""
+    if tel is None:
+        return _cache.launch(prog, *args)
     t0 = time.perf_counter()
-    out = prog(*args)
-    tel.record_dispatch(
-        name, t0, time.perf_counter(), getattr(op, "__name__", str(op)), cache_hit
-    )
+    out = _cache.launch(prog, *args)
+    tel.record_dispatch(name, t0, time.perf_counter(), _op_name(op), cache_hit)
     return out
 
 
@@ -185,6 +197,13 @@ def _reduce_identity(op, dtype):
 
 def _local_op(op: Callable, x: DNDarray, out: Optional[DNDarray] = None, **kwargs) -> DNDarray:
     """Elementwise op with no communication; split is preserved."""
+    if not _cache.recording():
+        return _local(op, x, out, kwargs)
+    with _cache.TraceAnnotation("ht.dispatch.local", op=_op_name(op)):
+        return _local(op, x, out, kwargs)
+
+
+def _local(op, x, out, kwargs):
     sanitation.sanitize_in(x)
     if x._pad and out is None:
         # ragged fast path: compute on the padded physical array — the pad
@@ -223,7 +242,7 @@ def _local_op(op: Callable, x: DNDarray, out: Optional[DNDarray] = None, **kwarg
             try:
                 res = (
                     prog(j)
-                    if tel is None
+                    if tel is None and not _cache.recording()
                     else _run_prog(tel, "dispatch.local", op, prog, (j,), _cache._STATS["misses"] == m0)
                 )
             except Exception as e:
@@ -231,7 +250,7 @@ def _local_op(op: Callable, x: DNDarray, out: Optional[DNDarray] = None, **kwarg
                     _MEMLEDGER.note_oom(e, "dispatch.local", None)
                 raise
             if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(getattr(op, "__name__", str(op)))
+                _FLIGHTREC.record_dispatch(_op_name(op))
             ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, x.device, comm)
             return ret if _CHECKS is None else _CHECKS(ret, "dispatch.local")
     result = op(j, **kwargs)
@@ -294,6 +313,13 @@ def _binary_op(
     fn_kwargs: Optional[dict] = None,
 ) -> DNDarray:
     """Broadcasting binary op with split reconciliation (reference __binary_op)."""
+    if not _cache.recording():
+        return _binary(op, t1, t2, out, where, fn_kwargs)
+    with _cache.TraceAnnotation("ht.dispatch.binary", op=_op_name(op)):
+        return _binary(op, t1, t2, out, where, fn_kwargs)
+
+
+def _binary(op, t1, t2, out, where, fn_kwargs):
     from . import factories
 
     # ---- planned fast path ------------------------------------------- #
@@ -333,7 +359,7 @@ def _binary_op(
                     try:
                         res = (
                             prog(*args)
-                            if tel is None
+                            if tel is None and not _cache.recording()
                             else _run_prog(
                                 tel, "dispatch.binary", op, prog, args,
                                 _cache._STATS["misses"] == m0,
@@ -344,7 +370,7 @@ def _binary_op(
                             _MEMLEDGER.note_oom(e, "dispatch.binary", None)
                         raise
                     if _FLIGHTREC is not None:
-                        _FLIGHTREC.record_dispatch(getattr(op, "__name__", str(op)))
+                        _FLIGHTREC.record_dispatch(_op_name(op))
                     if donate and _MEMLEDGER is not None and args[0].is_deleted():
                         # the donated left operand's buffer is gone — but
                         # only when the program REALLY consumed it: the plan
@@ -553,6 +579,13 @@ def _reduce_op(
     Reducing over the split axis (or all axes) yields a replicated result —
     the implicit ``Allreduce``; other axes keep the (shifted) split.
     """
+    if not _cache.recording():
+        return _reduce(op, x, axis, keepdims, out, dtype, kwargs)
+    with _cache.TraceAnnotation("ht.dispatch.reduce", op=_op_name(op)):
+        return _reduce(op, x, axis, keepdims, out, dtype, kwargs)
+
+
+def _reduce(op, x, axis, keepdims, out, dtype, kwargs):
     sanitation.sanitize_in(x)
     axis = sanitize_axis(x.shape, axis)
 
@@ -622,7 +655,7 @@ def _reduce_op(
             try:
                 res = (
                     prog(j)
-                    if tel is None
+                    if tel is None and not _cache.recording()
                     else _run_prog(tel, "dispatch.reduce", op, prog, (j,), _cache._STATS["misses"] == m0)
                 )
             except Exception as e:
@@ -630,7 +663,7 @@ def _reduce_op(
                     _MEMLEDGER.note_oom(e, "dispatch.reduce", None)
                 raise
             if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(getattr(op, "__name__", str(op)))
+                _FLIGHTREC.record_dispatch(_op_name(op))
             ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, x.device, x.comm)
             return ret if _CHECKS is None else _CHECKS(ret, "dispatch.reduce")
     result = op(j, axis=axis, keepdims=keepdims, **kwargs)
@@ -673,6 +706,13 @@ def _cum_op(
     out: Optional[DNDarray] = None,
 ) -> DNDarray:
     """Cumulative op along ``axis`` (reference __cum_op via Exscan; here XLA scan)."""
+    if not _cache.recording():
+        return _cum(op, x, axis, dtype, out)
+    with _cache.TraceAnnotation("ht.dispatch.cum", op=_op_name(op)):
+        return _cum(op, x, axis, dtype, out)
+
+
+def _cum(op, x, axis, dtype, out):
     sanitation.sanitize_in(x)
     axis = sanitize_axis(x.shape, axis)
     if axis is not None and x._pad and out is None:
@@ -705,7 +745,7 @@ def _cum_op(
             try:
                 res = (
                     prog(j)
-                    if tel is None
+                    if tel is None and not _cache.recording()
                     else _run_prog(tel, "dispatch.cum", op, prog, (j,), _cache._STATS["misses"] == m0)
                 )
             except Exception as e:
@@ -713,7 +753,7 @@ def _cum_op(
                     _MEMLEDGER.note_oom(e, "dispatch.cum", None)
                 raise
             if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(getattr(op, "__name__", str(op)))
+                _FLIGHTREC.record_dispatch(_op_name(op))
             ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, x.device, x.comm)
             return ret if _CHECKS is None else _CHECKS(ret, "dispatch.cum")
     if axis is None:

@@ -56,6 +56,7 @@ import os
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
+from ._cache import launch
 from .redistribution import parse_budget
 
 # device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
@@ -430,7 +431,7 @@ def dispatch_bucket_averages(comm, leaves, plan: GradBucketPlan, k: int, tele: _
     )
     _bucket_counters(bucket_bytes)
     prog = _daso_avg_program(comm, mesh, _daso_sig(leaves, idxs), len(idxs), d, i)
-    avgs = list(prog(*(leaves[j] for j in idxs)))
+    avgs = list(launch(prog, *(leaves[j] for j in idxs)))
     _ledger_dispatch(bucket_bytes, avgs)
     return avgs
 
@@ -443,7 +444,7 @@ def consume_bucket_averages(comm, leaves, avgs, plan: GradBucketPlan, k: int, w)
     idxs = plan.buckets[k]
     _await_bucket(avgs)
     blend = _blend_program(comm, _daso_sig(leaves, idxs), len(idxs))
-    out = blend(tuple(leaves[j] for j in idxs), tuple(avgs), w)
+    out = launch(blend, tuple(leaves[j] for j in idxs), tuple(avgs), w)
     for j, b in zip(idxs, out):
         leaves[j] = b
     _ledger_consume(avgs)
@@ -567,7 +568,7 @@ def dispatch_bucket_allreduce(comm, leaves, plan: GradBucketPlan, k: int, tele: 
     _account_stages(comm, tele, bucket_bytes / p, factors, x=leaves[idxs[0]])
     _bucket_counters(bucket_bytes)
     prog = _grad_mean_program(comm, _daso_sig(leaves, idxs), len(idxs), p, d)
-    means = list(prog(*(leaves[j] for j in idxs)))
+    means = list(launch(prog, *(leaves[j] for j in idxs)))
     _ledger_dispatch(bucket_bytes, means)
     return means
 

@@ -14,7 +14,7 @@ explicit ``Communication`` object:
 - **sharding constructors** (``sharding(ndim, split)``) translating the
   reference's ``split`` axis to a ``NamedSharding``;
 - **redistribution** (``resplit`` → ``jax.device_put`` with a new sharding,
-  lowered by XLA to all-to-all, cf. arXiv 2112.01075);
+  which the runtime does without a program on the chips: see ``resplit``);
 - **functional collectives** (``psum``/``all_gather``/``all_to_all``/
   ``ppermute``/…) for use inside ``shard_map`` — the building blocks of the
   manual-control paths (ring cdist, halo convolve, TSQR, DASO);
@@ -438,10 +438,15 @@ class Communication:
     ) -> jax.Array:
         """Redistribute a global array to a new split axis.
 
-        XLA lowers the sharding change to an all-to-all over ICI (the
-        memory-efficient reshard of arXiv 2112.01075); the reference does the
-        same thing by hand with derived datatypes + ``Alltoallv``
-        (``DNDarray.resplit_``, SURVEY §3.3).
+        The change of sharding is handed to ``jax.device_put(array,
+        sharding)``.  Across chips that is NOT lowered to an all-to-all and
+        puts no program on the chips: on a TPU v5e 2x2 the runtime moved
+        3.125 GiB in 6.7 s (0.5 GB/s) with every chip idle (PERF.md,
+        finding 4, PR 22).  A compiled identity with ``out_shardings`` is one
+        all-to-all over ICI (the memory-efficient reshard of arXiv
+        2112.01075, what the reference does by hand with derived datatypes +
+        ``Alltoallv`` in ``DNDarray.resplit_``, SURVEY §3.3); only the tiled
+        pipeline below moves its data that way today.
 
         ``memory_budget`` (bytes; ``None`` → the process default set via
         ``heat_tpu.set_redistribution_budget()`` / ``HEAT_TPU_RESPLIT_BUDGET``)
@@ -470,9 +475,11 @@ class Communication:
         a chunked transfer accounts per tile, summing to the identical
         total), plus ``comm.resplit.tiles``/``.peak_tile_bytes`` for the
         plan shape, and the eager transfer runs under a ``comm.resplit``
-        span when telemetry is enabled.  A no-op call (the array already
-        carries the target sharding) moves nothing and is NOT counted —
-        defensive resplit calls must not inflate the traffic metric.
+        span when telemetry is enabled or a profile records (there as
+        ``ht.comm.resplit``: the host inside ``device_put``).  A no-op call
+        (the array already carries the target sharding) moves nothing and is
+        NOT counted — defensive resplit calls must not inflate the traffic
+        metric.
         """
         if self._already_placed(array, split):
             return array

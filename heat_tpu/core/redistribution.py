@@ -306,7 +306,7 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
     import jax.numpy as jnp
     from jax import lax
 
-    from ._cache import cached_program
+    from ._cache import cached_program, launch
 
     ndim = array.ndim
     axis = plan.tile_axis
@@ -357,12 +357,12 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
             warnings.filterwarnings(
                 "ignore", message=".*[Dd]onated buffers were not usable.*"
             )
-            return prog(*args)
+            return launch(prog, *args)
 
     from ..utils import profiler as _prof
 
     ml = _MEMLEDGER
-    out = _program("init", 0, _build_init)()
+    out = launch(_program("init", 0, _build_init))
     if ml is not None:
         # the preallocated destination: a transient until the finished plan
         # reclassifies it (comm.resplit_tiled)
@@ -389,7 +389,7 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
         # the post-mortem report instead of tiles=0 masquerading as monolithic
         _tel.counter_inc("comm.resplit.tiles", 1)
         _prof.counter_max("comm.resplit.peak_tile_bytes", tile_bytes)
-        staged = _program("slice", length, lambda: _build_slice(length))(array, start)
+        staged = launch(_program("slice", length, lambda: _build_slice(length)), array, start)
         if ml is not None:
             ml.register(staged, op="resplit.tile", site="resplit.tile")
         if donate and i == plan.n_tiles - 1:

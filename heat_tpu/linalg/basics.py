@@ -20,7 +20,7 @@ import numpy as np
 from jax import lax
 
 from ..core import types
-from ..core import _operations
+from ..core import _cache, _operations
 from ..core._cache import cached_program, comm_cached
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
@@ -143,6 +143,13 @@ def matmul(a: DNDarray, b: DNDarray, allow_resplit: bool = False,
     ``'gspmd'`` / ``'summa'`` force a path (``'summa'`` requires the 2-D
     split0×split0 case, like :func:`matmul_summa`).
     """
+    if not _cache.recording():
+        return _matmul(a, b, method)
+    with _cache.TraceAnnotation("ht.dispatch.matmul", op="matmul"):
+        return _matmul(a, b, method)
+
+
+def _matmul(a, b, method):
     sanitize_in(a)
     sanitize_in(b)
     if method not in ("auto", "gspmd", "summa"):
@@ -150,7 +157,7 @@ def matmul(a: DNDarray, b: DNDarray, allow_resplit: bool = False,
     if method == "summa" or (method == "auto" and _summa_wins(a, b)):
         return matmul_summa(a, b)
     if a.ndim == 1 and b.ndim == 1:
-        return dot(a, b)
+        return _dot_1d(a, b)
     # result rank is a pure function of the operand ranks (vector operands
     # drop their axis; both-1-D went to dot() above, so nd >= 1), so the
     # split table resolves BEFORE dispatch and the (matmul + output
@@ -173,11 +180,10 @@ def matmul(a: DNDarray, b: DNDarray, allow_resplit: bool = False,
             lambda: _operations._build_binary(comm, jnp.matmul, ja, jb, split, False, {}),
         )
         prog, rshape, rdtype, rsplit = entry
+        res = _cache.launch(prog, ja, jb)
         if rsplit is None or comm.size <= 1 or rshape[rsplit] % comm.size == 0:
-            return DNDarray._from_parts(
-                prog(ja, jb), rshape, rdtype, rsplit, a.device, comm
-            )
-        return DNDarray(prog(ja, jb), rshape, rdtype, rsplit, a.device, comm, True)
+            return DNDarray._from_parts(res, rshape, rdtype, rsplit, a.device, comm)
+        return DNDarray(res, rshape, rdtype, rsplit, a.device, comm, True)
     return _wrap(jnp.matmul(ja, jb), split, a)
 
 
@@ -261,20 +267,29 @@ def _summa_program(comm):
     )
 
 
+def _dot_1d(a: DNDarray, b: DNDarray) -> DNDarray:
+    ja, jb = a._jarray, b._jarray
+    if not a._pad and not b._pad and _operations._cacheable(ja, jb):
+        comm = a.comm
+        prog, rshape, rdtype, rsplit = cached_program(
+            comm,
+            ("dot", _operations._sig(ja), _operations._sig(jb)),
+            lambda: _operations._build_binary(comm, jnp.dot, ja, jb, None, False, {}),
+        )
+        return DNDarray._from_parts(
+            _cache.launch(prog, ja, jb), rshape, rdtype, rsplit, a.device, comm
+        )
+    return _wrap(jnp.dot(ja, jb), None, a)
+
+
 def dot(a: DNDarray, b: DNDarray, out: Optional[DNDarray] = None) -> DNDarray:
     """Dot product: 1-D·1-D → scalar (implicit Allreduce); else matmul."""
     if a.ndim == 1 and b.ndim == 1:
-        ja, jb = a._jarray, b._jarray
-        if not a._pad and not b._pad and _operations._cacheable(ja, jb):
-            comm = a.comm
-            prog, rshape, rdtype, rsplit = cached_program(
-                comm,
-                ("dot", _operations._sig(ja), _operations._sig(jb)),
-                lambda: _operations._build_binary(comm, jnp.dot, ja, jb, None, False, {}),
-            )
-            r = DNDarray._from_parts(prog(ja, jb), rshape, rdtype, rsplit, a.device, comm)
+        if not _cache.recording():
+            r = _dot_1d(a, b)
         else:
-            r = _wrap(jnp.dot(ja, jb), None, a)
+            with _cache.TraceAnnotation("ht.dispatch.matmul", op="dot"):
+                r = _dot_1d(a, b)
         if out is not None:
             out._jarray = r._jarray
             return out

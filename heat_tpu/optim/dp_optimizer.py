@@ -267,7 +267,8 @@ class DataParallelOptimizer:
         if self._opt_state is None:
             self.init_state(params)
         if not _tel._ENABLED:
-            new_params, self._opt_state = self._update(params, grads, self._opt_state)
+            with _tel.span("optim.step"):  # the profiler's annotation while a profile records
+                new_params, self._opt_state = self._update(params, grads, self._opt_state)
             return new_params
         t0 = time.perf_counter()
         with _tel.span("optim.step"):
@@ -585,7 +586,8 @@ class DASO:
         from ..utils import telemetry as _tel
 
         if not _tel._ENABLED:
-            return self._step_impl(loss_fn, x, y, key)
+            with _tel.span("daso.step"):  # the profiler's annotation while a profile records
+                return self._step_impl(loss_fn, x, y, key)
         t0 = time.perf_counter()
         with _tel.span(
             "daso.step", step=self._step_count + 1, sync=self._sync_label()

@@ -5,10 +5,12 @@ perun only); this module is the TPU port's first-class story.  Three layers:
 
 - **Spans** — :func:`span` is a nestable context manager that records wall
   time, carries attributes (op name, shapes, split, bytes), tracks
-  *self-time* (own duration minus children), and forwards its name to
-  ``jax.profiler.TraceAnnotation`` so XProf traces inherit the runtime's
-  vocabulary.  Records land in a bounded ring buffer — telemetry memory is
-  O(ring), never O(run length).
+  *self-time* (own duration minus children), and forwards its name, as
+  ``ht.<name>``, to ``jax.profiler.TraceAnnotation`` so XProf traces inherit
+  the runtime's vocabulary.  Records land in a bounded ring buffer —
+  telemetry memory is O(ring), never O(run length).  While a profile
+  records, a span is that annotation even with telemetry disabled: the
+  profiler's own ``TraceAnnotation.is_enabled()`` arms it, no switch here.
 
 - **Counters & histograms** — byte accounting of every ``Communication``
   collective (``comm.<name>.calls`` / ``comm.<name>.bytes``, payload nbytes
@@ -22,10 +24,10 @@ perun only); this module is the TPU port's first-class story.  Three layers:
   files into one timeline/summary.  :func:`report` returns the in-process
   merged view (counters ∪ histograms ∪ top spans by self-time).
 
-**Overhead contract.**  Disabled (the default), every instrumentation site
-reduces to one module-global load — the dispatch tails in
+**Overhead contract.**  Disabled (the default), the dispatch tails in
 ``core._operations`` check a flag that :func:`enable`/:func:`disable` poke
-*into that module*, so the hot path never even calls into here.  Enabled,
+*into that module*, so the hot path never even calls into here, and a
+:func:`span` site costs one flag check and one ``is_enabled()``.  Enabled,
 a span costs two clock reads, a ring append and (optionally) a
 TraceAnnotation; the CI telemetry lane gates the enabled cost at <5% of
 dispatch overhead (``benchmarks/dispatch.py --telemetry-gate``).
@@ -103,7 +105,7 @@ _hist_lock = threading.Lock()
 _tls = threading.local()
 _flush_dir: Optional[str] = None
 _atexit_registered = False
-_trace_annotation = None  # jax.profiler.TraceAnnotation, resolved at enable()
+_trace_annotation = None  # jax.profiler.TraceAnnotation, resolved by _annotation()
 _profiler = None  # utils.profiler, resolved on first counter touch
 
 # flight-recorder hook (``utils.flightrec.enable()`` pokes the module in):
@@ -126,6 +128,17 @@ def _prof():
 
         _profiler = profiler
     return _profiler
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, resolved on first use: this module
+    stays stdlib-only at import (a standalone load must never import jax)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
 
 
 def _stack() -> list:
@@ -271,16 +284,10 @@ def _poke_dispatch_hook(on: bool) -> None:
 def enable(directory: Optional[str] = None, ring_size: Optional[int] = None) -> None:
     """Arm telemetry.  ``directory`` (or ``HEAT_TPU_TELEMETRY_DIR``) also
     registers an atexit :func:`flush` of this process's rank file."""
-    global _ENABLED, _ring, _flush_dir, _atexit_registered, _trace_annotation
+    global _ENABLED, _ring, _flush_dir, _atexit_registered
     if ring_size is not None and ring_size != _ring.maxlen:
         _ring = deque(_ring, maxlen=int(ring_size))
-    if _trace_annotation is None:
-        try:
-            import jax
-
-            _trace_annotation = jax.profiler.TraceAnnotation
-        except Exception:  # pragma: no cover - jax always present in-tree
-            _trace_annotation = None
+    _annotation()
     if directory:
         _flush_dir = directory
     elif _flush_dir is None:
@@ -408,8 +415,13 @@ def _uninstall_signal_flush() -> None:
 # ---------------------------------------------------------------------- #
 # spans
 # ---------------------------------------------------------------------- #
+# every program span reaches the profiler as ``ht.<name>``: the prefix by
+# which a trace's reader tells the program's spans from jax's own
+_PROFILE_PREFIX = "ht."
+
+
 class _NullSpan:
-    """Singleton returned by :func:`span` when telemetry is disabled."""
+    """Singleton returned by :func:`span` when nothing listens."""
 
     __slots__ = ()
 
@@ -436,11 +448,7 @@ class _Span:
         self.child = 0.0
         self.span_id = None
         self._parent_id = None
-        self._ta = (
-            _trace_annotation(name)
-            if (xprof and _trace_annotation is not None)
-            else None
-        )
+        self._ta = _annotation()(_PROFILE_PREFIX + name) if xprof else None
 
     def __enter__(self):
         stack = _stack()
@@ -494,27 +502,52 @@ class _Span:
         return self
 
 
+class _ProfileSpan:
+    """What :func:`span` returns while a profile records and telemetry is
+    disabled: the profiler's annotation ``ht.<name>`` alone, the attributes
+    its stats (fixed when it is made, so ``set`` keeps nothing)."""
+
+    __slots__ = ("_ta",)
+
+    def __init__(self, name: str, attrs: dict):
+        self._ta = _annotation()(_PROFILE_PREFIX + name, **attrs)
+
+    def __enter__(self):
+        self._ta.__enter__()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        return self._ta.__exit__(et, ev, tb)
+
+    def set(self, **attrs):
+        return self
+
+
 def span(name: str, xprof: bool = True, **attrs):
     """Record a named, attributed, nested wall-time span of the block.
 
-    No-op (a shared null object) when telemetry is disabled.  ``xprof=False``
-    skips the ``jax.profiler.TraceAnnotation`` forwarding — for sites hot
-    enough that creating the annotation object is measurable."""
-    if not _ENABLED:
-        return _NULL_SPAN
-    return _Span(name, attrs, xprof)
+    A span is on when telemetry is enabled or a profile records
+    (``jax.profiler.TraceAnnotation.is_enabled()``: no switch of ours).
+    Enabled, it lands in the ring and forwards ``ht.<name>`` to the
+    profiler; ``xprof=False`` skips the forwarding — for sites hot enough
+    that creating the annotation object is measurable.  Disabled while a
+    profile records, it is the annotation ``ht.<name>`` alone.  Otherwise
+    a shared null object."""
+    if _ENABLED:
+        return _Span(name, attrs, xprof)
+    if xprof and _annotation().is_enabled():
+        return _ProfileSpan(name, attrs)
+    return _NULL_SPAN
 
 
 def traced(name: str):
     """Decorator form of :func:`span` for whole functions (checkpoint
-    save/load entry points).  Disabled cost: one flag check."""
+    save/load entry points).  Nothing listening costs what ``span`` does."""
 
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if not _ENABLED:
-                return fn(*args, **kwargs)
-            with _Span(name, {}, True):
+            with span(name):
                 return fn(*args, **kwargs)
 
         return wrapper
