@@ -13,8 +13,9 @@ explicit ``Communication`` object:
   test oracles, matching JAX's ceil-division placement convention;
 - **sharding constructors** (``sharding(ndim, split)``) translating the
   reference's ``split`` axis to a ``NamedSharding``;
-- **redistribution** (``resplit`` → ``jax.device_put`` with a new sharding,
-  which the runtime does without a program on the chips: see ``resplit``);
+- **redistribution** (``resplit`` → one cached program per signature, a
+  jitted identity with the new sharding as ``out_shardings``, which XLA
+  lowers to an all-to-all over ICI: see ``resplit``);
 - **functional collectives** (``psum``/``all_gather``/``all_to_all``/
   ``ppermute``/…) for use inside ``shard_map`` — the building blocks of the
   manual-control paths (ring cdist, halo convolve, TSQR, DASO);
@@ -40,6 +41,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import devices
+from ._cache import cached_program, launch
 
 __all__ = [
     "Communication",
@@ -438,15 +440,30 @@ class Communication:
     ) -> jax.Array:
         """Redistribute a global array to a new split axis.
 
-        The change of sharding is handed to ``jax.device_put(array,
-        sharding)``.  Across chips that is NOT lowered to an all-to-all and
-        puts no program on the chips: on a TPU v5e 2x2 the runtime moved
-        3.125 GiB in 6.7 s (0.5 GB/s) with every chip idle (PERF.md,
-        finding 4, PR 22).  A compiled identity with ``out_shardings`` is one
-        all-to-all over ICI (the memory-efficient reshard of arXiv
-        2112.01075, what the reference does by hand with derived datatypes +
-        ``Alltoallv`` in ``DNDarray.resplit_``, SURVEY §3.3); only the tiled
-        pipeline below moves its data that way today.
+        Across chips the change of sharding is ONE compiled program: a
+        jitted identity whose ``out_shardings`` is ``self.sharding(ndim,
+        split)`` (``redistribution.identity_program``), which XLA lowers to
+        an all-to-all over ICI for k→j, an all-gather for k→None and a local
+        slice for None→k — the memory-efficient reshard of arXiv 2112.01075,
+        what the reference does by hand with derived datatypes +
+        ``Alltoallv`` in ``DNDarray.resplit_`` (SURVEY §3.3).  It is built
+        once per ``(shape, dtype, src split, dst split, donate)`` in this
+        communicator's program table (``_cache.cached_program``) and called
+        through ``_cache.launch``.  ``jax.device_put(array, sharding)`` is
+        NOT that: on a TPU v5e 2x2 the runtime moved 3.125 GiB in 6.7 s
+        (0.5 GB/s) of synchronous host work with every chip idle (PERF.md,
+        finding 4, PR 22).
+
+        Whether the program engages is read off the input, there is no
+        switch: the array is concrete, the communicator spans more than one
+        device in one process, the array carries this communicator's
+        canonical sharding of its current split, and the target extent
+        divides by ``size``.  Everything else goes through :meth:`shard`:
+        tracers (``with_sharding_constraint``), ragged targets (returned as
+        they are), multi-process meshes (host assembly), arrays that are not
+        on the mesh yet, and a one-device communicator, where the
+        ``device_put`` between two shardings of one device is cheaper than a
+        launch.
 
         ``memory_budget`` (bytes; ``None`` → the process default set via
         ``heat_tpu.set_redistribution_budget()`` / ``HEAT_TPU_RESPLIT_BUDGET``)
@@ -455,31 +472,29 @@ class Communication:
         pipeline of ``core.redistribution`` — K tiled all-to-alls along a
         non-split axis, each ≤ budget bytes, destination written in place,
         transient memory ≤ budget + one tile beyond source + destination.
-        K=1 (or no budget) degenerates to the monolithic fast path below.
+        K=1 (or no budget) degenerates to the monolithic program below.
 
-        ``donate=True`` (the in-place ``resplit_`` path) hands the source
-        buffer to the transfer (``jax.device_put(..., donate=True)``): the
-        runtime may alias input and output storage (layout permitting) and
-        can free the source as soon as the all-to-all has consumed it, so
+        ``donate=True`` (the in-place ``resplit_`` path) builds the program
+        with ``donate_argnums=(0,)``: the source buffer is handed to the
+        transfer and freed as soon as the all-to-all has consumed it, so
         peak memory stays at ~one copy instead of two.  The caller must not
-        use ``array`` afterwards.  Donation falls back to the plain path
-        for tracers, ragged extents and
-        multi-process meshes (where placement goes through host assembly
-        anyway) — counted under ``comm.resplit.donate_fallbacks`` when the
-        running jax lacks the ``donate`` kwarg, so a peak-memory regression
-        is attributable to the silently-lost donation.
+        use ``array`` afterwards.  Where the program does not engage,
+        donation frees nothing the plain path does not, and ``donate`` is
+        ignored.
 
         Telemetry: every resharding call counts under
         ``comm.resplit.calls``/``.bytes`` (the all-to-all moves (p-1)/p of
         the GLOBAL payload — the known hot spot of redistribution traffic;
         a chunked transfer accounts per tile, summing to the identical
         total), plus ``comm.resplit.tiles``/``.peak_tile_bytes`` for the
-        plan shape, and the eager transfer runs under a ``comm.resplit``
-        span when telemetry is enabled or a profile records (there as
-        ``ht.comm.resplit``: the host inside ``device_put``).  A no-op call
-        (the array already carries the target sharding) moves nothing and is
-        NOT counted — defensive resplit calls must not inflate the traffic
-        metric.
+        plan shape and ``comm.resplit.compiled`` for every call that took
+        the monolithic program, and the eager transfer runs under a
+        ``comm.resplit`` span (stat ``path``: ``"program"`` or
+        ``"device_put"``) when telemetry is enabled or a profile records
+        (there as ``ht.comm.resplit``, holding the program's
+        ``ht.dispatch.launch``).  A no-op call (the array already carries the
+        target sharding) moves nothing and is NOT counted — defensive
+        resplit calls must not inflate the traffic metric.
         """
         if self._already_placed(array, split):
             return array
@@ -488,11 +503,12 @@ class Communication:
         plan = _redist.make_plan(self, array, split, memory_budget)
         if plan is not None and plan.n_tiles > 1:
             return self.resplit_tiled(array, split, donate=donate, _plan=plan)
+        prog, src_split = self._resplit_program(array, split, donate)
         self._account(
             "resplit",
             array,
             (self.size - 1) / self.size,
-            src_split=self.split_of(array) if not isinstance(array, jax.core.Tracer) else None,
+            src_split=src_split,
             dst_split=split,
         )
         tel = _telemetry()
@@ -504,6 +520,7 @@ class Communication:
             donate=donate,
             nbytes=nbytes,
             tiles=1,
+            path="device_put" if prog is None else "program",
         ):
             ml = _MEMLEDGER
             src_cat = ml.category_of(array) if ml is not None else None
@@ -512,28 +529,18 @@ class Communication:
                     # the mem.alloc fault site: chaos CI injects a
                     # deterministic allocation failure ahead of the transfer
                     ml.alloc_check(nbytes, "comm.resplit")
-                if donate and self._donatable(array, split):
-                    # no already-placed test here: _already_placed() at the
-                    # top returned for every case a donatable array could hit
-                    sh = self.sharding(array.ndim, split)
-                    donated = False
-                    try:
-                        out = jax.device_put(array, sh, donate=True)
-                        donated = True
-                    except TypeError:  # jax without the donate kwarg
-                        self._note_donate_fallback()
-                        out = jax.device_put(array, sh)
-                    if ml is not None and donated:
-                        # consumed only AFTER a successful donating transfer:
-                        # a RESOURCE_EXHAUSTED out of the device_put must
-                        # still find the in-flight source in the OOM dump
-                        # (it is typically the dominant buffer), and the
-                        # donate-less ancient-jax fallback keeps the source
-                        # alive for real.  Metadata-only id lookup, not a
-                        # buffer read.
-                        ml.consume(array)  # heatlint: disable=HT103 — ledger id-lookup decrement, no storage read
-                else:
+                if prog is None:
                     out = self.shard(array, split)
+                else:
+                    out = (_redist.launch_quiet if donate else launch)(prog, array)
+                    tel.counter_inc("comm.resplit.compiled", 1)
+                    if ml is not None and donate:
+                        # consumed only AFTER a successful donating transfer:
+                        # a RESOURCE_EXHAUSTED out of the program must still
+                        # find the in-flight source in the OOM dump (it is
+                        # typically the dominant buffer).  Metadata-only id
+                        # lookup, not a buffer read.
+                        ml.consume(array)
             except Exception as e:
                 if ml is not None:
                     ml.note_oom(e, "comm.resplit", nbytes)
@@ -608,28 +615,6 @@ class Communication:
                 _RESPLIT_CHECK(out, self, split, where="comm.resplit_tiled")
             return out
 
-    # one-time-per-process warning flag for the lost-donation fallback
-    _DONATE_FALLBACK_WARNED = False
-
-    def _note_donate_fallback(self) -> None:
-        """The running jax's ``device_put`` lacks ``donate=`` — the in-place
-        resplit silently degraded to a copying transfer.  Counted under
-        ``comm.resplit.donate_fallbacks`` (every occurrence) and warned once
-        per process, so a peak-memory regression on an old jax is
-        attributable instead of invisible."""
-        from ..utils import profiler as _profiler
-
-        _profiler.counter_inc("comm.resplit.donate_fallbacks")
-        if not Communication._DONATE_FALLBACK_WARNED:
-            Communication._DONATE_FALLBACK_WARNED = True
-            warnings.warn(
-                "jax.device_put does not support donate=: in-place resplit "
-                "falls back to a copying transfer (peak memory ~2x the "
-                "array). Upgrade jax to recover donation; occurrences are "
-                "counted under comm.resplit.donate_fallbacks.",
-                stacklevel=4,
-            )
-
     def _already_placed(self, array, split: Optional[int]) -> bool:
         """True when ``array`` is concrete and already carries exactly the
         canonical sharding of ``split`` — a resplit of it moves no bytes
@@ -644,17 +629,39 @@ class Communication:
             return False  # ragged: placement is XLA's, not the canonical one
         return getattr(array, "sharding", None) == self.sharding(array.ndim, split)
 
-    def _donatable(self, array, split: Optional[int]) -> bool:
-        """True when the donating reshard program may be used for ``array``."""
+    def _resplit_program(self, array, split: Optional[int], donate: bool):
+        """``(program, src_split)`` of the monolithic reshard of ``array`` to
+        ``split``; ``program`` is None where :meth:`shard` moves it instead.
+        Decided by what the input shows (see :meth:`resplit`): concrete, more
+        than one device in one process, on this communicator's canonical
+        sharding, target not ragged."""
         if isinstance(array, jax.core.Tracer) or not isinstance(array, jax.Array):
-            return False
-        if self.n_processes > 1:
-            return False  # placement goes through host assembly (see shard())
-        if split is not None and (
-            array.ndim == 0 or array.shape[split % array.ndim] % self.size != 0
-        ):
-            return False  # ragged: split stays logical, no canonical target
-        return True
+            return None, None
+        src_split = self.split_of(array)
+        if self.size == 1 or self.n_processes > 1:
+            return None, src_split  # one device: shard()'s device_put is cheaper
+        ndim = array.ndim
+        if split is not None:
+            if ndim == 0 or array.shape[split % ndim] % self.size != 0:
+                return None, src_split  # ragged: split stays logical
+            split = split % ndim
+        from . import redistribution as _redist
+
+        if not _redist.on_mesh(self, array, src_split):
+            return None, src_split
+        # keyed on the signature, never the array: a job's fresh result of
+        # the same shape must be a hit
+        key = (
+            "resplit", "mono", tuple(array.shape), str(array.dtype),
+            src_split, split, bool(donate),
+        )
+        dst_sh = self.sharding(ndim, split)
+        return (
+            cached_program(
+                self, key, lambda: _redist.identity_program(dst_sh, donate)
+            ),
+            src_split,
+        )
 
     # ------------------------------------------------------------------ #
     # functional collectives — valid ONLY inside shard_map over this mesh.

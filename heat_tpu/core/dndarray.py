@@ -12,7 +12,8 @@ communicator's mesh realizes the ``split`` axis:
   (``PartitionSpec(..., 'x', ...)``)
 
 All inter-chip data movement is emitted by XLA when ops require it; the
-explicit ``resplit_`` maps to a resharding ``device_put`` (→ all-to-all).
+explicit ``resplit_`` maps to ``Communication.resplit``'s reshard program
+(→ all-to-all).
 
 DNDarray is registered as a JAX pytree (the array is the leaf; split/device/
 comm are static aux data), so user functions over DNDarrays can be ``jax.jit``

@@ -2,9 +2,10 @@
 
 Redistribution (``DNDarray.resplit_`` → ``Communication.resplit``) is the
 reference framework's signature data movement (SURVEY §3.3).  The monolithic
-realization — one ``device_put`` to the target sharding, lowered by XLA to a
-single all-to-all — materializes source and destination WHOLE: peak memory is
-~2× the array plus collective staging, and donation recovers almost nothing
+realization — one :func:`identity_program` with the target sharding, lowered
+by XLA to a single all-to-all — materializes source and destination WHOLE:
+peak memory is ~2× the array plus collective staging, and donation recovers
+almost nothing
 because the transfer itself holds both copies (``BENCH_DISPATCH.json``:
 in-place resplit peaked at 751 MB vs 774 MB for the copy path).  Following
 "Memory-efficient array redistribution through portable collective
@@ -236,6 +237,52 @@ def plan_resplit(
 # ---------------------------------------------------------------------- #
 # eligibility + execution (jax-touching half)
 # ---------------------------------------------------------------------- #
+def on_mesh(comm, array, src_split: Optional[int]) -> bool:
+    """True when the concrete ``array`` carries ``comm``'s canonical sharding
+    of ``src_split`` (its own ``comm.split_of(array)``) — the placement every
+    reshard program (monolithic or per tile) is built from.  Anything else
+    (XLA's opportunistic ragged placement, sub-meshes, a single default
+    device) is moved by ``Communication.shard``'s ``device_put``."""
+    cur = getattr(array, "sharding", None)
+    want = comm.sharding(array.ndim, src_split)
+    if cur == want:
+        return True
+    try:
+        return cur is not None and cur.is_equivalent_to(want, array.ndim)
+    except Exception:
+        return False
+
+
+def identity_program(dst_sh, donate: bool):
+    """THE reshard program of the comm layer: a jitted identity whose
+    ``out_shardings`` differ from its input's, which XLA lowers to the
+    collective the transition needs (all-to-all k→j, all-gather k→None, a
+    local slice None→k).  ``donate`` hands the input buffer to the program,
+    so it is freed as soon as the transfer has consumed it."""
+    import jax
+
+    return jax.jit(
+        lambda t: t, out_shardings=dst_sh, donate_argnums=(0,) if donate else ()
+    )
+
+
+def launch_quiet(prog, *args):
+    """``_cache.launch`` of a DONATING program whose donated inputs cannot
+    ALIAS their (differently-shaped) outputs — the donation is for the early
+    free, which still happens; jax's compile-time "donated buffers were not
+    usable" warning is expected noise, filtered at the call (= first-compile)
+    site only."""
+    import warnings
+
+    from ._cache import launch
+
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message=".*[Dd]onated buffers were not usable.*"
+        )
+        return launch(prog, *args)
+
+
 def make_plan(comm, array, dst_split: Optional[int], memory_budget=None) -> Optional[ResplitPlan]:
     """Plan the redistribution of a CONCRETE array, or None when the tiled
     pipeline cannot apply (tracer, non-canonical current
@@ -254,19 +301,11 @@ def make_plan(comm, array, dst_split: Optional[int], memory_budget=None) -> Opti
         return None
     if isinstance(array, jax.core.Tracer) or not isinstance(array, jax.Array):
         return None
-    ndim = array.ndim
-    src_split = comm.split_of(array)
     # the per-tile slice programs assume the source carries exactly the
-    # canonical sharding of src_split; anything else (XLA's opportunistic
-    # ragged placement, sub-meshes) takes the monolithic path
-    cur = getattr(array, "sharding", None)
-    want = comm.sharding(ndim, src_split)
-    if cur != want:
-        try:
-            if cur is None or not cur.is_equivalent_to(want, ndim):
-                return None
-        except Exception:
-            return None
+    # canonical sharding of its split; anything else takes the monolithic path
+    src_split = comm.split_of(array)
+    if not on_mesh(comm, array, src_split):
+        return None
     return plan_resplit(
         array.shape, np.dtype(array.dtype).itemsize, src_split, dst_split,
         comm.size, budget,
@@ -330,10 +369,9 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
         return jax.jit(f, out_shardings=src_sh)
 
     def _build_move():
-        # identity with changed out_shardings: XLA lowers the sharding
-        # change to the tile-sized all-to-all; donation frees the staged
-        # slice as soon as the transfer has consumed it
-        return jax.jit(lambda t: t, out_shardings=dst_sh, donate_argnums=(0,))
+        # the tile-sized all-to-all; donation frees the staged slice as soon
+        # as the transfer has consumed it
+        return identity_program(dst_sh, donate=True)
 
     def _build_update():
         def f(acc, tile, start):
@@ -345,19 +383,6 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
 
     from ..utils import health as _hlth
     from ..utils import telemetry as _tel
-
-    def _quiet(prog, *args):
-        # donated tiles cannot ALIAS their (differently-shaped) outputs —
-        # the donation is for the early free, which still happens; jax's
-        # compile-time "donated buffers were not usable" warning is expected
-        # noise here, filtered at the call (= first-compile) site only
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.filterwarnings(
-                "ignore", message=".*[Dd]onated buffers were not usable.*"
-            )
-            return launch(prog, *args)
 
     from ..utils import profiler as _prof
 
@@ -401,7 +426,7 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
                 pass
             if ml is not None:
                 ml.consume(array)
-        tile = _quiet(_program("move", length, _build_move), staged)
+        tile = launch_quiet(_program("move", length, _build_move), staged)
         if ml is not None:
             # consumed only AFTER the donating program ran (the monolithic
             # path's rule): an OOM inside the move must still find the
@@ -412,7 +437,7 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
             ml.consume(staged)
             ml.register(tile, op="resplit.tile", site="resplit.tile")
         prev = out
-        out = _quiet(_program("update", length, _build_update), prev, tile, start)
+        out = launch_quiet(_program("update", length, _build_update), prev, tile, start)
         if ml is not None:
             ml.consume(tile)  # donated into (and consumed by) the update
             # the accumulator was donated and aliases in place: move the

@@ -176,7 +176,9 @@ def test_resplit_span_with_telemetry_disabled(recorded):
     assert whole[3]["split"] == 1 and whole[3]["tiles"] == 1 and whole[3]["nbytes"] == 256
     assert tiled[3]["tiles"] == 4  # 4 KiB along the free third axis, 1 KiB a tile
     launches = [e for e in recorded if e[0] == _cache.LAUNCH_SPAN]
-    assert not any(_inside(l, whole) for l in launches)
+    # the monolithic resplit is one cached program on the multi-device mesh
+    assert whole[3]["path"] == "program"
+    assert sum(_inside(l, whole) for l in launches) == 1
     # init, then slice, move and update for every tile
     assert sum(_inside(l, tiled) for l in launches) == 1 + 3 * tiled[3]["tiles"]
     assert not telemetry._ring

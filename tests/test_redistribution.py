@@ -14,10 +14,12 @@ Three layers under test:
   chunked and monolithic (telescoped per-tile accounting), ``.calls`` = K,
   ``.tiles`` = K, ``.peak_tile_bytes`` = the largest tile;
 - the robustness hooks: per-tile ``comm.collective`` fault site under an
-  armed ``comm.deadline`` (a hung tile trips ``CollectiveTimeoutError``),
-  the donate-kwarg ``TypeError`` fallback counted under
-  ``comm.resplit.donate_fallbacks`` with a one-time warning, and the budget
-  default plumbing (``set_redistribution_budget`` / env parsing).
+  armed ``comm.deadline`` (a hung tile trips ``CollectiveTimeoutError``) and
+  the budget default plumbing (``set_redistribution_budget`` / env parsing);
+- the monolithic program (ISSUE 26): across devices ``Communication.resplit``
+  launches one cached identity with ``out_shardings`` (an all-to-all for
+  k→j), counted under ``comm.resplit.compiled``; what the input shows decides
+  whether it engages.
 """
 
 import warnings
@@ -332,34 +334,6 @@ class TestRobustness:
                 time.sleep(0.1)  # blow the budget before the first tile
                 x.resplit(2, memory_budget=512)
 
-    def test_donate_fallback_counted_and_warned_once(self, monkeypatch):
-        import jax
-
-        from heat_tpu.core import communication as comm_mod
-
-        real = jax.device_put
-
-        def no_donate(x, sharding=None, **kw):
-            if kw.pop("donate", False):
-                raise TypeError("device_put() got an unexpected keyword 'donate'")
-            return real(x, sharding, **kw)
-
-        monkeypatch.setattr(comm_mod.jax, "device_put", no_donate)
-        monkeypatch.setattr(Communication, "_DONATE_FALLBACK_WARNED", False)
-        profiler.reset_counters()
-        x = _fresh((8, 8), 0)
-        want = x.resplit(1).numpy()
-        with pytest.warns(UserWarning, match="donate"):
-            x.resplit_(1)  # monolithic donate path hits the TypeError
-        np.testing.assert_array_equal(x.numpy(), want)
-        assert profiler.counters()["comm.resplit.donate_fallbacks"] == 1
-        # second occurrence: counted again, warned never again
-        y = _fresh((8, 8), 0)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            y.resplit_(1)
-        assert profiler.counters()["comm.resplit.donate_fallbacks"] == 2
-
     def test_no_warnings_on_tiled_path(self):
         # the expected "donated buffers were not usable" compile noise of the
         # per-tile programs must be filtered at the source
@@ -397,3 +371,109 @@ class TestRobustness:
         x = _fresh((8, 8), 0)
         out = f(x._jarray)
         np.testing.assert_array_equal(np.asarray(out), x.numpy())
+
+
+# ---------------------------------------------------------------------- #
+# the monolithic program: one cached identity with out_shardings
+# ---------------------------------------------------------------------- #
+def _compiled():
+    return profiler.counters().get("comm.resplit.compiled", 0)
+
+
+def _programs(comm):
+    """The monolithic reshard programs in ``comm``'s table, by key."""
+    from heat_tpu.core import _cache
+
+    table = comm._compiled_programs.get(_cache._DISPATCH_SLOT, {})
+    return {k: p for k, p in table.items() if k[:2] == ("resplit", "mono")}
+
+
+class TestMonolithicProgram:
+    SHAPE = (16, 8)
+
+    @pytest.mark.parametrize(
+        "src,dst", [(0, 1), (1, 0), (0, None), (1, None), (None, 0), (None, 1)]
+    )
+    def test_engages_across_devices(self, src, dst):
+        comm = ht.communication.get_comm()
+        if not comm.is_distributed():
+            pytest.skip("the program needs a multi-device mesh")
+        x, y = _fresh(self.SHAPE, src), _fresh(self.SHAPE, src)
+        want = x.numpy()
+        profiler.reset_counters()
+        got = comm.resplit(x._jarray, dst)
+        assert _compiled() == 1
+        assert got.sharding == comm.sharding(2, dst)
+        np.testing.assert_array_equal(np.asarray(got), want)  # bit for bit
+        # a fresh array of the same signature: a hit, nothing built
+        profiler.reset_cache_stats()
+        again = comm.resplit(y._jarray, dst)
+        assert _compiled() == 2
+        assert profiler.cache_stats() == {"hits": 1, "misses": 0, "slow": 0}
+        np.testing.assert_array_equal(np.asarray(again), want)
+        assert _counters()["comm.resplit.calls"] == 2
+
+    def test_program_is_an_all_to_all(self):
+        comm = ht.communication.get_comm()
+        if not comm.is_distributed():
+            pytest.skip("the program needs a multi-device mesh")
+        x = _fresh((32, 24), 0)
+        comm.resplit(x._jarray, 1)
+        prog = _programs(comm)[("resplit", "mono", (32, 24), "float32", 0, 1, False)]
+        assert "all-to-all" in prog.lower(x._jarray).compile().as_text()
+
+    def test_inplace_form_is_its_own_donating_program(self):
+        comm = ht.communication.get_comm()
+        if not comm.is_distributed():
+            pytest.skip("the program needs a multi-device mesh")
+        x = _fresh((32, 8), 0)
+        want, old = x.numpy(), x._jarray
+        profiler.reset_counters()
+        with warnings.catch_warnings():
+            # "donated buffers were not usable" is filtered at the launch
+            warnings.simplefilter("error")
+            x.resplit_(1)
+        assert _compiled() == 1 and old.is_deleted()
+        np.testing.assert_array_equal(x.numpy(), want)
+        keys = [k for k in _programs(comm) if k[2] == (32, 8)]
+        assert [k[-1] for k in keys] == [True]  # donate is part of the key
+
+    @pytest.mark.parametrize(
+        "case", ["ragged", "tracer", "already_placed", "one_device", "off_mesh"]
+    )
+    def test_does_not_engage(self, case):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh
+
+        comm = ht.communication.get_comm()
+        if not comm.is_distributed():
+            pytest.skip("the program needs a multi-device mesh")
+        want = np.arange(9 * 8, dtype=np.float32).reshape(9, 8)
+        j = jnp.asarray(want)  # one default device: not on the mesh
+        profiler.reset_counters()
+        profiler.reset_cache_stats()
+        if case == "ragged":
+            out = comm.resplit(j, 0)  # 9 rows over the mesh: split stays logical
+            assert out is j
+        elif case == "tracer":
+            x = _fresh((8, 8), 0)
+            want = x.numpy()
+            out = jax.jit(lambda t: comm.resplit(t, 1))(x._jarray)
+        elif case == "already_placed":
+            x = _fresh((8, 8), 0)
+            want = x.numpy()
+            out = comm.resplit(x._jarray, 0)
+            assert out is x._jarray
+            assert _counters().get("comm.resplit.calls", 0) == 0  # still uncounted
+        elif case == "one_device":
+            one = Communication(Mesh(np.asarray(jax.devices()[:1]), ("x",)), "x")
+            src = one.shard(j, 0)
+            out = one.resplit(src, 1)
+            assert out.sharding == one.sharding(2, 1)
+        else:
+            out = comm.resplit(j, 1)  # shard()'s device_put brings it onto the mesh
+            assert out.sharding == comm.sharding(2, 1)
+        assert _compiled() == 0
+        assert profiler.cache_stats()["misses"] == 0
+        np.testing.assert_array_equal(np.asarray(out), want)
