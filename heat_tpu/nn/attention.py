@@ -24,23 +24,33 @@ from .modules import Module
 __all__ = ["MultiheadAttention", "apply_rope"]
 
 
-def apply_rope(x, positions, base: float = 10000.0):
+def apply_rope(x, positions, base: float = 10000.0, pairing: str = "interleaved"):
     """Rotary position embedding on per-head states x (..., S, d).
 
-    Rotates consecutive pairs of feature channels by position-dependent
-    angles, so q·k depends only on the RELATIVE position (the RoPE
-    property; tested).  ``positions`` broadcasts against x's S axis — an
-    ``arange`` for a full sequence, a scalar index for one decode step.
-    Pointwise along S, so it rides GSPMD sharding (the sequence-parallel
-    ring applies it to the sharded q/k before the rotation starts).
+    Rotates pairs of feature channels by position-dependent angles, so q·k
+    depends only on the RELATIVE position (the RoPE property; tested).
+    ``pairing="interleaved"`` pairs consecutive channels ``(2i, 2i+1)``;
+    ``"half"`` pairs channel ``i`` with ``i + d/2`` (the rotate-half
+    convention of most published checkpoints).  ``positions`` broadcasts
+    against x's S axis — an ``arange`` for a full sequence, a scalar index
+    for one decode step.  Pointwise along S, so it rides GSPMD sharding (the
+    sequence-parallel ring applies it to the sharded q/k before the rotation
+    starts).  The angles and the rotation are float32 whatever x's dtype.
     """
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rope requires an even head dim, got {d}")
+    if pairing not in ("interleaved", "half"):
+        raise ValueError(f"pairing must be 'interleaved' or 'half', got {pairing!r}")
     freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (d/2,)
     ang = jnp.asarray(positions, jnp.float32)[..., None] * freqs  # (..., S, d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
+    xf = x.astype(jnp.float32)
+    if pairing == "half":
+        x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.astype(x.dtype)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
 
@@ -78,6 +88,9 @@ class MultiheadAttention(Module):
         rope: bool = False,
         rope_base: float = 10000.0,
         num_kv_heads: int = None,
+        rope_pairing: str = "interleaved",
+        qk_norm: bool = False,
+        qk_norm_eps: float = 1e-5,
     ):
         if embed_dim % num_heads:
             raise ValueError(f"embed_dim {embed_dim} not divisible by num_heads {num_heads}")
@@ -100,6 +113,11 @@ class MultiheadAttention(Module):
         self.comm = comm
         self.rope = rope  # rotary positions on SELF-attention q/k (not cross)
         self.rope_base = rope_base
+        self.rope_pairing = rope_pairing
+        # RMS normalisation of every query and key head over its head_dim,
+        # one learned weight vector each, before the rotation
+        self.qk_norm = qk_norm
+        self.qk_norm_eps = qk_norm_eps
 
     def init(self, key):
         k1, k2 = jax.random.split(key)
@@ -120,7 +138,23 @@ class MultiheadAttention(Module):
         if self.bias:
             p["in_proj_bias"] = jnp.zeros((rows,))
             p["out_proj"]["bias"] = jnp.zeros((E,))
+        if self.qk_norm:
+            p["q_norm"] = {"weight": jnp.ones((self.head_dim,))}
+            p["k_norm"] = {"weight": jnp.ones((self.head_dim,))}
         return p
+
+    def _position(self, params, qh, kh, positions):
+        """What happens to the query and key heads between the projection and
+        the scores: the optional RMS normalisation, then the rotation."""
+        if self.qk_norm:
+            from .modules import rms_normalize
+
+            qh = rms_normalize(qh, params["q_norm"]["weight"], self.qk_norm_eps)
+            kh = rms_normalize(kh, params["k_norm"]["weight"], self.qk_norm_eps)
+        if self.rope:
+            qh = apply_rope(qh, positions, self.rope_base, self.rope_pairing)
+            kh = apply_rope(kh, positions, self.rope_base, self.rope_pairing)
+        return qh, kh
 
     def _heads(self, t, n_heads: int = None):
         B, S, _ = t.shape
@@ -211,11 +245,9 @@ class MultiheadAttention(Module):
         kh = self._heads(k, self.num_kv_heads)
         vh = self._heads(v, self.num_kv_heads)
         i = cache["index"]
-        if self.rope:
-            # rotate at THIS position; the cache stores post-rope keys, so
-            # cached entries already carry their positions (standard)
-            qh = apply_rope(qh, i, self.rope_base)
-            kh = apply_rope(kh, i, self.rope_base)
+        # rotate at THIS position; the cache stores post-rope keys, so
+        # cached entries already carry their positions (standard)
+        qh, kh = self._position(params, qh, kh, i)
         kc = jax.lax.dynamic_update_slice_in_dim(cache["k"], kh.astype(cache["k"].dtype), i, axis=2)
         vc = jax.lax.dynamic_update_slice_in_dim(cache["v"], vh.astype(cache["v"].dtype), i, axis=2)
         L = kc.shape[2]
@@ -278,6 +310,46 @@ class MultiheadAttention(Module):
         q = x @ w[:E].T + (b[:E] if b is not None else 0.0)
         return self._attend_merge_project(params, self._heads(q), kh, vh)
 
+    def _attend(self, qh, kh, vh, kv, causal, ring, masked, need_weights,
+                key_padding_mask, attn_mask):
+        """``(out (B, H, S, d), probabilities or None)`` by the path the call
+        asks for: ring, masked dense, grouped-query flash, flash, dense."""
+        from ..parallel.ring_attention import _global_attention, ring_attention
+
+        probs = None
+        gqa = self.num_kv_heads != self.num_heads
+        if ring:
+            # the ring rotates full-head K/V blocks — broadcast the groups
+            # (training-time copy; the GQA memory win is the DECODE cache)
+            out = ring_attention(qh, *self._repeat_kv(kh, vh), self.comm,
+                                 causal=causal)
+        elif masked or need_weights:
+            # need_weights forces the probability-returning dense path even
+            # when the flash kernel would otherwise serve the call
+            out = self._masked_dense(
+                qh, *self._repeat_kv(kh, vh), causal, key_padding_mask,
+                attn_mask, return_probs=need_weights,
+            )
+            if need_weights:
+                out, probs = out
+        elif gqa and kv is None and qh.shape[-2] == kh.shape[-2]:
+            # grouped-query self-attention: the head-mapping flash kernel
+            # reads each group's K/V head from its index map — the
+            # H/H_kv-fold repeat never reaches HBM
+            from ..ops.flash_attention import flash_attention_gqa
+
+            out = flash_attention_gqa(qh, kh, vh, causal=causal)
+        elif not gqa and qh.shape == kh.shape == vh.shape:
+            # local self-attention: flash-fused Pallas kernel on TPU (the
+            # (S, S) score matrix never reaches HBM), dense-jnp elsewhere
+            from ..ops.flash_attention import flash_attention
+
+            out = flash_attention(qh, kh, vh, causal=causal)
+        else:
+            out = _global_attention(qh, *self._repeat_kv(kh, vh), causal,
+                                    1.0 / (self.head_dim**0.5))
+        return out, probs
+
     def apply(self, params, x, *, kv=None, causal: bool = False,
               key_padding_mask=None, attn_mask=None,
               need_weights: bool = False, average_attn_weights: bool = True,
@@ -327,46 +399,17 @@ class MultiheadAttention(Module):
             q = x @ w[:E].T + (b[:E] if b is not None else 0.0)
             qh = self._heads(q)
             kh, vh = self._project_kv(params, kv)
-        if self.rope and kv is None:
+        if kv is None:
             # rotary positions on self-attention only (cross-attention has
             # no shared position scale between q and the encoder memory)
-            pos = jnp.arange(qh.shape[-2])
-            qh = apply_rope(qh, pos, self.rope_base)
-            kh = apply_rope(kh, pos, self.rope_base)
-        from ..parallel.ring_attention import _global_attention, ring_attention
-
-        probs = None
-        gqa = self.num_kv_heads != self.num_heads
-        if ring:
-            # the ring rotates full-head K/V blocks — broadcast the groups
-            # (training-time copy; the GQA memory win is the DECODE cache)
-            out = ring_attention(qh, *self._repeat_kv(kh, vh), self.comm,
-                                 causal=causal)
-        elif masked or need_weights:
-            # need_weights forces the probability-returning dense path even
-            # when the flash kernel would otherwise serve the call
-            out = self._masked_dense(
-                qh, *self._repeat_kv(kh, vh), causal, key_padding_mask,
-                attn_mask, return_probs=need_weights,
-            )
-            if need_weights:
-                out, probs = out
-        elif gqa and kv is None and qh.shape[-2] == kh.shape[-2]:
-            # grouped-query self-attention: the head-mapping flash kernel
-            # reads each group's K/V head from its index map — the
-            # H/H_kv-fold repeat never reaches HBM
-            from ..ops.flash_attention import flash_attention_gqa
-
-            out = flash_attention_gqa(qh, kh, vh, causal=causal)
-        elif not gqa and qh.shape == kh.shape == vh.shape:
-            # local self-attention: flash-fused Pallas kernel on TPU (the
-            # (S, S) score matrix never reaches HBM), dense-jnp elsewhere
-            from ..ops.flash_attention import flash_attention
-
-            out = flash_attention(qh, kh, vh, causal=causal)
-        else:
-            out = _global_attention(qh, *self._repeat_kv(kh, vh), causal,
-                                    1.0 / (self.head_dim**0.5))
+            qh, kh = self._position(params, qh, kh, jnp.arange(qh.shape[-2]))
+        elif self.qk_norm:
+            raise ValueError("qk_norm is defined for self-attention only")
+        # the scores-softmax-values part under a scope of its own, so that a
+        # trace of one fused training step can tell it from the projections
+        with jax.named_scope("ht.attention"):
+            out, probs = self._attend(qh, kh, vh, kv, causal, ring, masked, need_weights,
+                                      key_padding_mask, attn_mask)
         B, H, S, d = out.shape
         merged = out.transpose(0, 2, 1, 3).reshape(B, S, E)
         y = merged @ params["out_proj"]["weight"].T

@@ -144,7 +144,7 @@ class DataParallel:
     # -- fused train step ----------------------------------------------- #
     def make_train_step(self, loss_fn: Callable, with_rng: bool = False,
                         donate: bool = True, overlap_sync=None,
-                        grad_bucket_bytes=None, sync_domains=None):
+                        grad_bucket_bytes=None, sync_domains=None, stats=None):
         """Build a jitted (params, opt_state, x, y[, key]) →
         (params, opt_state, loss) step.  The batch arrives sharded; the mean
         loss over the GLOBAL batch makes XLA emit the gradient psum (the
@@ -178,6 +178,16 @@ class DataParallel:
         topology-derived slow-domain count.  The default (``False``) keeps
         today's single-program path bit-exact; the overlapped step has no
         ``.lower`` (it is three programs, not one).
+
+        ``stats`` (a function ``(grads, aux, params, new_params, new_state)
+        -> pytree``) makes the step report on itself without a second
+        program: ``loss_fn`` then returns ``(loss, aux)`` (``aux``: whatever
+        the forward pass counted, such as an expert layer's routed rows), and
+        the step returns a fourth value, what ``stats`` makes of the
+        gradients, of the parameters before and after the update and of the
+        optimizer's new state, computed on the device in the same program
+        (norms by parameter group of the gradient, of the step taken and of
+        Adam's moments, say).  The fused path only.
         """
         if self.optimizer is None:
             raise RuntimeError("make_train_step requires an attached optimizer")
@@ -187,6 +197,8 @@ class DataParallel:
             overlap_sync = getattr(self.optimizer, "overlap_sync", False)
         if grad_bucket_bytes is None:
             grad_bucket_bytes = getattr(self.optimizer, "grad_bucket_bytes", None)
+        if overlap_sync and stats is not None:
+            raise ValueError("stats= is a hook of the one-program step: not with overlap_sync")
         if overlap_sync:
             return self._make_overlapped_step(
                 loss_fn, with_rng, donate, grad_bucket_bytes, sync_domains
@@ -210,27 +222,29 @@ class DataParallel:
             def _forward(p, jx, key):
                 return apply(p, jx)  # flax-style apply without train/key kwargs
 
+        def _step(params, opt_state, jx, jy, key=None):
+            def loss(p):
+                out = loss_fn(_forward(p, jx, key), jy)
+                return out if stats is not None else (out, None)
+
+            (lval, aux), grads = jax.value_and_grad(loss, has_aux=True)(params)
+            with jax.named_scope("ht.optim.update"):
+                new_params, new_state = opt._update(params, grads, opt_state)
+            if stats is None:
+                return new_params, new_state, lval
+            return new_params, new_state, lval, stats(grads, aux, params, new_params, new_state)
+
         if with_rng:
 
             @_jit
             def step(params, opt_state, jx, jy, key):
-                def loss(p):
-                    return loss_fn(_forward(p, jx, key), jy)
-
-                lval, grads = jax.value_and_grad(loss)(params)
-                new_params, new_state = opt._update(params, grads, opt_state)
-                return new_params, new_state, lval
+                return _step(params, opt_state, jx, jy, key)
 
         else:
 
             @_jit
             def step(params, opt_state, jx, jy):
-                def loss(p):
-                    return loss_fn(_forward(p, jx, None), jy)
-
-                lval, grads = jax.value_and_grad(loss)(params)
-                new_params, new_state = opt._update(params, grads, opt_state)
-                return new_params, new_state, lval
+                return _step(params, opt_state, jx, jy)
 
         step = _instrumented_step(step)
         self._train_step = step

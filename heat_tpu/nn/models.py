@@ -5,6 +5,18 @@ torchvision's ResNet-50 on ImageNet (reference: ``heat/optim/dp_optimizer.py``
 docstrings, SURVEY §2.5/§6).  These builders provide the equivalent
 residual-CNN family natively so the DASO/DataParallel baselines are
 reproducible without torchvision.
+
+What the model layer offers: ``mlp`` and the ``resnet`` family (the
+trainers' baselines); ``transformer_encoder`` / ``transformer_decoder`` /
+``Seq2SeqTransformer`` and ``TransformerLM`` (LayerNorm + GELU blocks,
+learned/rope/sinusoidal positions, GQA, an optional capacity-routed ``MoE``
+FFN, KV-cache generation); and ``PatternLM``, a pre-norm causal LM whose
+every layer is chosen from the configuration (sequence operator: gated
+short convolution or QK-normed rotary GQA; feed-forward: SwiGLU or
+sigmoid-routed drop-free experts, of which this rank may hold a range),
+RMSNorm, tied embedding, float32 parameters under lower-precision
+activations.  ``PatternLM`` trains through ``DataParallel.make_train_step``;
+it has no decode path yet.
 """
 
 from __future__ import annotations
@@ -13,7 +25,7 @@ from typing import Sequence
 
 from . import modules as nn
 
-__all__ = ["resnet", "resnet18", "resnet34", "resnet50", "resnet50_ish", "mlp", "transformer_encoder", "transformer_decoder", "TransformerLM", "Seq2SeqTransformer"]
+__all__ = ["resnet", "resnet18", "resnet34", "resnet50", "resnet50_ish", "mlp", "transformer_encoder", "transformer_decoder", "TransformerLM", "Seq2SeqTransformer", "PatternLM"]
 
 
 def _basic_block(cin: int, cout: int, stride: int = 1) -> nn.Module:
@@ -1079,3 +1091,210 @@ class Seq2SeqTransformer(nn.Module):
         else:
             best = jnp.argmax(scores, axis=1)  # (B,)
         return ys.reshape(B, W, total)[jnp.arange(B), best]
+
+
+def _names(path):
+    """The dict keys along a pytree path (``""`` for a list index)."""
+    return [str(getattr(k, "key", "")) for k in path]
+
+
+def _is_norm(names) -> bool:
+    return any(n.endswith("norm") for n in names)
+
+
+class _ShortConvOperator(nn.Module):
+    """Gated short convolution as a sequence operator: ``[B, C, u] =
+    split3(W_in z)``, ``out = W_out (C * conv(B * u))`` with a depthwise
+    causal convolution of ``taps`` positions
+    (:func:`heat_tpu.ops.short_conv.gated_short_conv`); no bias anywhere."""
+
+    def __init__(self, embed_dim: int, taps: int = 3):
+        self.embed_dim, self.taps = embed_dim, taps
+        self.in_proj = nn.Linear(embed_dim, 3 * embed_dim, bias=False)
+        self.out_proj = nn.Linear(embed_dim, embed_dim, bias=False)
+
+    def init(self, key):
+        import jax
+
+        k1, k2, k3 = jax.random.split(key, 3)
+        bound = 1.0 / self.taps**0.5
+        return {
+            "in_proj": self.in_proj.init(k1),
+            "conv": {"weight": jax.random.uniform(
+                k2, (self.embed_dim, self.taps), minval=-bound, maxval=bound)},
+            "out_proj": self.out_proj.init(k3),
+        }
+
+    def apply(self, params, x, **kw):
+        import jax
+
+        from ..ops.short_conv import gated_short_conv
+
+        bcu = self.in_proj.apply(params["in_proj"], x)
+        with jax.named_scope("ht.shortconv"):
+            gated = gated_short_conv(bcu, params["conv"]["weight"])
+        return self.out_proj.apply(params["out_proj"], gated)
+
+
+class _PatternBlock(nn.Module):
+    """``h = x + Op(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; an expert
+    FFN also returns what its routing counted (``MoE.apply_with_stats``)."""
+
+    def __init__(self, embed_dim: int, operator: nn.Module, ffn: nn.Module, eps: float):
+        self.operator_norm = nn.RMSNorm(embed_dim, eps=eps)
+        self.operator = operator
+        self.ffn_norm = nn.RMSNorm(embed_dim, eps=eps)
+        self.ffn = ffn
+        self.routed = hasattr(ffn, "apply_with_stats")
+        # the operator's projections, beside the scope the operator gives its core
+        self.scope = "ht.shortconv.proj" if isinstance(operator, _ShortConvOperator) else "ht.attention.proj"
+
+    def init(self, key):
+        import jax
+
+        k1, k2 = jax.random.split(key)
+        return {
+            "operator_norm": self.operator_norm.init(None), "operator": self.operator.init(k1),
+            "ffn_norm": self.ffn_norm.init(None), "ffn": self.ffn.init(k2),
+        }
+
+    def apply(self, params, x, **kw):
+        import jax
+
+        z = self.operator_norm.apply(params["operator_norm"], x)
+        with jax.named_scope(self.scope):
+            h = x + self.operator.apply(params["operator"], z, causal=True)
+        z = self.ffn_norm.apply(params["ffn_norm"], h)
+        if self.routed:
+            out, stats = self.ffn.apply_with_stats(params["ffn"], z)
+            return h + out, stats
+        with jax.named_scope("ht.mlp"):
+            return h + self.ffn.apply(params["ffn"], z), None
+
+
+class PatternLM(nn.Module):
+    """Pre-norm causal language model whose layers follow a pattern.
+
+    ``layer_types[l]`` names layer ``l``'s sequence operator: ``"conv"`` (a
+    gated short convolution of ``conv_taps`` positions) or
+    ``"full_attention"`` (causal grouped-query attention with RMS-normalised
+    query and key heads and rotate-half rotary positions of base
+    ``rope_base``).  The first ``num_dense_layers`` layers have a SwiGLU
+    feed-forward of width ``ffn_dim``; with ``num_experts`` set, every later
+    layer has ``num_experts`` SwiGLU experts of width ``expert_dim``,
+    ``experts_per_token`` of them a token, chosen by sigmoid scores plus a
+    selection bias (a buffer) and weighted by the renormalised scores, routed
+    without drops (``MoE(dispatch="sorted")``).  ``experts_held`` (a
+    ``range``) says which experts' weights live on this rank: the router
+    still scores all of them, and what the absent ones would add is left
+    out.  RMSNorm everywhere, no bias anywhere, the token embedding is also
+    the output head.
+
+    Parameters are float32, drawn ``N(0, init_std)`` (norm weights 1, the
+    selection bias ``N(0, bias_std)`` and then fixed).  ``dtype`` is the
+    dtype of the activations and of the operands of the matrix products
+    (``None``: the parameters'); norms' statistics, routing scores, softmax
+    and loss stay float32.  Every block is rematerialised under ``grad``.
+
+    ``apply(params, tokens)`` with tokens ``(B, S)`` returns ``(logits
+    (B, S, vocab) in dtype, stats)``; ``stats`` holds, per expert layer, the
+    rows routed to each expert held and the rows dropped (always 0 on this
+    path), for the ``stats=`` hook of ``DataParallel.make_train_step``.
+    ``decay_mask(params)`` is the usual weight-decay mask (matrices yes;
+    norms, selection bias and embedding no).
+    """
+
+    def __init__(self, vocab_size: int, embed_dim: int, layer_types: Sequence[str], *,
+                 num_heads: int, num_kv_heads: int = None, ffn_dim: int,
+                 num_dense_layers: int = None, num_experts: int = None,
+                 experts_per_token: int = 2, expert_dim: int = None, experts_held=None,
+                 routed_scaling: float = 1.0, norm_topk: bool = True,
+                 conv_taps: int = 3, rope_base: float = 1e6, norm_eps: float = 1e-5,
+                 init_std: float = 0.02, bias_std: float = 0.0, dtype=None):
+        from .attention import MultiheadAttention
+        from .moe import MoE
+
+        unknown = sorted(set(layer_types) - {"conv", "full_attention"})
+        if unknown:
+            raise ValueError(f"layer_types may hold 'conv' and 'full_attention', got {unknown}")
+        n_dense = len(layer_types) if num_dense_layers is None or not num_experts else num_dense_layers
+        self.vocab_size, self.embed_dim = vocab_size, embed_dim
+        self.layer_types = tuple(layer_types)
+        self.init_std, self.bias_std, self.dtype = init_std, bias_std, dtype
+        self.embed = nn.Embedding(vocab_size, embed_dim)
+        self.blocks = []
+        for i, kind in enumerate(self.layer_types):
+            operator = _ShortConvOperator(embed_dim, conv_taps) if kind == "conv" else MultiheadAttention(
+                embed_dim, num_heads, bias=False, rope=True, rope_base=rope_base,
+                rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=True, qk_norm_eps=norm_eps)
+            ffn = nn.SwiGLU(embed_dim, ffn_dim) if i < n_dense else MoE(
+                embed_dim, num_experts, hidden_dim=expert_dim, top_k=experts_per_token,
+                gated=True, scoring="sigmoid", expert_bias=True, norm_topk=norm_topk,
+                routed_scaling=routed_scaling, dispatch="sorted", experts_held=experts_held)
+            self.blocks.append(_PatternBlock(embed_dim, operator, ffn, norm_eps))
+        self.norm = nn.RMSNorm(embed_dim, eps=norm_eps)
+        self._remat_fns = [{} for _ in self.blocks]
+
+    def _structure(self, key):
+        return {"embed": self.embed.init(key), "blocks": [b.init(key) for b in self.blocks],
+                "norm": self.norm.init(None)}
+
+    def init(self, key):
+        """Every matrix ``N(0, init_std)``, every norm weight 1, the selection
+        bias ``N(0, bias_std)``: one draw per leaf, keyed by its place."""
+        import jax
+        import jax.numpy as jnp
+
+        shapes = jax.eval_shape(self._structure, key)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+
+        def draw(i, path, leaf):
+            names = _names(path)
+            if _is_norm(names):
+                return jnp.ones(leaf.shape, jnp.float32)
+            std = self.bias_std if "expert_bias" in names else self.init_std
+            return std * jax.random.normal(jax.random.fold_in(key, i), leaf.shape, jnp.float32)
+
+        return jax.tree_util.tree_unflatten(
+            treedef, [draw(i, path, leaf) for i, (path, leaf) in enumerate(flat)])
+
+    def decay_mask(self, params):
+        import jax
+
+        def decays(path, _):
+            names = _names(path)
+            return not ("embed" in names or "expert_bias" in names or _is_norm(names))
+
+        return jax.tree_util.tree_map_with_path(decays, params)
+
+    def _cast(self, tree):
+        """The matrices in the activations' dtype; vectors (norm weights,
+        the selection bias) and the router stay float32."""
+        import jax
+
+        if self.dtype is None:
+            return tree
+
+        def cast(path, a):
+            return a if a.ndim < 2 or "router" in _names(path) else a.astype(self.dtype)
+
+        return jax.tree_util.tree_map_with_path(cast, tree)
+
+    def apply(self, params, tokens, *, train: bool = False, key=None):
+        import jax
+
+        embedding = self._cast(params["embed"])["weight"]  # also the output head
+        with jax.named_scope("ht.lm.embed"):
+            h = embedding[tokens]
+        stats = []
+        for block, cache, p in zip(self.blocks, self._remat_fns, params["blocks"]):
+            def run(p, h, block=block):
+                return block.apply(self._cast(p), h)
+
+            h, s = _remat_jit(cache, train, run)(p, h)
+            if s is not None:
+                stats.append(s)
+        with jax.named_scope("ht.lm.head_loss"):
+            h = self.norm.apply(params["norm"], h)
+            logits = h @ embedding.T
+        return logits, stats

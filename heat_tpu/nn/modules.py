@@ -41,6 +41,7 @@ __all__ = [
     "BatchNorm3d",
     "LayerNorm",
     "RMSNorm",
+    "SwiGLU",
     "GroupNorm",
     "Embedding",
     "Residual",
@@ -528,10 +529,40 @@ class RMSNorm(Module):
     def apply(self, params, x, **kw):
         axes = tuple(range(x.ndim - len(self.normalized_shape), x.ndim))
         eps = jnp.finfo(x.dtype).eps if self.eps is None else self.eps
-        y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=axes, keepdims=True) + eps)
-        if self.affine:
-            y = y * params["weight"]
-        return y
+        return rms_normalize(x, params["weight"] if self.affine else None, eps, axes)
+
+
+def rms_normalize(x, weight=None, eps: float = 1e-5, axes=(-1,)):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over ``axes``.  The statistics
+    and the scaling are float32 whatever ``x``'s dtype (a mean of squares in
+    bfloat16 loses the small terms); the result has ``x``'s dtype."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=axes, keepdims=True) + eps)
+    if weight is not None:
+        y = y * weight
+    return y.astype(x.dtype)
+
+
+class SwiGLU(Module):
+    """Gated-linear feed-forward ``w2 (silu(w1 x) * w3 x)`` without biases
+    (Shazeer 2020; the FFN of most current language models).  A weight
+    matrix may be kept in a wider dtype than ``x``: it is brought to ``x``'s
+    dtype where it is used."""
+
+    def __init__(self, embed_dim: int, hidden_dim: int):
+        self.embed_dim = embed_dim
+        self.hidden_dim = hidden_dim
+        self.w1 = Linear(embed_dim, hidden_dim, bias=False)
+        self.w3 = Linear(embed_dim, hidden_dim, bias=False)
+        self.w2 = Linear(hidden_dim, embed_dim, bias=False)
+
+    def init(self, key):
+        k1, k2, k3 = jax.random.split(key, 3)
+        return {"w1": self.w1.init(k1), "w3": self.w3.init(k3), "w2": self.w2.init(k2)}
+
+    def apply(self, params, x, **kw):
+        w1, w3, w2 = (params[n]["weight"].astype(x.dtype) for n in ("w1", "w3", "w2"))
+        return (jax.nn.silu(x @ w1.T) * (x @ w3.T)) @ w2.T
 
 
 class GroupNorm(Module):

@@ -1,20 +1,42 @@
 """Mixture-of-Experts layer with expert parallelism.
 
 Beyond-reference model family (the reference has no MoE or expert
-parallelism — SURVEY §2.8 lists EP as absent), built the TPU way: the
-token→expert dispatch and combine are dense einsums over a capacity-bounded
+parallelism — SURVEY §2.8 lists EP as absent).  One layer, two ways to bring
+tokens to experts (``dispatch=``):
+
+``"capacity"`` (the default, and what ``comm=`` shards): the token→expert
+dispatch and combine are dense einsums over a capacity-bounded
 ``(experts, capacity, d)`` buffer (static shapes, so the whole layer jits
 and rides the MXU), and with ``comm=`` the experts are sharded over the
 mesh while tokens travel through TWO ``all_to_all`` collectives — the
-canonical expert-parallel data movement on ICI.
+canonical expert-parallel data movement on ICI.  Capacity positions are
+claimed slot-major (all first choices before any second choice, tokens in
+order within a slot) and a token that overflows an expert's capacity is
+dropped from that expert (contributing zero — the standard GShard/Switch
+overflow semantics).  Its ``(tokens, experts, capacity)`` tensors grow with
+the square of the token count, so it is the path of small batches.
 
-Routing is token-choice top-k with slot-priority capacity assignment: all
-first choices claim capacity before any second choice, tokens in order
-within a slot.  Selected gate weights are renormalized by their sum, and
-tokens that overflow an expert's capacity are dropped from that expert
-(contributing zero — the standard GShard/Switch overflow semantics).
-Routing is deterministic: no jitter noise, so eval == train and results
-are reproducible across device counts.
+``"sorted"``: no capacity and no drop, whatever the loads.  The
+``tokens x top_k`` token-slots are sorted by expert, the experts run as
+grouped matrix products over the sorted rows (``jax.lax.ragged_dot``: each
+expert multiplies exactly the rows routed to it), and the results are
+brought back into token order and weighted.  Memory and work are linear in
+the tokens.  This path also takes ``experts_held``: the range of expert ids
+whose weights live here (one rank's share of an expert-parallel layer).
+The router still scores every expert and picks ``top_k`` of them; only the
+held experts have parameters, and what the others would add is left out of
+the result.  It runs on one chip without any exchange (``comm`` must be
+``None``): the exchange of a multi-chip no-drop layer is not built yet.
+
+Routing is token-choice top-k.  ``scoring="softmax"`` renormalizes the
+selected probabilities by their sum; ``scoring="sigmoid"`` scores each
+expert independently, selects on ``score + expert_bias`` (``expert_bias=
+True``: a buffer in the parameters that no optimizer updates), and weights
+by the unbiased scores, renormalized (``norm_topk=True``) and scaled by
+``routed_scaling``.  ``gated=True`` makes every expert a gated-linear unit,
+``w2 (silu(w1 x) * w3 x)``, without biases.  Routing is deterministic: no
+jitter noise, so eval == train and results are reproducible across device
+counts.
 """
 
 from __future__ import annotations
@@ -69,6 +91,25 @@ def _topk_gates(gates, top_k: int):
     decode == teacher-forced contract can never drift between them."""
     val, idx = jax.lax.top_k(gates, top_k)  # (n, k)
     return val / (val.sum(axis=-1, keepdims=True) + 1e-9), idx
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is known: the gradient is
+    then the gather ``g[inverse]`` and not the scatter-add that autodiff
+    writes for a gather it cannot know to be one-to-one."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
 def _routing(gates, top_k: int, capacity: int):
@@ -140,9 +181,30 @@ class MoE(Module):
         capacity_factor: float = 1.5,
         comm=None,
         batch_axis: str | None = None,
+        *,
+        gated: bool = False,
+        scoring: str = "softmax",
+        expert_bias: bool = False,
+        norm_topk: bool = True,
+        routed_scaling: float = 1.0,
+        dispatch: str = "capacity",
+        experts_held=None,
     ):
         if top_k < 1 or top_k > num_experts:
             raise ValueError(f"top_k {top_k} must be in [1, num_experts={num_experts}]")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
+        if dispatch not in ("capacity", "sorted"):
+            raise ValueError(f"dispatch must be 'capacity' or 'sorted', got {dispatch!r}")
+        held = range(num_experts) if experts_held is None else experts_held
+        if not isinstance(held, range) or held.step != 1 or not 0 <= held.start < held.stop <= num_experts:
+            raise ValueError(f"experts_held {experts_held!r} is not a range of the {num_experts} experts")
+        held = (held.start, held.stop)
+        if dispatch == "capacity" and (held != (0, num_experts) or gated or scoring != "softmax"
+                                       or expert_bias):
+            raise ValueError("experts_held, gated experts and sigmoid/bias routing need dispatch='sorted'")
+        if dispatch == "sorted" and comm is not None:
+            raise ValueError("dispatch='sorted' runs on one chip: the no-drop exchange is not built")
         if batch_axis is not None:
             if comm is None:
                 raise ValueError(
@@ -160,6 +222,13 @@ class MoE(Module):
         self.capacity_factor = capacity_factor
         self.comm = comm
         self.batch_axis = batch_axis  # dp axis of a 2-D mesh (see _ep_program)
+        self.gated = gated
+        self.scoring = scoring
+        self.expert_bias = expert_bias
+        self.norm_topk = norm_topk
+        self.routed_scaling = routed_scaling
+        self.dispatch = dispatch
+        self.experts_held = held  # (first id, one past the last)
 
     @property
     def _program_key(self):
@@ -173,6 +242,21 @@ class MoE(Module):
         kr, k1, k2 = jax.random.split(key, 3)
         bound1 = 1.0 / jnp.sqrt(D)
         bound2 = 1.0 / jnp.sqrt(H)
+        if self.dispatch == "sorted":
+            k1, k3 = jax.random.split(k1)
+            held = self.experts_held[1] - self.experts_held[0]
+            out = {
+                "router": jax.random.uniform(kr, (D, E), minval=-bound1, maxval=bound1),
+                "w1": jax.random.uniform(k1, (held, D, H), minval=-bound1, maxval=bound1),
+                "w2": jax.random.uniform(k2, (held, H, D), minval=-bound2, maxval=bound2),
+            }
+            if self.gated:
+                out["w3"] = jax.random.uniform(k3, (held, D, H), minval=-bound1, maxval=bound1)
+            else:
+                out.update(b1=jnp.zeros((held, H)), b2=jnp.zeros((held, D)))
+            if self.expert_bias:
+                out["expert_bias"] = jnp.zeros((E,))
+            return out
         return {
             "router": jax.random.uniform(kr, (D, E), minval=-bound1, maxval=bound1),
             "w1": jax.random.uniform(k1, (E, D, H), minval=-bound1, maxval=bound1),
@@ -194,11 +278,15 @@ class MoE(Module):
         return jnp.einsum("ech,ehd->ecd", h, params["w2"]) + params["b2"][:, None, :]
 
     def _dense(self, params, x2d):
+        """``(y, stats)`` of the capacity path on one chip; ``stats`` counts
+        what the buffers took and what overflowed."""
         gates = jax.nn.softmax(x2d @ params["router"])
         dispatch, combine = _routing(gates, self.top_k, self._capacity(x2d.shape[0]))
         buf = jnp.einsum("nec,nd->ecd", dispatch, x2d)
         out = self._experts(params, buf)
-        return jnp.einsum("nec,ecd->nd", combine, out)
+        rows = jnp.sum(dispatch, axis=(0, 2)).astype(jnp.int32)
+        stats = {"rows": rows, "dropped": x2d.shape[0] * self.top_k - jnp.sum(rows)}
+        return jnp.einsum("nec,ecd->nd", combine, out), stats
 
     def _ep_fn(self, params, x_loc, mask_loc):
         """Per-shard body: local routing, all_to_all to expert owners,
@@ -214,7 +302,81 @@ class MoE(Module):
         out = comm.Alltoall(out, split_axis=1, concat_axis=0)  # (E, C, D)
         return jnp.einsum("nec,ecd->nd", combine, out)
 
+    # ------------------------------------------------------------------ #
+    # the sorted, drop-free path
+    # ------------------------------------------------------------------ #
+
+    def _route(self, params, x2d):
+        """``(weights (n, k), expert ids (n, k))`` in float32 whatever the
+        activations' dtype: a selection decided in bfloat16 differs from the
+        float32 one wherever two scores lie within its rounding."""
+        logits = jnp.matmul(x2d.astype(jnp.float32), params["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        if self.scoring == "softmax":
+            return _topk_gates(jax.nn.softmax(logits), self.top_k)
+        scores = jax.nn.sigmoid(logits)
+        biased = scores + jax.lax.stop_gradient(params["expert_bias"]) if self.expert_bias else scores
+        _, idx = jax.lax.top_k(biased, self.top_k)
+        val = jnp.take_along_axis(scores, idx, axis=-1)
+        if self.norm_topk:
+            val = val / (val.sum(axis=-1, keepdims=True) + 1e-6)
+        return val * self.routed_scaling, idx
+
+    def _sorted(self, params, x2d):
+        """``(y, stats)``: every token-slot whose expert is held goes through
+        that expert; nothing else is computed and nothing is dropped."""
+        n, k = x2d.shape[0], self.top_k
+        lo, hi = self.experts_held
+        held = hi - lo
+        with jax.named_scope("ht.moe.route"):
+            val, idx = self._route(params, x2d)
+        with jax.named_scope("ht.moe.dispatch"):
+            # slot i = token i // k; the slots of experts not held sort last
+            flat = idx.reshape(-1)
+            here = (flat >= lo) & (flat < hi)
+            group = jnp.where(here, flat - lo, held)
+            order = jnp.argsort(group, stable=True)
+            back = jnp.argsort(order)
+            rows = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+            in_group = jnp.arange(n * k) < jnp.sum(rows)
+            xs = _permute_rows(jnp.repeat(x2d, k, axis=0), order, back)
+            # a grouped product leaves the rows past its groups undefined
+            xs = jnp.where(in_group[:, None], xs, 0)
+        with jax.named_scope("ht.moe.experts"):
+            dt = x2d.dtype
+            h = jax.lax.ragged_dot(xs, params["w1"].astype(dt), rows)
+            if self.gated:
+                h = jax.nn.silu(h) * jax.lax.ragged_dot(xs, params["w3"].astype(dt), rows)
+            else:
+                h = jax.nn.gelu(h + jnp.repeat(params["b1"].astype(dt), rows, axis=0,
+                                               total_repeat_length=n * k))
+            ys = jax.lax.ragged_dot(h, params["w2"].astype(dt), rows)
+            if not self.gated:
+                ys = ys + jnp.repeat(params["b2"].astype(dt), rows, axis=0, total_repeat_length=n * k)
+            ys = jnp.where(in_group[:, None], ys, 0)
+        with jax.named_scope("ht.moe.combine"):
+            per_slot = _permute_rows(ys, back, order).reshape(n, k, -1)
+            y = jnp.einsum("nk,nkd->nd", val.astype(jnp.float32), per_slot.astype(jnp.float32))
+        # the buffer has a row for every token-slot, so this is 0 by construction
+        stats = {"rows": rows, "dropped": jnp.sum(here, dtype=jnp.int32) - jnp.sum(rows)}
+        return y.astype(x2d.dtype), stats
+
+    def apply_with_stats(self, params, x):
+        """``(y, {"rows": rows routed to each expert held, "dropped": token-
+        slots of held experts that no expert computed})``; the capacity path
+        counts what its buffers took and what overflowed."""
+        x2d = x.reshape(-1, self.embed_dim)
+        if self.dispatch == "sorted":
+            y, stats = self._sorted(params, x2d)
+            return y.reshape(x.shape), stats
+        if self.comm is not None:
+            raise ValueError("apply_with_stats counts on one chip: comm must be None")
+        y, stats = self._dense(params, x2d)
+        return y.reshape(x.shape), stats
+
     def apply(self, params, x, **kw):
+        if self.dispatch == "sorted":
+            return self.apply_with_stats(params, x)[0]
         orig_shape = x.shape
         x2d = x.reshape(-1, self.embed_dim)
         comm = self.comm
@@ -222,7 +384,7 @@ class MoE(Module):
         # identity there) so the dp token sharding survives — only the
         # truly-unsharded case takes the dense shortcut
         if comm is None or (comm.size == 1 and self.batch_axis is None):
-            return self._dense(params, x2d).reshape(orig_shape)
+            return self._dense(params, x2d)[0].reshape(orig_shape)
         if self.num_experts % comm.size:
             warnings.warn(
                 f"MoE: num_experts={self.num_experts} not divisible by mesh size "
@@ -233,7 +395,7 @@ class MoE(Module):
                 "from the expert-parallel path for the same config",
                 stacklevel=2,
             )
-            return self._dense(params, x2d).reshape(orig_shape)
+            return self._dense(params, x2d)[0].reshape(orig_shape)
 
         # tokens shard over dp x ep jointly when batch_axis is given,
         # else over the expert axis alone
@@ -260,8 +422,11 @@ class MoE(Module):
         disagree arbitrarily, so decoding uses this exact path instead
         (== :meth:`apply` whenever apply's capacity was not binding — the
         usual serving regime).  Cost is k gathered FFNs per token; with
-        decode batches this is small and stays on the MXU.
+        decode batches this is small and stays on the MXU.  The sorted
+        dispatch never drops, so there decoding is :meth:`apply` itself.
         """
+        if self.dispatch == "sorted":
+            return self.apply(params, x)
         orig_shape = x.shape
         x2d = x.reshape(-1, self.embed_dim)
         gates = jax.nn.softmax(x2d @ params["router"])
@@ -278,7 +443,11 @@ class MoE(Module):
         """Switch-transformer auxiliary loss: ``E * Σ_e f_e · P_e`` where
         ``f_e`` is the fraction of tokens whose TOP choice is expert e and
         ``P_e`` the mean router probability — minimized (=1) by a uniform
-        router.  Add ``coef * load_balance_loss`` to the training loss."""
+        router.  Add ``coef * load_balance_loss`` to the training loss.
+        Defined for softmax scores (sigmoid routing balances by its
+        selection bias instead)."""
+        if self.scoring != "softmax":
+            raise ValueError("load_balance_loss is the softmax router's auxiliary loss")
         x2d = x.reshape(-1, self.embed_dim)
         gates = jax.nn.softmax(x2d @ params["router"])
         top1 = jnp.argmax(gates, axis=-1)

@@ -133,17 +133,20 @@ def _guard_counters(opt_state) -> dict:
     return {"steps": int(_np.max(steps)), "skipped": int(_np.sum(skipped))}
 
 
+# buffers by name: BatchNorm's ``running_*`` statistics and an expert layer's
+# selection bias (``nn.MoE(expert_bias=True)``)
+_BUFFER_PREFIX, _BUFFER_NAMES = "running_", ("expert_bias",)
+
+
 def _nontrainable_mask(params):
     """True for trainable leaves, False for buffers (``running_*`` stats of
-    BatchNorm live in the params pytree but must receive no updates and no
-    weight decay)."""
+    BatchNorm and the experts' selection bias live in the params pytree but
+    must receive no updates and no weight decay)."""
     import jax
 
     def is_trainable(path):
-        return not any(
-            getattr(k, "key", None) is not None and str(getattr(k, "key", "")).startswith("running_")
-            for k in path
-        )
+        names = [str(getattr(k, "key", "")) for k in path if getattr(k, "key", None) is not None]
+        return not any(n.startswith(_BUFFER_PREFIX) or n in _BUFFER_NAMES for n in names)
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     return jax.tree_util.tree_unflatten(treedef, [is_trainable(p) for p, _ in flat])
@@ -163,8 +166,8 @@ def _named_optimizer(name: str, **kw):
         "adam": lambda lr=1e-3, betas=(0.9, 0.999), eps=1e-8, weight_decay=0.0: optax.adam(
             lr, b1=betas[0], b2=betas[1], eps=eps
         ),
-        "adamw": lambda lr=1e-3, betas=(0.9, 0.999), eps=1e-8, weight_decay=1e-2: optax.adamw(
-            lr, b1=betas[0], b2=betas[1], eps=eps, weight_decay=weight_decay
+        "adamw": lambda lr=1e-3, betas=(0.9, 0.999), eps=1e-8, weight_decay=1e-2, mask=None: optax.adamw(
+            lr, b1=betas[0], b2=betas[1], eps=eps, weight_decay=weight_decay, mask=mask
         ),
     }
     if name.lower() not in table:
@@ -181,8 +184,11 @@ def Adam(params=None, lr: float = 1e-3, betas=(0.9, 0.999), eps: float = 1e-8):
     return _named_optimizer("adam", lr=lr, betas=betas, eps=eps)
 
 
-def AdamW(params=None, lr: float = 1e-3, betas=(0.9, 0.999), eps: float = 1e-8, weight_decay: float = 1e-2):
-    return _named_optimizer("adamw", lr=lr, betas=betas, eps=eps, weight_decay=weight_decay)
+def AdamW(params=None, lr: float = 1e-3, betas=(0.9, 0.999), eps: float = 1e-8, weight_decay: float = 1e-2,
+          mask=None):
+    """``mask`` (a pytree of bools like the parameters, or a function from
+    the parameters to one) names the leaves that decay; ``None``: all."""
+    return _named_optimizer("adamw", lr=lr, betas=betas, eps=eps, weight_decay=weight_decay, mask=mask)
 
 
 class DataParallelOptimizer:
