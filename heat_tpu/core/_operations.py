@@ -14,7 +14,9 @@ signature per comm, with the output sharding compiled in as a
 ``with_sharding_constraint`` — so a repeated op never re-traces, re-lowers,
 or pays an eager post-op ``device_put``.  The in-place dunders additionally
 donate their left operand's buffer to the executable (``donate_argnums``),
-letting XLA alias input and output storage.
+letting XLA alias input and output storage.  ``_program_op`` gives a library
+function the same tail for its whole ``jnp`` expression (``spatial.cdist``:
+one launch where op-by-op made eleven).
 
 Program spans: while a profile records (``_cache.recording()``, the
 profiler's own answer — no flag here), each helper runs entry to return
@@ -39,7 +41,7 @@ from . import _cache, sanitation, types
 from .dndarray import DNDarray
 from .stride_tricks import broadcast_shape, sanitize_axis
 
-__all__ = ["_local_op", "_binary_op", "_reduce_op", "_cum_op"]
+__all__ = ["_local_op", "_binary_op", "_reduce_op", "_cum_op", "_program_op"]
 
 # set by the in-place dunders (``__iadd__`` etc. via ``arithmetics._iop``):
 # the next _binary_op donates its first operand's buffer to the compiled
@@ -271,23 +273,24 @@ def _local(op, x, out, kwargs):
     return ret if _CHECKS is None else _CHECKS(ret, "dispatch.local.general")
 
 
-def _compile_tail(comm, compute, j, want_split):
-    """Shared compile tail of the unary fast paths (_local/_reduce/_cum):
-    resolve the result signature of ``compute`` by eval_shape, clamp the
-    split, refuse ragged results (``_SLOW`` — pad bookkeeping belongs to
-    the general path), and jit (compute + canonical output placement).
+def _compile_tail(comm, compute, want_split, *js):
+    """Shared compile tail of the fast paths that take ``compute`` whole
+    (_local/_reduce/_cum on one operand, _program on several): resolve the
+    result signature of ``compute`` by eval_shape, clamp the split, refuse
+    ragged results (``_SLOW`` — pad bookkeeping belongs to the general
+    path), and jit (compute + canonical output placement).
     Returns ``(program, result shape, heat dtype, split)`` or ``_SLOW``."""
-    aval = jax.eval_shape(compute, j)
+    aval = jax.eval_shape(compute, *js)
     rshape = tuple(aval.shape)
     rsplit = want_split if want_split is not None and want_split < len(rshape) else None
     if rsplit is not None and comm.size > 1 and rshape[rsplit] % comm.size:
         return _SLOW
-    prog = jax.jit(lambda a: comm.shard(compute(a), rsplit))
+    prog = jax.jit(lambda *a: comm.shard(compute(*a), rsplit))
     return prog, rshape, types.canonical_heat_type(aval.dtype), rsplit
 
 
 def _build_local(comm, op, j, split, kwargs):
-    return _compile_tail(comm, lambda a: op(a, **kwargs), j, split)
+    return _compile_tail(comm, lambda a: op(a, **kwargs), split, j)
 
 
 def _result_split(
@@ -695,7 +698,7 @@ def _build_reduce(comm, op, j, axis, keepdims, dtype, new_split, kwargs):
         r = op(a, axis=axis, keepdims=keepdims, **kwargs)
         return r if jdt is None else r.astype(jdt)
 
-    return _compile_tail(comm, compute, j, new_split)
+    return _compile_tail(comm, compute, new_split, j)
 
 
 def _cum_op(
@@ -788,7 +791,75 @@ def _build_cum(comm, op, j, axis, dtype, split):
         r = op(a.reshape(-1), axis=0) if axis is None else op(a, axis=axis)
         return r if jdt is None else r.astype(jdt)
 
-    return _compile_tail(comm, compute, j, split)
+    return _compile_tail(comm, compute, split, j)
+
+
+def _program_op(compute: Callable, operands: tuple, split: Optional[int], static: tuple = ()) -> DNDarray:
+    """A library function's whole computation, ``compute(*arrays, *static)``,
+    as ONE cached program where it would otherwise be one launch per ``jnp``
+    call (``spatial.cdist``: eleven).  ``compute`` is a module-level function
+    (its identity keys the cache); ``operands`` are DNDarrays, the first at
+    least, or python scalars that ride as runtime arguments (a new value
+    compiles nothing); ``split`` is the result's.  Which path runs is read
+    off the operands, as in the other tails: concrete and pad-free take the
+    program, whose result leaves it on the canonical sharding; tracers,
+    padded operands and ragged results call ``compute`` itself, un-jitted,
+    and place the result."""
+    if not _cache.recording():
+        return _program(compute, operands, split, static)
+    with _cache.TraceAnnotation("ht.dispatch.program", op=_op_name(compute)):
+        return _program(compute, operands, split, static)
+
+
+def _program(compute, operands, split, static):
+    proto = operands[0]
+    comm = proto.comm
+    args = tuple(t._jarray if isinstance(t, DNDarray) else t for t in operands)
+    descs = tuple(_plan_desc(t, comm) for t in operands)
+    if None not in descs:
+        tel = _TELEMETRY
+        m0 = _cache._STATS["misses"] if tel is not None else 0
+        entry = _cache.cached_program(
+            comm,
+            ("program", compute, descs, split, static),
+            lambda: _build_program(comm, compute, operands, args, split, static),
+        )
+        if entry is not _SLOW:
+            prog, rshape, rdtype, rsplit = entry
+            try:
+                res = (
+                    prog(*args)
+                    if tel is None and not _cache.recording()
+                    else _run_prog(tel, "dispatch.program", compute, prog, args, _cache._STATS["misses"] == m0)
+                )
+            except Exception as e:
+                if _MEMLEDGER is not None:
+                    _MEMLEDGER.note_oom(e, "dispatch.program", None)
+                raise
+            if _FLIGHTREC is not None:
+                _FLIGHTREC.record_dispatch(_op_name(compute))
+            ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, proto.device, comm)
+            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.program")
+    result = compute(*args, *static)
+    if split is not None and split >= result.ndim:
+        split = None
+    result = comm.shard(result, split)
+    ret = DNDarray(
+        result,
+        tuple(result.shape),
+        types.canonical_heat_type(result.dtype),
+        split,
+        proto.device,
+        comm,
+        True,
+    )
+    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.program.general")
+
+
+def _build_program(comm, compute, operands, args, split, static):
+    if any(isinstance(t, DNDarray) and t._pad for t in operands):
+        return _SLOW  # padded operands: compute sees the logical arrays
+    return _compile_tail(comm, lambda *a: compute(*a, *static), split, *args)
 
 
 # telemetry may have been armed before this module finished importing

@@ -1,10 +1,16 @@
 """Pairwise distances (reference: ``heat/spatial/distance.py``).
 
 The reference's both-split case is a ring algorithm: the X block stays put,
-Y blocks circulate via Isend/Irecv (SURVEY §2.4).  Here the default path is
-one sharded computation (GSPMD chooses the data movement — typically an
-all-gather of the smaller operand over ICI); the explicit ring is available
-as ``cdist_ring`` built on ``parallel.ring_map`` for the memory-constrained
+Y blocks circulate via Isend/Irecv (SURVEY §2.4).  Here a call of ``cdist``,
+``rbf`` or ``manhattan`` is ONE sharded program (GSPMD chooses the data
+movement — typically an all-gather of the smaller operand over ICI): the
+whole expression is a module-level compute function, compiled once per
+operand signature into the dispatch layer's program cache and launched once
+a call (``core._operations._program_op``), its result already on the
+canonical sharding.  Operands that cannot key a program — tracers under the
+caller's own ``jax.jit``, padded (ragged) operands, a ragged result — run
+the same compute function op by op.  The explicit ring is available as
+``cdist_ring`` built on ``parallel.ring_map`` for the memory-constrained
 regime where only one rotating block may be resident at a time.
 """
 
@@ -15,6 +21,7 @@ from typing import Optional
 import jax.numpy as jnp
 
 from ..core import types
+from ..core._operations import _program_op
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 
@@ -30,6 +37,10 @@ def _wrap(jarr, split, proto: DNDarray) -> DNDarray:
     )
 
 
+# ---------------------------------------------------------------------- #
+# compute functions: plain jnp on the operands' arrays, module-level so
+# that their identity keys the program cache
+# ---------------------------------------------------------------------- #
 def _sq_euclid(x, y):
     # quadratic expansion: ||x||² + ||y||² − 2 x·yᵀ — one big MXU GEMM
     xx = jnp.sum(x * x, axis=1, keepdims=True)
@@ -38,25 +49,49 @@ def _sq_euclid(x, y):
     return jnp.maximum(d2, 0.0)
 
 
-def cdist(x: DNDarray, y: Optional[DNDarray] = None, quadratic_expansion: bool = False) -> DNDarray:
-    """Euclidean distance matrix between rows of ``x`` and ``y``.
+def _sq_direct(x, y):
+    # direct form, still batched: (n,1,d)-(1,m,d) — better precision
+    return jnp.sum((x[:, None, :] - y[None, :, :]) ** 2, axis=-1)
 
-    ``quadratic_expansion=True`` uses the GEMM form (MXU-friendly; the TPU
-    default regardless, since the expansion maps the whole computation onto
-    the systolic array).
-    """
+
+def _cdist_quadratic(x, y):
+    return jnp.sqrt(_sq_euclid(x, y))
+
+
+def _cdist_direct(x, y):
+    return jnp.sqrt(jnp.maximum(_sq_direct(x, y), 0.0))
+
+
+def _manhattan(x, y):
+    return jnp.sum(jnp.abs(x[:, None, :] - y[None, :, :]), axis=-1)
+
+
+def _rbf(x, y, scale, quadratic):
+    d2 = _sq_euclid(x, y) if quadratic else _sq_direct(x, y)
+    return jnp.exp(-d2 / scale)
+
+
+def _pairwise(compute, x, y, *scalars, static=()) -> DNDarray:
+    """``compute`` over the rows of ``x`` and ``y`` (``x`` again if None) as
+    one program; the result is split along the rows of whichever is."""
     sanitize_in(x)
     if y is None:
         y = x
     sanitize_in(y)
-    jx, jy = x._jarray, y._jarray
-    if quadratic_expansion:
-        d = jnp.sqrt(_sq_euclid(jx, jy))
-    else:
-        # direct form, still batched: (n,1,d)-(1,m,d) — better precision
-        d = jnp.sqrt(jnp.maximum(jnp.sum((jx[:, None, :] - jy[None, :, :]) ** 2, axis=-1), 0.0))
     split = 0 if x.split == 0 else (1 if y.split == 0 else None)
-    return _wrap(d, split, x)
+    return _program_op(compute, (x, y, *scalars), split, static)
+
+
+def cdist(x: DNDarray, y: Optional[DNDarray] = None, quadratic_expansion: bool = False) -> DNDarray:
+    """Euclidean distance matrix between rows of ``x`` and ``y``.
+
+    ``quadratic_expansion=True`` uses the GEMM form (MXU-friendly: the
+    expansion maps the computation onto the systolic array); the default is
+    the direct form, of better precision.  Either is one cached program a
+    call; see the module's docstring for when the same expression runs op by
+    op instead.
+    """
+    return _pairwise(_cdist_quadratic if quadratic_expansion else _cdist_direct, x, y)
 
 
 def cdist_small(x: DNDarray, y: Optional[DNDarray] = None, quadratic_expansion: bool = False) -> DNDarray:
@@ -65,25 +100,13 @@ def cdist_small(x: DNDarray, y: Optional[DNDarray] = None, quadratic_expansion: 
 
 def manhattan(x: DNDarray, y: Optional[DNDarray] = None, expand: bool = False) -> DNDarray:
     """City-block distance matrix."""
-    sanitize_in(x)
-    if y is None:
-        y = x
-    d = jnp.sum(jnp.abs(x._jarray[:, None, :] - y._jarray[None, :, :]), axis=-1)
-    split = 0 if x.split == 0 else (1 if y.split == 0 else None)
-    return _wrap(d, split, x)
+    return _pairwise(_manhattan, x, y)
 
 
 def rbf(x: DNDarray, y: Optional[DNDarray] = None, sigma: float = 1.0, quadratic_expansion: bool = False) -> DNDarray:
-    """Gaussian RBF kernel matrix exp(−d²/(2σ²))."""
-    sanitize_in(x)
-    if y is None:
-        y = x
-    d2 = _sq_euclid(x._jarray, y._jarray) if quadratic_expansion else jnp.sum(
-        (x._jarray[:, None, :] - y._jarray[None, :, :]) ** 2, axis=-1
-    )
-    k = jnp.exp(-d2 / (2.0 * sigma * sigma))
-    split = 0 if x.split == 0 else (1 if y.split == 0 else None)
-    return _wrap(k, split, x)
+    """Gaussian RBF kernel matrix exp(−d²/(2σ²)).  ``sigma`` reaches the
+    program as an argument: a new value compiles nothing."""
+    return _pairwise(_rbf, x, y, 2.0 * sigma * sigma, static=(bool(quadratic_expansion),))
 
 
 def cdist_ring(x: DNDarray, y: Optional[DNDarray] = None) -> DNDarray:
@@ -118,4 +141,4 @@ def cdist_ring(x: DNDarray, y: Optional[DNDarray] = None) -> DNDarray:
 def _cdist_ring_step(x_blk, y_blk, src):
     # module-level (stable identity) so ring_map's comm-cached program is
     # reused across cdist_ring calls instead of recompiling per call
-    return jnp.sqrt(_sq_euclid(x_blk, y_blk))
+    return _cdist_quadratic(x_blk, y_blk)

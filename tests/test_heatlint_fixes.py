@@ -993,7 +993,7 @@ class TestSplitMig:
         plan = splitmig.build_plan(split_inventory, program_holder[0], contexts)
         for s in plan["sites"]:
             s["path"] = os.path.relpath(s["path"], REPO).replace(os.sep, "/")
-        assert plan["count"] == committed["count"] == inv["count"] == 412
+        assert plan["count"] == committed["count"] == inv["count"] == 413
         assert plan == committed
         # every inventory site is covered, keyed identically
         key = lambda s: (s["path"], s["line"], s["kind"], s["detail"])  # noqa: E731

@@ -1,8 +1,8 @@
 """Program spans (ISSUE 25): a span is a ``jax.profiler.TraceAnnotation`` named
 ``ht.<layer>.<what>``, made only while a profile records.  With nothing
 recording an eager op makes no annotation; under a profile recorded here on
-the CPU the dispatch helpers, ``ht.matmul`` and a real ``resplit`` give their
-named spans, properly nested; telemetry's ring still gets its records."""
+the CPU the dispatch helpers, ``ht.matmul``, ``ht.spatial.cdist`` (a library
+function as one program, ISSUE 29) and a real ``resplit`` give their named spans, properly nested; telemetry's ring still gets its records."""
 
 import glob
 import os
@@ -18,7 +18,7 @@ from heat_tpu.core import _cache, _operations
 from heat_tpu.core.communication import Communication
 from heat_tpu.utils import profiler, telemetry
 
-KINDS = ("local", "binary", "reduce", "cum", "matmul")
+KINDS = ("local", "binary", "reduce", "cum", "matmul", "program")
 
 
 @pytest.fixture(autouse=True)
@@ -48,6 +48,7 @@ OPS = {
     "cum": lambda a: _operations._cum_op(jnp.cumsum, a, 0),
     "matmul": lambda a: ht.matmul(a, a),
     "dot": lambda a: ht.dot(a[0], a[1]),
+    "program": lambda a: ht.spatial.cdist(a, quadratic_expansion=True),
 }
 
 
@@ -142,8 +143,8 @@ def test_dispatch_span_holds_its_launch(recorded, kind):
     assert len(spans) == want
     launches = [e for e in recorded if e[0] == _cache.LAUNCH_SPAN]
     first = spans[0]
-    assert first[3]["op"] == {"local": "sin", "binary": "add", "reduce": "sum",
-                              "cum": "cumsum", "matmul": "matmul"}[kind]
+    assert first[3]["op"] == {"local": "sin", "binary": "add", "reduce": "sum", "cum": "cumsum",
+                              "matmul": "matmul", "program": "_cdist_quadratic"}[kind]
     assert sum(_inside(l, first) for l in launches) == 1
     if kind == "matmul":
         assert spans[1][3]["op"] == "dot" and sum(_inside(l, spans[1]) for l in launches) == 1
@@ -187,7 +188,7 @@ def test_resplit_span_with_telemetry_disabled(recorded):
 # ---------------------------------------------------------------------- #
 # telemetry enabled
 # ---------------------------------------------------------------------- #
-@pytest.mark.parametrize("kind", KINDS[:4])
+@pytest.mark.parametrize("kind", [k for k in KINDS if k != "matmul"])
 def test_ring_still_gets_its_dispatch_record(kind, a):
     OPS[kind](a)
     telemetry.enable()
