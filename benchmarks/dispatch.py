@@ -398,8 +398,8 @@ def main(argv=None) -> int:
     sync = profiler.sync
     n = args.size
 
-    x = ht.random.randn(n, n, split=ht.axisspec.named(0))
-    y = ht.random.randn(n, n, split=ht.axisspec.named(0))
+    x = ht.random.randn(n, n, split=0)
+    y = ht.random.randn(n, n, split=0)
 
     # --- compiled-program floor ---------------------------------------- #
     # a pre-built jitted (add + placement) on the raw arrays: the fastest
@@ -634,13 +634,13 @@ def main(argv=None) -> int:
 
     # --- reduction + matmul cached latencies --------------------------- #
     reduce_us = _time_op(lambda: ht.sum(x, axis=0), sync, args.reps)
-    mm_a = ht.random.randn(n, n, split=ht.axisspec.named(0))
-    mm_b = ht.random.randn(n, n, split=ht.axisspec.named(1))
+    mm_a = ht.random.randn(n, n, split=0)
+    mm_b = ht.random.randn(n, n, split=1)
     _ = mm_a @ mm_b
     matmul_us = _time_op(lambda: mm_a @ mm_b, sync, args.reps)
 
     # --- in-place donation surfaces ------------------------------------ #
-    z = ht.random.randn(n, n, split=ht.axisspec.named(0))
+    z = ht.random.randn(n, n, split=0)
     z += 1.0  # warm the donating program
     iadd_us = _time_op((lambda: z.__iadd__(1.0)), sync, max(args.reps // 2, 5))
     prog_alias = "unknown"
@@ -659,7 +659,7 @@ def main(argv=None) -> int:
     # memory_budget=0 pins the monolithic path throughout: these rows are
     # labeled monolithic and must not silently stream under an inherited
     # HEAT_TPU_RESPLIT_BUDGET / process default
-    r = ht.random.randn(n, n, split=ht.axisspec.named(0))
+    r = ht.random.randn(n, n, split=0)
     r.resplit_(1, memory_budget=0)  # warm both directions
     r.resplit_(0, memory_budget=0)
 
@@ -667,7 +667,7 @@ def main(argv=None) -> int:
         r.resplit_(1 if r.split == 0 else 0, memory_budget=0)
         return r
 
-    rc0 = ht.random.randn(n, n, split=ht.axisspec.named(0))
+    rc0 = ht.random.randn(n, n, split=0)
     rc1 = rc0.resplit(1, memory_budget=0)
     copy_state = [0]
 

@@ -4,11 +4,11 @@ One process drives the three main paths through their ordinary entry points
 over the default mesh of every attached chip: the ``ht.*`` array path at
 BASELINE widths, the two trainers (``DataParallel`` MLP, ``DASO`` ResNet-50),
 and the model layer with the Pallas kernels engaged (``TransformerLM``, the
-flash-attention family against the dense reference, the KMeans kernel); on
-more than one chip also the ring, expert-parallel, pipeline and two-tier DASO
-paths.  Every phase checks its result (shape, finiteness, agreement with a
-reference, placement on every chip) and the first fault raises: there is no
-``try`` that continues, no fallback to the CPU and no subprocess.
+flash-attention family against the dense reference); on more than one chip
+also the ring, expert-parallel, pipeline and two-tier DASO paths.  Every
+phase checks its result (shape, finiteness, agreement with a reference,
+placement on every chip) and the first fault raises: there is no ``try``
+that continues, no fallback to the CPU and no subprocess.
 
 ``python chip_smoke.py`` demands the TPU: ``jax_platforms`` is pinned to
 ``tpu`` before first device use, so without a chip jax raises, nothing is
@@ -49,7 +49,7 @@ from heat_tpu.parallel.ring_attention import (
     _block_impl, path_counts as ring_counts, ring_attention,
 )
 
-# BASELINE.json's own widths (configs 0-4) and bench.py's model-layer rows
+# BASELINE.json's own widths (configs 0-4) and the model-layer sizes
 FULL = dict(
     matmul_n=16384,
     resplit_n=16384,
@@ -62,7 +62,6 @@ FULL = dict(
     lm=dict(vocab_size=32768, embed_dim=512, num_heads=8, depth=8, max_len=1024),
     lm_batch=8, lm_seq=1024, lm_prompt=64, lm_new=64,
     attn=(4, 8, 4096, 64), attn_kv_heads=2, attn_long=(2, 8, 32768, 64),
-    kmeans_kernel=(2**20, 32, 64),
     ring=(2, 8, 4096, 64),  # S is per chip
     moe=dict(embed=1024, hidden=4096, experts_per_chip=8, tokens_per_chip=512),
     pipe=dict(embed=512, heads=8, seq=1024, batch_per_chip=2),
@@ -430,29 +429,6 @@ def model_flash(shape, kv_heads: int, long_shape) -> None:
           **{f"{n}_rel_err": f"{e:.2e}" for n, e in errs.items()})
 
 
-def model_kmeans_kernel(rows: int, d: int, k: int) -> None:
-    """The opt-in fused E+M Pallas kernel against the jnp arm."""
-    t0 = time.perf_counter()
-    ht.random.seed(5)
-    x = ht.random.randn(rows, d, split=0)
-    fits = {
-        arm: ht.cluster.KMeans(n_clusters=k, init="random", max_iter=3, tol=0.0,
-                               random_state=0, assign_kernel=arm).fit(x)
-        for arm in ("pallas", "jnp")
-    }
-    err = _rel_err(fits["pallas"].cluster_centers_._jarray,
-                   fits["jnp"].cluster_centers_._jarray)
-    # An f32 product at default precision runs in bf16 passes in XLA's arm
-    # and not in the kernel's, so points near a boundary change cluster
-    # (1.4e-3 of the largest coordinate after three iterations on the v5e);
-    # a fault in the kernel's masking or accumulation would be of order 1.
-    assert err < 1e-2, f"pallas vs jnp centers: {err}"
-    gap = abs(fits["pallas"].inertia_ - fits["jnp"].inertia_) / fits["jnp"].inertia_
-    assert gap < 1e-2, gap
-    _done("model.kmeans_kernel", t0, shape=f"{rows}x{d}", k=k,
-          centers_rel_err=f"{err:.2e}", inertia_rel_gap=f"{gap:.2e}")
-
-
 # ---------------------------------------------------------------------- #
 # more than one chip
 # ---------------------------------------------------------------------- #
@@ -543,7 +519,6 @@ def run(s: dict) -> None:
                            s["daso_batch_per_chip"]),
         lambda: model_lm(s["lm"], s["lm_batch"], s["lm_seq"], s["lm_prompt"], s["lm_new"]),
         lambda: model_flash(s["attn"], s["attn_kv_heads"], s["attn_long"]),
-        lambda: model_kmeans_kernel(*s["kmeans_kernel"]),
     ]
     n = len(jax.devices())
     if n > 1:

@@ -9,7 +9,6 @@ flat namespace.
 
 from .core import *
 from . import core
-from .core import axisspec
 from .core import random
 from .core.redistribution import set_redistribution_budget, get_redistribution_budget
 from .core.collectives import set_grad_bucket_budget, get_grad_bucket_budget

@@ -15,10 +15,8 @@ Two halves, one contract set:
   lattice + symbolic ``(gshape, split, dtype)`` metadata domain
   (rank-tainted collective flow, split mismatch, payload asymmetry,
   donation-size mismatch) — each the static twin of a runtime failure
-  mode.  The same pass emits the ``--split-inventory`` catalog of every
-  single-split-axis assumption (the mesh-refactor work list).  Gates CI
-  against a committed baseline; unresolved-call conclusions are
-  downgraded to non-gating ``info``.
+  mode.  Gates CI against a committed baseline; unresolved-call
+  conclusions are downgraded to non-gating ``info``.
 - **heatfix** (:mod:`.fixes`): the proof-carrying autofix layer — fixers
   registered per rule emit span splices ONLY when a safety proof holds
   (0-d + untraced host syncs → ``Communication.host_fetch``, literal-seed
@@ -26,11 +24,6 @@ Two halves, one contract set:
   ``with comm.deadline(...)``, stale suppressions → deleted), with
   mandatory post-fix re-lint and a fix∘fix = fix idempotence assertion;
   refusal reasons ship in ``--json`` (the honesty policy, fix edition).
-- **splitmig** (:mod:`.splitmig`): the mesh-migration codemod planner —
-  classifies every split-inventory site mechanical-vs-semantic, orders
-  them into call-graph dependency tranches (committed, drift-gated
-  ``MIGRATION_PLAN.json``), and executes mechanical tranches against the
-  ``core/axisspec.py`` shim.
 - **runtime sanitizer** (:mod:`heat_tpu.core.sanitation`, armed by
   ``HEAT_TPU_CHECKS=1``): a metadata-only validator at the dispatch tails
   and factory/resplit boundaries — the dynamic complement for what the
@@ -64,7 +57,6 @@ from . import summaries  # noqa: F401
 from . import absint  # noqa: F401
 from . import rules  # noqa: F401  — registers the built-in rules on import
 from . import fixes  # noqa: F401  — registers the built-in fixers on import
-from . import splitmig  # noqa: F401
 from . import timeline  # noqa: F401
 
 __all__ = [
@@ -85,7 +77,6 @@ __all__ = [
     "render_text",
     "rules",
     "split_by_baseline",
-    "splitmig",
     "summaries",
     "timeline",
     "write_baseline",

@@ -48,12 +48,6 @@ recorded call descriptors against the program call graph (``record=False``
 computes the return-taint / param-sink / metadata resolutions the HT301–
 HT304 rules consume.
 
-The **split inventory** falls out of the same pass: every site whose
-behavior depends on single-``split``-axis semantics (``.split`` reads,
-``split=`` keywords, ``resplit*`` calls, ``split`` parameters) is cataloged
-with its enclosing qualname — the machine-readable work list for the
-named-axis mesh refactor (``scripts/heatlint.py --split-inventory``).
-
 Stdlib-only and standalone-loadable, like the rest of ``analysis/``.
 """
 
@@ -255,10 +249,10 @@ class _Interp:
 
     Produces the serializable per-function fact record: the call list with
     per-argument taint/metadata, collective sites, rank-taintable control-
-    flow sites, binary-op sites, return taint/metadata, and split-inventory
-    atoms.  All records are keyed by source position, so the loop-fixpoint
-    re-walks update them in place instead of duplicating — the final pass
-    (fixpoint env) wins, and call ids stay stable across passes.
+    flow sites, binary-op sites and return taint/metadata.  All records
+    are keyed by source position, so the loop-fixpoint re-walks update them
+    in place instead of duplicating — the final pass (fixpoint env) wins,
+    and call ids stay stable across passes.
     Everything downstream (verdicts, findings) happens at link time against
     the program call graph.
     """
@@ -285,7 +279,6 @@ class _Interp:
         # lets tuple unpacking at call sites bind element-precise taint
         # instead of smearing one tainted element over every target
         self.ret_tuple: object = "unset"
-        self.inventory: Dict[Tuple[str, int, str], dict] = {}
         # stack of region collectors (branch arms / loop bodies):
         # colls keyed (line, name) so fixpoint re-walks don't duplicate
         self._regions: List[dict] = []
@@ -304,8 +297,6 @@ class _Interp:
             taint = {_tok_param(i)}
             if name in self.RANK_NAMES:
                 taint.add(_TOK_RANK)
-            if name == "split":
-                self._inv("split-param", self.fn.lineno, name)
             env[name] = (frozenset(taint), None)
         self._stmts(self.fn.body, env)
         return {
@@ -321,15 +312,6 @@ class _Interp:
                 else None
             ),
             "ret_metas": [self.ret_metas[k] for k in sorted(self.ret_metas)],
-            "inventory": [self.inventory[k] for k in sorted(self.inventory)],
-        }
-
-    def _inv(self, kind: str, line: int, detail: str) -> None:
-        self.inventory[(kind, line, detail)] = {
-            "kind": kind,
-            "line": line,
-            "qualname": self.qual,
-            "detail": detail,
         }
 
     # ---------------- statements ---------------- #
@@ -601,7 +583,6 @@ class _Interp:
             if node.attr in self.RANK_ATTRS:
                 return base_t | {_TOK_RANK}, None
             if node.attr == "split" and isinstance(getattr(node, "ctx", None), ast.Load):
-                self._inv("split-read", node.lineno, node.attr)
                 return frozenset(), None  # metadata is rank-uniform
             return base_t, None
         if isinstance(node, ast.Call):
@@ -690,23 +671,6 @@ class _Interp:
             node.value is None or isinstance(node.value, int)
         ):
             return node.value
-        # the core/axisspec shim's `named(<literal>)` IS the literal it
-        # wraps (AxisSpec subclasses int; split ↔ named-spec translation is
-        # value-preserving by contract, round-trip tested) — migrated call
-        # sites keep their concrete split in the metadata domain AND the
-        # split inventory, so executing a migration tranche cannot drift
-        # the committed catalogs
-        if (
-            isinstance(node, ast.Call)
-            and last_attr(node) == "named"
-            and len(node.args) == 1
-            and not node.keywords
-        ):
-            inner = node.args[0]
-            if isinstance(inner, ast.Constant) and (
-                inner.value is None or isinstance(inner.value, int)
-            ):
-                return inner.value
         return "?"
 
     def _literal_dims(self, node: ast.expr, env) -> Tuple[object, set]:
@@ -858,13 +822,6 @@ class _Interp:
             key = kw.arg or "**"
             kw_taints[key] = sorted(t)
             kw_metas[key] = m
-            if kw.arg == "split":
-                callee = call_name(node) or last_attr(node) or "<dynamic>"
-                self._inv(
-                    "split-kwarg",
-                    node.lineno,
-                    f"{callee}(split={self._literal_split(kw.value)})",
-                )
         # keyed by START + END position: `f(x)(y)` puts the inner call and
         # the outer call at the SAME (line, col) — only the end offsets
         # tell them apart, and a collision would overwrite the inner
@@ -942,7 +899,6 @@ class _Interp:
                 if kw.arg in ("axis", "split"):
                     split_arg = kw.value
             new_split = self._literal_split(split_arg) if split_arg is not None else "?"
-            self._inv("resplit-call", node.lineno, f"{la}({new_split})")
             cid, _rec = self._record_call(node, env)
             for frame in self._regions:
                 frame["colls"][(node.lineno, la)] = la
@@ -1015,33 +971,12 @@ class _Interp:
 # ------------------------------------------------------------------ #
 
 
-def _module_inventory(ctx) -> List[dict]:
-    """Split-semantics sites outside any def (module-level code)."""
-    out: List[dict] = []
-    for node in ctx.walk(ast.Attribute):
-        if (
-            node.attr == "split"
-            and ctx.enclosing_function(node) is None
-            and isinstance(getattr(node, "ctx", None), ast.Load)
-        ):
-            out.append(
-                {
-                    "kind": "split-read",
-                    "line": node.lineno,
-                    "qualname": ctx.qualname(node),
-                    "detail": "split",
-                }
-            )
-    return out
-
-
 def extract_absint(ctx) -> dict:
-    """Serializable abstract-interpretation facts for every def in ``ctx``
-    plus the module-level split inventory."""
+    """Serializable abstract-interpretation facts for every def in ``ctx``."""
     functions: Dict[str, dict] = {}
     for node in ctx.walk(ast.FunctionDef, ast.AsyncFunctionDef):
         functions[ctx.qualname(node)] = _Interp(ctx, node).run()
-    return {"functions": functions, "module_inventory": _module_inventory(ctx)}
+    return {"functions": functions}
 
 
 # ------------------------------------------------------------------ #
@@ -1093,27 +1028,9 @@ class AbsintView:
     def __init__(self, program, facts_by_path: Dict[str, dict]):
         self.program = program
         self.functions: Dict[FuncKey, dict] = {}
-        self.inventory: List[dict] = []
         for path in sorted(facts_by_path):
-            fact = facts_by_path[path]
-            # the analysis layer's own split vocabulary is subject matter,
-            # not runtime behavior — keep it out of the refactor work list;
-            # same for core/axisspec.py: the split ↔ named-spec shim IS the
-            # migration machinery, and counting its translation params
-            # would grow the denominator the moment the executor landed
-            in_inventory = "/analysis/" not in f"/{path}" and not path.endswith(
-                "core/axisspec.py"
-            )
-            for qual in fact.get("functions", {}):
-                rec = fact["functions"][qual]
+            for qual, rec in facts_by_path[path].get("functions", {}).items():
                 self.functions[(path, qual)] = rec
-                if in_inventory:
-                    for item in rec.get("inventory", ()):
-                        self.inventory.append(dict(item, path=path))
-            if in_inventory:
-                for item in fact.get("module_inventory", ()):
-                    self.inventory.append(dict(item, path=path))
-        self.inventory.sort(key=lambda d: (d["path"], d["line"], d["kind"], d["detail"]))
         # resolve the absint call lists (record=False: the effect pass
         # already audited these sites into the honesty bucket)
         self.resolved: Dict[FuncKey, list] = {}

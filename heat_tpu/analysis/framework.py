@@ -414,7 +414,6 @@ def lint_paths(
     select: Optional[Sequence[str]] = None,
     cache_path: Optional[str] = None,
     unresolved_out: Optional[List[dict]] = None,
-    split_inventory_out: Optional[List[dict]] = None,
     contexts_out: Optional[Dict[str, "LintContext"]] = None,
     program_out: Optional[List] = None,
 ) -> List[Finding]:
@@ -424,11 +423,9 @@ def lint_paths(
     file content hash; None disables caching).  When ``unresolved_out`` is
     given, the call graph's unresolved bucket (every unresolvable call with
     its reason — the honesty policy's audit trail) is appended to it.
-    When ``split_inventory_out`` is given, the absint layer's catalog of
-    every split-semantics site (the mesh-refactor work list) is appended.
     ``contexts_out``/``program_out`` hand the parsed contexts and the built
-    Program back to the caller (the autofix engine and migration planner
-    reuse them instead of re-parsing the repo); ``program_out`` forces the
+    Program back to the caller (the autofix engine reuses them instead of
+    re-parsing the repo); ``program_out`` forces the
     program build even when no program-level rule is selected."""
     rules = all_rules(select)
     file_rules = [r for r in rules if not r.program_level]
@@ -446,11 +443,7 @@ def lint_paths(
             if rule.code in disabled:
                 continue
             findings.extend(f for f in rule.check(ctx) if f is not None)
-    need_program = (
-        bool(program_rules)
-        or split_inventory_out is not None
-        or program_out is not None
-    )
+    need_program = bool(program_rules) or program_out is not None
     if need_program and contexts:
         from . import summaries as _summaries  # lazy: only when HT2xx selected
 
@@ -462,8 +455,6 @@ def lint_paths(
                 findings.append(f)
         if unresolved_out is not None:
             unresolved_out.extend(program.graph.unresolved)
-        if split_inventory_out is not None:
-            split_inventory_out.extend(program.absint.inventory)
         if program_out is not None:
             program_out.append(program)
     if contexts_out is not None:

@@ -67,10 +67,11 @@ CACHE_VERSION = 2  # JSON layout of the cache file
 # gains/changes fact atoms so pre-existing caches (keyed by file content
 # hash, which cannot see analyzer changes) become misses instead of
 # silently serving summaries that lack the new facts.
-# rev 2: absint records (rank-taint + array-metadata + split inventory)
-# rev 3: ISSUE 13 — item-on-materialized-data sink exemption, axisspec
-# named()-aware _literal_split, materializer-collective HT301 exclusion
-ANALYSIS_SCHEMA_REV = 3
+# rev 2: absint records (rank-taint + array-metadata)
+# rev 3: ISSUE 13 — item-on-materialized-data sink exemption,
+# materializer-collective HT301 exclusion
+# rev 4: ISSUE 30 — the per-function and per-module inventory records are gone
+ANALYSIS_SCHEMA_REV = 4
 _EXPAND_CAP = 160  # atoms per expanded footprint before truncation
 _CHAIN_CAP = 12  # hops kept in a provenance chain
 

@@ -162,16 +162,14 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         raise NotImplementedError()
 
     @classmethod
-    def _em_step(cls, jx, centers, use_kernel: bool = False):
+    def _em_step(cls, jx, centers):
         """One Lloyd iteration: new centers from current ones.  Default =
-        assign then update (two passes over X); subclasses may fuse.
-        ``use_kernel`` requests the Pallas E+M path where a subclass has
-        one (base classes ignore it)."""
+        assign then update (two passes over X); subclasses may fuse."""
         labels, _ = cls._assign(jx, centers)
         return cls._update(jx, labels, centers)
 
     @classmethod
-    def _fit_program(cls, use_kernel: bool = False):
+    def _fit_program(cls):
         """The WHOLE Lloyd iteration as one compiled XLA program
         (lax.while_loop, SURVEY §3.4) — a single device dispatch per fit,
         no per-iteration host round-trips.  Cached per class so repeated
@@ -181,7 +179,7 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             cache = {}
             cls._FIT_PROGRAM = cache
         # the E/M block size is baked into the trace — key the cache on it
-        prog = cache.get((_KCluster._ASSIGN_BLOCK, use_kernel))
+        prog = cache.get(_KCluster._ASSIGN_BLOCK)
         if prog is None:
 
             @jax.jit
@@ -192,7 +190,7 @@ class _KCluster(ClusteringMixin, BaseEstimator):
 
                 def body(state):
                     centers, it, _ = state
-                    new = cls._em_step(jx, centers, use_kernel)
+                    new = cls._em_step(jx, centers)
                     return new, it + 1, jnp.max(jnp.abs(new - centers))
 
                 centers, n_iter, _ = jax.lax.while_loop(
@@ -201,7 +199,7 @@ class _KCluster(ClusteringMixin, BaseEstimator):
                 labels, d2 = cls._assign(jx, centers)
                 return centers, labels, jnp.sum(d2), n_iter
 
-            cache[(_KCluster._ASSIGN_BLOCK, use_kernel)] = prog
+            cache[_KCluster._ASSIGN_BLOCK] = prog
         return prog
 
     def fit(self, x: DNDarray):
@@ -222,9 +220,8 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             and x.split == 0
             and x.comm.is_distributed()
         )
-        use_kernel = bool(getattr(self, "_kernel_enabled", False))
         if use_sharded:
-            prog = self._fit_program_sharded(x.comm, use_kernel)
+            prog = self._fit_program_sharded(x.comm)
             centers, labels_phys, inertia, n_iter = prog(
                 x._masked(0),  # pads must be zero, not dead garbage
                 centers0,
@@ -246,12 +243,7 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             return self
 
         jx = x._jarray
-        if x.comm.is_distributed():
-            # global-path fits on a multi-device mesh: a Mosaic kernel cannot
-            # be auto-partitioned (jax refuses to lower it inside a jit over
-            # more than one device) — keep the jnp program
-            use_kernel = False
-        centers, labels, inertia, n_iter = self._fit_program(use_kernel)(
+        centers, labels, inertia, n_iter = self._fit_program()(
             jx, centers0, jnp.asarray(self.max_iter), jnp.asarray(self.tol, centers0.dtype)
         )
         n_iter = int(n_iter)
@@ -272,16 +264,7 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         from ..core.sanitation import sanitize_in
 
         sanitize_in(x)
-        # a Mosaic kernel cannot be auto-partitioned: on a multi-device mesh
-        # the jnp path stays GSPMD-partitioned
-        use_kernel = (getattr(self, "_kernel_enabled", False)
-                      and not x.comm.is_distributed())
-        if use_kernel:
-            from ..ops.kmeans_kernels import fused_assign
-
-            labels, _ = fused_assign(x._jarray, self._cluster_centers._jarray)
-        else:
-            labels, _ = self._assign(x._jarray, self._cluster_centers._jarray)
+        labels, _ = self._assign(x._jarray, self._cluster_centers._jarray)
         lab = x.comm.shard(labels, x.split)
         return DNDarray(
             lab, tuple(lab.shape), types.canonical_heat_type(lab.dtype), x.split, x.device, x.comm, True

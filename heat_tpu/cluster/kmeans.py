@@ -31,27 +31,11 @@ class KMeans(_KCluster):
         max_iter: int = 300,
         tol: float = 1e-4,
         random_state: Optional[int] = None,
-        assign_kernel: str = "auto",
     ):
         super().__init__(
             metric=lambda x, y: None, n_clusters=n_clusters, init=init,
             max_iter=max_iter, tol=tol, random_state=random_state,
         )
-        if assign_kernel not in ("auto", "pallas", "jnp"):
-            raise ValueError(
-                f"assign_kernel must be 'auto', 'pallas' or 'jnp', got {assign_kernel!r}"
-            )
-        # 'pallas' routes the E-step (fit: fused assign+stats; predict:
-        # fused assign) through ops.kmeans_kernels on TPU, jnp elsewhere.
-        # 'auto' currently resolves to the jnp path: XLA's own fusion
-        # measured faster at the benched (1e6-1e8)x32, k=64 workloads on
-        # v5e (see kmeans_kernels module docstring + BENCH kernel-on/off
-        # rows); flip here if a future measurement inverts.
-        self.assign_kernel = assign_kernel
-
-    @property
-    def _kernel_enabled(self) -> bool:
-        return self.assign_kernel == "pallas"
 
     @staticmethod
     def _blocked_stats(jx, k, label_fn):
@@ -116,19 +100,10 @@ class KMeans(_KCluster):
         return KMeans._centers_from_stats(sums, counts, centers)
 
     @classmethod
-    def _em_step(cls, jx, centers, use_kernel: bool = False):
+    def _em_step(cls, jx, centers):
         """Fused Lloyd iteration: ONE pass over X per iteration — each block
         is read once, assigned, and immediately folded into the (k, d)/(k,)
-        statistics.  Halves HBM traffic vs assign-then-update.
-        ``use_kernel`` runs the Pallas fused E+M grid instead of the jnp
-        blocked loop (same math; see ``ops.kmeans_kernels``)."""
-        if use_kernel:
-            from ..ops.kmeans_kernels import fused_em_stats
-
-            sums, counts = fused_em_stats(jx, centers)
-            return cls._centers_from_stats(
-                sums, counts, centers.astype(jnp.float32)
-            ).astype(centers.dtype)
+        statistics.  Halves HBM traffic vs assign-then-update."""
         k = centers.shape[0]
         n = jx.shape[0]
         if n <= _KCluster._ASSIGN_BLOCK:
@@ -153,20 +128,11 @@ class KMeans(_KCluster):
     _supports_sharded_fit = True
 
     @staticmethod
-    def _local_em_stats(jxl, centers, base, n, use_kernel: bool = False):
+    def _local_em_stats(jxl, centers, base, n):
         """Blocked (k, d) sums + (k,) counts over one shard's LOCAL rows
         ``jxl`` (c, d); ``base`` is this shard's global row offset, rows with
         ``base + i >= n`` are pad and get the sentinel label ``k`` (zero
-        onehot row — see ``_blocked_stats``).  ``use_kernel`` runs the
-        Pallas fused E+M grid over the local block instead."""
-        if use_kernel:
-            from ..ops.kmeans_kernels import fused_em_stats
-
-            n_local = jnp.clip(n - base, 0, jxl.shape[0])
-            s, cnt = fused_em_stats(jxl, centers, n_local)
-            # match the jnp path's accumulator dtype: the while_loop carry
-            # (and the psum'd stats) stay in the data dtype
-            return s.astype(jxl.dtype), cnt.astype(jxl.dtype)
+        onehot row — see ``_blocked_stats``)."""
         k = centers.shape[0]
         cc = jnp.sum(centers * centers, axis=1)[:, None]
 
@@ -180,7 +146,7 @@ class KMeans(_KCluster):
         return KMeans._blocked_stats(jxl, k, label_fn)
 
     @classmethod
-    def _fit_program_sharded(cls, comm, use_kernel: bool = False):
+    def _fit_program_sharded(cls, comm):
         """Whole Lloyd iteration as one shard_map'd XLA program over the
         PHYSICAL row-sharded array: per-shard blocked E+M, psum of the
         (k,d)/(k,) statistics, while_loop to convergence, final per-shard
@@ -188,11 +154,11 @@ class KMeans(_KCluster):
         traced operand, so all row counts sharing a padded shape share one
         compile.  Cached on the comm instance (``comm_cached``) so the
         program — which pins mesh + XLA executable — dies with the comm."""
-        return _fit_sharded_program(comm, cls, _KCluster._ASSIGN_BLOCK, use_kernel)
+        return _fit_sharded_program(comm, cls, _KCluster._ASSIGN_BLOCK)
 
 
 @comm_cached
-def _fit_sharded_program(comm, cls, assign_block, use_kernel=False):
+def _fit_sharded_program(comm, cls, assign_block):
     axis = comm.axis
 
     def shard_fn(phys_blk, centers0, n, max_iter, tol):
@@ -200,7 +166,7 @@ def _fit_sharded_program(comm, cls, assign_block, use_kernel=False):
         base = jax.lax.axis_index(axis) * c
 
         def em(centers):
-            s, cnt = cls._local_em_stats(phys_blk, centers, base, n, use_kernel)
+            s, cnt = cls._local_em_stats(phys_blk, centers, base, n)
             s = jax.lax.psum(s, axis)  # the reference's two Allreduces
             cnt = jax.lax.psum(cnt, axis)
             return cls._centers_from_stats(s, cnt, centers)

@@ -3,7 +3,7 @@
 Compiled collective pipelines (shard_map + jit) close over a
 ``Communication``'s mesh and pin XLA executables.  Caching them with
 ``functools.lru_cache`` keyed on the comm strongly pins comm + mesh +
-executables until LRU eviction — the leak ADVICE.md flagged in round 3.
+executables until LRU eviction: a leak once a comm is dropped.
 
 ``comm_cached`` stores each function's programs in a dict ON the comm
 instance (``comm._compiled_programs``), so:

@@ -112,8 +112,7 @@ def _matmul_result_split(sa: Optional[int], sb: Optional[int], nd_out: int) -> O
 # (ties go to GSPMD, the fused default).  No TPU entry: the pair has
 # not been measured on chips (ROADMAP S6), and GSPMD's collective-matmul
 # fusion is the principled TPU default.  These are CPU-mesh timings from
-# before PR 1; PR 21 took the CPU-subprocess row that re-measured them
-# out of bench.py, which is a device benchmark.
+# before PR 1; nothing re-measures them.
 _SUMMA_DISPATCH = {("cpu", 8): 4096}
 
 

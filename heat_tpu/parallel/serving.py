@@ -107,7 +107,7 @@ def make_executor(comm=None) -> Callable[[List[Any]], List[Any]]:
     def _matmul(job) -> dict:
         n = int(job.payload.get("n", 16))
         scale = 1.0 + int(job.payload.get("seed", 0)) % 7
-        a = ht.reshape(ht.arange(n * n, dtype=ht.float32, split=ht.axisspec.named(0)), (n, n))
+        a = ht.reshape(ht.arange(n * n, dtype=ht.float32, split=0), (n, n))
         a = a * (scale / n)
         c = a @ ht.transpose(a)
         return {"digest": _fetch_sum(c), "n": n}
@@ -115,9 +115,9 @@ def make_executor(comm=None) -> Callable[[List[Any]], List[Any]]:
     def _solve(job) -> dict:
         n = int(job.payload.get("n", 8))
         # well-conditioned lower-triangular system, deterministic entries
-        ln = ht.reshape(ht.arange(n * n, dtype=ht.float32, split=ht.axisspec.named(0)), (n, n))
-        a = ht.tril(ln * (1.0 / (n * n))) + ht.eye(n, dtype=ht.float32, split=ht.axisspec.named(0)) * 2.0
-        b = ht.reshape(ht.arange(n, dtype=ht.float32, split=ht.axisspec.named(0)), (n, 1))
+        ln = ht.reshape(ht.arange(n * n, dtype=ht.float32, split=0), (n, n))
+        a = ht.tril(ln * (1.0 / (n * n))) + ht.eye(n, dtype=ht.float32, split=0) * 2.0
+        b = ht.reshape(ht.arange(n, dtype=ht.float32, split=0), (n, 1))
         x = ht.linalg.solve_triangular(a, b, lower=True)
         return {"digest": _fetch_sum(x), "n": n}
 
@@ -129,7 +129,7 @@ def make_executor(comm=None) -> Callable[[List[Any]], List[Any]]:
         rng = np.random.default_rng(int(job.payload.get("seed", 0)))  # heatlint: disable=HT105 payload-seeded, rank-identical
         pts = rng.standard_normal((n, 2)).astype(np.float32)
         pts[: n // 2] += 8.0  # two separable blobs: the fit converges fast
-        x = ht.array(pts, split=ht.axisspec.named(0))
+        x = ht.array(pts, split=0)
         km = ht.cluster.KMeans(n_clusters=k, init="random", max_iter=5,
                                random_state=0)
         km.fit(x)

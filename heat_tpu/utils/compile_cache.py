@@ -1,5 +1,5 @@
 """Placement of JAX's persistent compilation cache: one rule for every entry
-point of this checkout (``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``).
+point of this checkout (``chip_smoke.py``, ``tests/conftest.py``).
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and no
 directory is set in code; otherwise the cache is ``<checkout>/.jax_cache``

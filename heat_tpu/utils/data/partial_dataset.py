@@ -15,7 +15,6 @@ from typing import Optional
 import numpy as np
 
 from ...core import factories
-from ...core import axisspec
 
 __all__ = ["PartialH5Dataset", "PartialH5DataLoaderIter"]
 
@@ -90,7 +89,7 @@ class PartialH5Dataset:
                 for n, arr in block.items():
                     if self.transforms is not None:
                         arr = self.transforms(arr)
-                    out[n] = factories.array(arr, split=axisspec.named(0))
+                    out[n] = factories.array(arr, split=0)
                 yield out if len(out) > 1 else next(iter(out.values()))
         finally:
             stop.set()

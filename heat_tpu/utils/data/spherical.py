@@ -7,7 +7,6 @@ import jax.numpy as jnp
 
 from ...core import factories, types
 from ...core.dndarray import DNDarray
-from ...core import axisspec
 
 __all__ = ["create_spherical_dataset", "create_clusters"]
 
@@ -28,7 +27,7 @@ def create_spherical_dataset(
         pts = jax.random.normal(k, (num_samples_cluster, 3)) * radius + center
         blobs.append(pts)
     data = jnp.concatenate(blobs, axis=0).astype(types.canonical_heat_type(dtype).jax_dtype())
-    return factories.array(data, split=axisspec.named(0))
+    return factories.array(data, split=0)
 
 
 def create_clusters(
@@ -58,4 +57,4 @@ def create_clusters(
         key, sub = jax.random.split(key)
         parts.append(jax.random.normal(sub, (counts[i], n_features)) * stds[i] + means[i])
     data = jnp.concatenate(parts, axis=0)
-    return factories.array(data, split=axisspec.named(0), device=device)
+    return factories.array(data, split=0, device=device)

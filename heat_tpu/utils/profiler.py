@@ -22,7 +22,6 @@ __all__ = [
     "timer",
     "sync",
     "annotate",
-    "timeit_min",
     "cache_stats",
     "reset_cache_stats",
     "cache_hit_rate",
@@ -115,18 +114,6 @@ def cache_hit_rate() -> float:
     s = cache_stats()
     total = s["hits"] + s["misses"]
     return s["hits"] / total if total else 1.0
-
-
-def timeit_min(fn, reps: int = 3) -> float:
-    """Best-of-``reps`` wall-clock seconds of ``fn()``, forcing completion of
-    its result (the benchmark harness's shared timing methodology)."""
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = fn()
-        sync(out)
-        best = min(best, time.perf_counter() - t0)
-    return best
 
 
 def sync(x=None) -> None:

@@ -5,7 +5,7 @@ reference tracks per-PR benchmark regressions; VERDICT r4 item 7).
 
 Loads two bench payloads (either the driver wrapper ``{n, cmd, rc, tail,
 parsed}`` or a direct ``{metric, value, unit, vs_baseline, extra}`` object
-as ``bench.py`` prints it), flattens every numeric row
+as ``benchmarks/dispatch.py`` writes it), flattens every numeric row
 (top-level value + ``extra`` recursively), prints a per-row delta table,
 and flags regressions beyond the threshold.  Direction (higher/lower is
 better) is inferred from the metric name; rows with unknown direction are

@@ -7,9 +7,6 @@ Usage:
     python scripts/heatlint.py heat_tpu/ --sarif out.sarif  # PR annotations
     python scripts/heatlint.py heat_tpu/ --write-baseline   # regenerate
     python scripts/heatlint.py heat_tpu/ --select HT3*      # prefix wildcard
-    python scripts/heatlint.py heat_tpu/ --split-inventory SPLIT_INVENTORY.json
-    python scripts/heatlint.py heat_tpu/ --split-plan MIGRATION_PLAN.json
-    python scripts/heatlint.py heat_tpu/ --split-apply 0    # execute a tranche
     python scripts/heatlint.py heat_tpu/ --fix              # proof-carrying autofix
     python scripts/heatlint.py heat_tpu/ --fix --dry-run-diff
     python scripts/heatlint.py heat_tpu/ --fix-check        # CI: no autofixable news
@@ -49,7 +46,6 @@ from __future__ import annotations
 import argparse
 import importlib
 import importlib.util
-import json
 import os
 import sys
 import types
@@ -82,13 +78,11 @@ def _load_analysis():
     rules = importlib.import_module(name + ".rules")
     pkg.rules = rules
     pkg.fixes = importlib.import_module(name + ".fixes")
-    pkg.splitmig = importlib.import_module(name + ".splitmig")
     return framework
 
 
 _fw = _load_analysis()
 _fixes = sys.modules["_heatlint_analysis.fixes"]
-_splitmig = sys.modules["_heatlint_analysis.splitmig"]
 all_rules = _fw.all_rules
 lint_paths = _fw.lint_paths
 load_baseline = _fw.load_baseline
@@ -145,28 +139,6 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--list-rules", action="store_true", help="list registered rules and exit")
     ap.add_argument(
-        "--split-inventory",
-        metavar="FILE",
-        help="write the split-semantics site catalog (the mesh-refactor "
-        "work list: every .split read, split= kwarg, resplit* call, split "
-        "parameter) as JSON to FILE ('-' = stdout)",
-    )
-    ap.add_argument(
-        "--split-plan",
-        metavar="FILE",
-        help="write the named-axis migration plan (every inventory site "
-        "classified mechanical-vs-semantic and ordered into call-graph "
-        "dependency tranches) as JSON to FILE ('-' = stdout)",
-    )
-    ap.add_argument(
-        "--split-apply",
-        metavar="TRANCHE",
-        type=int,
-        help="execute a migration tranche's mechanical rewrites against the "
-        "core/axisspec.py shim (split=<k> -> split=axisspec.named(<k>)); "
-        "honors --dry-run-diff",
-    )
-    ap.add_argument(
         "--fix",
         action="store_true",
         help="apply every provable autofix (post-fix re-lint + idempotence "
@@ -176,7 +148,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--dry-run-diff",
         action="store_true",
-        help="with --fix/--split-apply: print unified diffs instead of writing",
+        help="with --fix: print unified diffs instead of writing",
     )
     ap.add_argument(
         "--fix-check",
@@ -206,16 +178,8 @@ def main(argv=None) -> int:
         ap.error("--fix and --fix-check are mutually exclusive (apply vs gate)")
     if args.fix and args.write_baseline:
         ap.error("--fix and --write-baseline are mutually exclusive")
-    if (args.fix or args.fix_check) and args.split_apply is not None:
-        # both rewrite (or plan against) the same pre-lint sources: the
-        # second writer would clobber the first's edits, and fix plans
-        # computed pre-apply would render against post-apply sources —
-        # run them as two passes
-        ap.error(
-            "--fix/--fix-check and --split-apply are mutually exclusive (run two passes)"
-        )
-    if args.dry_run_diff and not (args.fix or args.split_apply is not None):
-        ap.error("--dry-run-diff requires --fix or --split-apply")
+    if args.dry_run_diff and not args.fix:
+        ap.error("--dry-run-diff requires --fix")
 
     select = [c for c in (args.select or "").split(",") if c.strip()] or None
     want_fix = args.fix or args.fix_check
@@ -236,11 +200,8 @@ def main(argv=None) -> int:
             )
             return 2
 
-    want_split = args.split_plan or args.split_apply is not None
-    need_extras = want_fix or want_split
     cache_path = None if args.no_cache else args.summaries_cache
     unresolved: list = []
-    split_inventory: list = []
     contexts: dict = {}
     program_holder: list = []
     try:
@@ -249,11 +210,8 @@ def main(argv=None) -> int:
             select=select,
             cache_path=cache_path,
             unresolved_out=unresolved,
-            split_inventory_out=(
-                split_inventory if (args.split_inventory or want_split) else None
-            ),
-            contexts_out=contexts if need_extras else None,
-            program_out=program_holder if need_extras else None,
+            contexts_out=contexts if want_fix else None,
+            program_out=program_holder if want_fix else None,
         )
     except ValueError as exc:
         print(f"heatlint: {exc}", file=sys.stderr)
@@ -280,61 +238,6 @@ def main(argv=None) -> int:
             print(f"heatlint: FIX CONTRACT VIOLATION: {exc}", file=sys.stderr)
             return 2
 
-    # ---- migration plan / tranche execution (pre-normalization) ---- #
-    split_plan_obj = None
-    split_apply_report = None
-    if want_split:
-        split_plan_obj = _splitmig.build_plan(split_inventory, program, contexts)
-        if args.split_apply is not None:
-            edits, skipped = _splitmig.tranche_edits(
-                split_plan_obj, contexts, tranche=args.split_apply
-            )
-            by_path: dict = {}
-            for e in edits:
-                by_path.setdefault(e.path, []).append(e)
-            import difflib
-
-            split_apply_report = {"files": sorted(by_path), "edits": len(edits),
-                                  "skipped": len(skipped)}
-            for path in sorted(by_path):
-                src = contexts[path].source
-                new_src = _fixes.apply_edits(src, by_path[path])
-                if args.dry_run_diff:
-                    sys.stdout.write(
-                        "".join(
-                            difflib.unified_diff(
-                                src.splitlines(keepends=True),
-                                new_src.splitlines(keepends=True),
-                                fromfile=f"a/{path}",
-                                tofile=f"b/{path}",
-                            )
-                        )
-                    )
-                else:
-                    with open(path, "w", encoding="utf-8") as fh:
-                        fh.write(new_src)
-            # the plan (and inventory) written below must reflect the tree
-            # we leave behind — re-lint from scratch rather than patching:
-            # an inserted import shifts every later line, so reusing the
-            # pre-edit inventory would commit stale line numbers that fail
-            # the CI drift gate on the very next regeneration
-            if by_path and not args.dry_run_diff:
-                split_inventory = []
-                contexts = {}
-                rebuild_holder: list = []
-                lint_paths(
-                    args.paths,
-                    select=select,
-                    cache_path=cache_path,
-                    split_inventory_out=split_inventory,
-                    contexts_out=contexts,
-                    program_out=rebuild_holder,
-                )
-                program = rebuild_holder[0] if rebuild_holder else program
-                split_plan_obj = _splitmig.build_plan(
-                    split_inventory, program, contexts
-                )
-
     # normalize paths relative to the baseline file's directory so the
     # committed baseline matches regardless of how the CLI was invoked
     # (absolute path, relative path, different cwd)
@@ -352,46 +255,6 @@ def main(argv=None) -> int:
             hop["path"] = _norm(hop["path"])
     for u in unresolved:
         u["caller_path"] = _norm(u["caller_path"])
-    for s in split_inventory:
-        s["path"] = _norm(s["path"])
-    if split_plan_obj is not None:
-        for s in split_plan_obj["sites"]:
-            s["path"] = _norm(s["path"])
-
-    if args.split_inventory:
-        by_kind: dict = {}
-        for s in split_inventory:
-            by_kind[s["kind"]] = by_kind.get(s["kind"], 0) + 1
-        catalog = json.dumps(
-            {
-                "version": 1,
-                "comment": (
-                    "Every site whose behavior depends on single-split-axis "
-                    "semantics — the named-axis mesh refactor's work list. "
-                    "The committed snapshot covers the full lint scope; "
-                    "regenerate with: python scripts/heatlint.py heat_tpu/ "
-                    "benchmarks/ tutorials/ --split-inventory SPLIT_INVENTORY.json"
-                ),
-                "count": len(split_inventory),
-                "by_kind": {k: by_kind[k] for k in sorted(by_kind)},
-                "sites": split_inventory,
-            },
-            indent=2,
-        )
-        if args.split_inventory == "-":
-            print(catalog)
-        else:
-            with open(args.split_inventory, "w", encoding="utf-8") as fh:
-                fh.write(catalog + "\n")
-
-    if args.split_plan:
-        payload = _splitmig.render_plan(split_plan_obj)
-        if args.split_plan == "-":
-            print(payload, end="")
-        else:
-            with open(args.split_plan, "w", encoding="utf-8") as fh:
-                fh.write(payload)
-
     if args.write_baseline:
         if select:
             print(
@@ -483,16 +346,7 @@ def main(argv=None) -> int:
         with open(args.sarif, "w", encoding="utf-8") as fh:
             fh.write(sarif + "\n")
 
-    # ---- human-facing fix/migration summaries + exit codes ---- #
-    if split_apply_report is not None:
-        print(
-            f"splitmig: tranche {args.split_apply} — "
-            f"{split_apply_report['edits']} edit(s) across "
-            f"{len(split_apply_report['files'])} file(s), "
-            f"{split_apply_report['skipped']} skipped"
-            + (" [dry run]" if args.dry_run_diff else "")
-        )
-
+    # ---- human-facing fix summaries + exit codes ---- #
     if args.fix_check:
         new_ids = {id(f) for f in new}
         offenders = [

@@ -1,8 +1,8 @@
 """``chip_smoke.py`` off the chip: its phase functions at toy sizes on the
-8-device CPU mesh (kernels in interpret mode), its refusal (and
-``bench.py``'s) to run without a TPU, and the compile-cache placement rule.
+8-device CPU mesh (kernels in interpret mode), its refusal to run without a
+TPU, and the compile-cache placement rule.
 
-The whole script at toy sizes (``chip_smoke.run``: sixteen phases) is a
+The whole script at toy sizes (``chip_smoke.run``: fifteen phases) is a
 minute of XLA:CPU compiles, more than the quick lane can spare (ROADMAP D9),
 so it carries the ``slow`` marker; the quick lane keeps the refusal, the
 cache rule and the agreement of the toy and full size tables.  Run the slow
@@ -31,7 +31,6 @@ TOY = dict(
     lm=dict(vocab_size=64, embed_dim=32, num_heads=4, depth=2, max_len=64),
     lm_batch=8, lm_seq=32, lm_prompt=4, lm_new=4,
     attn=(8, 4, 128, 16), attn_kv_heads=2, attn_long=(2, 8, 256, 16),
-    kmeans_kernel=(2048, 8, 4),
     ring=(2, 2, 16, 8),
     moe=dict(embed=16, hidden=32, experts_per_chip=2, tokens_per_chip=8),
     pipe=dict(embed=16, heads=2, seq=8, batch_per_chip=1),
@@ -54,27 +53,22 @@ def test_every_phase_toy(capsys):
     assert [l.split()[1] for l in lines] == [
         "array.matmul", "array.resplit", "array.qr", "array.kmeans", "array.ragged",
         "array.fft", "train.mlp_dataparallel", "train.daso", "model.transformer_lm",
-        "model.flash_attention", "model.kmeans_kernel", "multi.dryrun_tiers",
+        "model.flash_attention", "multi.dryrun_tiers",
         "multi.ring_attention", "multi.moe_expert_parallel", "multi.pipeline",
         "multi.daso_two_tier",
     ]
 
 
 def test_no_tpu_no_result():
-    """Without a chip neither entry point prints a success marker or a metric
-    line, and both exit non-zero (no CPU stand-in under a device's name)."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    procs = {
-        script: subprocess.Popen(
-            [sys.executable, os.path.join(REPO, script)], env=env, cwd=REPO,
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-        for script in ("chip_smoke.py", "bench.py")
-    }
-    for script, proc in procs.items():
-        out, err = proc.communicate(timeout=240)
-        assert proc.returncode != 0, (script, out[-300:])
-        assert "CHIP_SMOKE OK" not in out and "{" not in out, (script, out[-300:])
-        assert "Unable to initialize backend 'tpu'" in err, (script, err[-500:])
+    """Without a chip the entry point prints no success marker and no metric
+    line, and exits non-zero (no CPU stand-in under a device's name)."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO,
+        capture_output=True, text=True, timeout=240)
+    assert proc.returncode != 0, proc.stdout[-300:]
+    assert "CHIP_SMOKE OK" not in proc.stdout and "{" not in proc.stdout, proc.stdout[-300:]
+    assert "Unable to initialize backend 'tpu'" in proc.stderr, proc.stderr[-500:]
 
 
 def test_compile_cache_rule(monkeypatch):

@@ -6,8 +6,7 @@ returns, metadata through resplit and binary-op promotion), the HT301–
 HT304 rules (positive AND negative fixtures — the honesty policy means a
 value of unknown origin never gates), the analysis-schema cache revision,
 the ``--select`` prefix wildcards, the ``--list-rules`` severity/level
-columns, the ``--split-inventory`` catalog, and a determinism assertion
-(two runs, identical findings order).
+columns, and a determinism assertion (two runs, identical findings order).
 """
 
 import importlib.util
@@ -1062,7 +1061,7 @@ class TestCacheSchemaRevision:
 
 
 # ---------------------------------------------------------------------- #
-# CLI: wildcard select, list-rules columns, split inventory
+# CLI: wildcard select, list-rules columns
 # ---------------------------------------------------------------------- #
 class TestCli:
     FIXTURE = """
@@ -1107,58 +1106,6 @@ class TestCli:
         assert "[file   ]" in lines["HT101"] and "[error]" in lines["HT101"]
         assert "[program]" in lines["HT301"] and "[error]" in lines["HT301"]
         assert "[program]" in lines["HT201"]
-
-    def test_split_inventory_catalog(self, tmp_path, capsys):
-        pkg = write_pkg(
-            tmp_path,
-            {
-                "lib.py": """
-                    import heat_tpu as ht
-
-                    def f(x, split):
-                        s = x.split
-                        y = ht.zeros((8, 4), split=0)
-                        z = y.resplit(1)
-                        return s, z
-                """
-            },
-        )
-        out_file = str(tmp_path / "inventory.json")
-        heatlint_cli.main(
-            [pkg, "--split-inventory", out_file,
-             "--baseline", str(tmp_path / "bl.json"), "--no-cache"]
-        )
-        capsys.readouterr()
-        catalog = json.load(open(out_file))
-        assert catalog["count"] == len(catalog["sites"]) > 0
-        kinds = set(catalog["by_kind"])
-        assert {"split-read", "split-kwarg", "resplit-call", "split-param"} <= kinds
-        site = catalog["sites"][0]
-        assert {"path", "line", "kind", "qualname", "detail"} <= set(site)
-
-    def test_committed_repo_inventory_fresh_and_nonempty(self):
-        """The committed SPLIT_INVENTORY.json (the mesh-refactor work list)
-        exactly matches a fresh run over the SAME scope the CI heatlint
-        lane lints — this IS the drift gate: a change that adds/moves a
-        split-semantics site must regenerate the snapshot (command in the
-        file's own comment)."""
-        committed = json.load(open(os.path.join(REPO, "SPLIT_INVENTORY.json")))
-        assert committed["count"] == len(committed["sites"]) > 100
-        inventory: list = []
-        lint_paths(
-            [
-                os.path.join(REPO, "heat_tpu"),
-                os.path.join(REPO, "benchmarks"),
-                os.path.join(REPO, "tutorials"),
-            ],
-            select=["HT301"],
-            cache_path=None,
-            split_inventory_out=inventory,
-        )
-        # lint_paths emits absolute-path sites here; normalize like the CLI
-        for s in inventory:
-            s["path"] = os.path.relpath(s["path"], REPO).replace(os.sep, "/")
-        assert inventory == committed["sites"]
 
 
 # ---------------------------------------------------------------------- #

@@ -1,4 +1,4 @@
-"""heatfix + splitmig tests (ISSUE 13 tentpole).
+"""heatfix tests (ISSUE 13 tentpole).
 
 The proof-carrying autofix engine: every fixer gets a positive fixture
 (the proof holds and the rewrite lands, re-lints clean, and is idempotent)
@@ -9,9 +9,7 @@ the site is left byte-identical with the refusal reason shipped in
 surface (``--fix``/``--dry-run-diff``/``--fix-check``/SARIF ``fixes``/
 ``--list-rules`` fixable column/``--select`` refusal), the baseline
 burn-down honesty gate (every fingerprint removed from the baseline
-re-lints clean UN-suppressed in the live repo), and the split-migration
-planner (plan coverage, tranche-0 execution round-trip, committed-plan
-drift gate).
+re-lints clean UN-suppressed in the live repo).
 """
 
 import importlib.util
@@ -21,7 +19,7 @@ import textwrap
 
 import pytest
 
-from heat_tpu.analysis import LintContext, fixes, lint_paths, splitmig, summaries
+from heat_tpu.analysis import LintContext, fixes, lint_paths, summaries
 from heat_tpu.analysis.framework import load_baseline_records
 from heat_tpu.analysis.rules import (
     HostSyncRule,
@@ -120,7 +118,7 @@ class TestEditEngine:
         assert (
             fixes._relative_core_prefix("heat_tpu/utils/data/datatools.py") == "...core"
         )
-        assert fixes._relative_core_prefix("benchmarks/main.py") == "heat_tpu.core"
+        assert fixes._relative_core_prefix("benchmarks/dispatch.py") == "heat_tpu.core"
 
 
 # ---------------------------------------------------------------------- #
@@ -720,34 +718,6 @@ class TestCli:
         rc = heatlint_cli.main([str(tmp_path), "--fix", "--no-cache"])
         assert rc == 1
 
-    def test_split_apply_written_plan_survives_regeneration(self, tmp_path):
-        # a tranche-0 file NEEDING an import insertion shifts line numbers;
-        # the plan written by --split-apply must match a fresh --split-plan
-        # of the new tree (the CI drift-gate contract)
-        (tmp_path / "bench_fixture.py").write_text(
-            "from heat_tpu import random\n"
-            "def bench():\n"
-            "    return random.randn(8, 8, split=0)\n"
-        )
-        # the consumer classification keys on a benchmarks/ segment
-        bench_dir = tmp_path / "benchmarks"
-        bench_dir.mkdir()
-        (tmp_path / "bench_fixture.py").rename(bench_dir / "bench_fixture.py")
-        plan1 = tmp_path / "plan1.json"
-        rc = heatlint_cli.main(
-            [str(bench_dir), "--split-apply", "0", "--split-plan", str(plan1),
-             "--no-cache"]
-        )
-        assert rc == 0
-        new_src = (bench_dir / "bench_fixture.py").read_text()
-        assert "from heat_tpu.core import axisspec" in new_src
-        assert "split=axisspec.named(0)" in new_src
-        plan2 = tmp_path / "plan2.json"
-        heatlint_cli.main(
-            [str(bench_dir), "--split-plan", str(plan2), "--no-cache"]
-        )
-        assert json.loads(plan1.read_text()) == json.loads(plan2.read_text())
-
     def test_fix_exit_1_when_unfixable_new_remains(self, tmp_path, capsys):
         (tmp_path / "lib.py").write_text(
             self.FIXABLE
@@ -801,14 +771,6 @@ class TestCli:
             [str(tmp_path), "--fix", "--select", "HT101", "--no-cache"]
         )
         assert rc == 0
-
-    def test_fix_and_split_apply_mutually_exclusive(self, tmp_path, capsys):
-        (tmp_path / "lib.py").write_text(self.FIXABLE)
-        with pytest.raises(SystemExit):
-            heatlint_cli.main(
-                [str(tmp_path), "--fix", "--split-apply", "0", "--no-cache"]
-            )
-        assert "mutually exclusive" in capsys.readouterr().err
 
     def test_list_rules_has_fixable_column(self, capsys):
         heatlint_cli.main(["--list-rules"])
@@ -891,120 +853,3 @@ class TestBaselineBurnDown:
         errors = [f for f in findings if f.severity == "error"]
         attempts = fixes.plan_fixes(errors, contexts, program_holder[0])
         assert [a for a in attempts if a.edits] == []
-
-
-# ---------------------------------------------------------------------- #
-# splitmig — the migration planner + tranche-0 executor
-# ---------------------------------------------------------------------- #
-class TestSplitMig:
-    def test_classify_kinds(self):
-        deps: dict = {}
-        sig = splitmig.classify_site(
-            {"path": "heat_tpu/cluster/kmeans.py", "kind": "split-param",
-             "detail": "split", "line": 1}, deps)
-        assert sig["class"] == "signature" and not sig["mechanical"]
-        assert sig["tranche"] == 3
-        core = splitmig.classify_site(
-            {"path": "heat_tpu/core/communication.py", "kind": "split-read",
-             "detail": "split", "line": 1}, deps)
-        assert not core["mechanical"] and core["tranche"] == 3
-        consumer = splitmig.classify_site(
-            {"path": "benchmarks/main.py", "kind": "split-kwarg",
-             "detail": "ht.random.randn(split=0)", "line": 1}, deps)
-        assert consumer["class"] == "spec-kwarg" and consumer["tranche"] == 0
-        dyn = splitmig.classify_site(
-            {"path": "benchmarks/main.py", "kind": "split-kwarg",
-             "detail": "ht.zeros(split=?)", "line": 1}, deps)
-        assert not dyn["mechanical"] and dyn["tranche"] == 3
-
-    def test_fan_in_bumps_tranche(self):
-        deps = {"heat_tpu/linalg/solver.py": {f"m{i}" for i in range(5)}}
-        hot = splitmig.classify_site(
-            {"path": "heat_tpu/linalg/solver.py", "kind": "split-kwarg",
-             "detail": "ht.zeros(split=0)", "line": 1}, deps)
-        assert hot["tranche"] == 2
-        cold = splitmig.classify_site(
-            {"path": "heat_tpu/cluster/kmeans.py", "kind": "split-kwarg",
-             "detail": "ht.zeros(split=0)", "line": 1}, {})
-        assert cold["tranche"] == 1
-
-    def test_tranche0_execution_round_trip(self, tmp_path):
-        src = (
-            "import heat_tpu as ht\n"
-            "def bench():\n"
-            "    return ht.random.randn(64, 64, split=0)\n"
-        )
-        path = "benchmarks/fixture_bench.py"
-        ctx = LintContext(path, src)
-        inventory = [
-            {"path": path, "line": 3, "kind": "split-kwarg",
-             "qualname": "bench", "detail": "ht.random.randn(split=0)"}
-        ]
-        plan = splitmig.build_plan(inventory, None, {path: ctx})
-        assert plan["count"] == 1
-        assert plan["sites"][0]["tranche"] == 0
-        assert plan["sites"][0]["migrated"] is False
-        edits, skipped = splitmig.tranche_edits(plan, {path: ctx}, tranche=0)
-        assert skipped == []
-        new_src = fixes.apply_edits(src, edits)
-        # the call-site's own ht binding is used: NO import inserted (the
-        # consumer lazy-import / XLA_FLAGS-before-jax contract)
-        assert "split=ht.axisspec.named(0)" in new_src
-        assert "from heat_tpu.core import axisspec" not in new_src
-        # round trip: the rewritten site is migrated, detail-stable, and a
-        # second execution plans zero edits (idempotence)
-        ctx2 = LintContext(path, new_src)
-        plan2 = splitmig.build_plan(inventory, None, {path: ctx2})
-        assert plan2["sites"][0]["migrated"] is True
-        edits2, _ = splitmig.tranche_edits(plan2, {path: ctx2}, tranche=0)
-        assert edits2 == []
-
-    def test_tranche0_without_ht_binding_inserts_import(self):
-        src = (
-            "from heat_tpu import random\n"
-            "def bench():\n"
-            "    return random.randn(64, 64, split=0)\n"
-        )
-        path = "benchmarks/fixture2.py"
-        ctx = LintContext(path, src)
-        inventory = [
-            {"path": path, "line": 3, "kind": "split-kwarg",
-             "qualname": "bench", "detail": "random.randn(split=0)"}
-        ]
-        plan = splitmig.build_plan(inventory, None, {path: ctx})
-        edits, _ = splitmig.tranche_edits(plan, {path: ctx}, tranche=0)
-        new_src = fixes.apply_edits(src, edits)
-        assert "from heat_tpu.core import axisspec" in new_src
-        assert "split=axisspec.named(0)" in new_src
-
-    def test_committed_plan_matches_fresh_regeneration(self):
-        committed = json.load(open(os.path.join(REPO, "MIGRATION_PLAN.json")))
-        inv = json.load(open(os.path.join(REPO, "SPLIT_INVENTORY.json")))
-        contexts: dict = {}
-        program_holder: list = []
-        split_inventory: list = []
-        lint_paths(
-            [os.path.join(REPO, d) for d in ("heat_tpu", "benchmarks", "tutorials")],
-            cache_path=None,
-            split_inventory_out=split_inventory,
-            contexts_out=contexts,
-            program_out=program_holder,
-        )
-        plan = splitmig.build_plan(split_inventory, program_holder[0], contexts)
-        for s in plan["sites"]:
-            s["path"] = os.path.relpath(s["path"], REPO).replace(os.sep, "/")
-        assert plan["count"] == committed["count"] == inv["count"] == 413
-        assert plan == committed
-        # every inventory site is covered, keyed identically
-        key = lambda s: (s["path"], s["line"], s["kind"], s["detail"])  # noqa: E731
-        assert {key(s) for s in plan["sites"]} == {key(s) for s in inv["sites"]}
-
-    def test_committed_plan_tranche0_fully_migrated(self):
-        plan = json.load(open(os.path.join(REPO, "MIGRATION_PLAN.json")))
-        t0 = plan["tranches"]["0"]
-        assert t0["sites"] == t0["migrated"] == 15
-        # and every site record carries class + tranche (the acceptance shape)
-        for s in plan["sites"]:
-            assert s["class"] in ("axis-read", "spec-kwarg", "respec", "signature")
-            assert s["tranche"] in (0, 1, 2, 3)
-            assert isinstance(s["mechanical"], bool)

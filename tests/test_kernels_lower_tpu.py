@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import pytest
 
 fa = importlib.import_module("heat_tpu.ops.flash_attention")
-km = importlib.import_module("heat_tpu.ops.kmeans_kernels")
 
 B, S, D = 6, 1024, 64  # batch x heads > 1; two 512-blocks per sequence
 
@@ -57,14 +56,6 @@ class TestFlashLowers:
         kpos = jax.ShapeDtypeStruct((1, S // 2), jnp.int32)
         _lowers(_vg(lambda a, b, c, qp, kp: fa._flash_pos(
             a, b, c, qp, kp, True, D**-0.5, S // 2, True, False)), q, kv, kv, qpos, kpos)
-
-
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_kmeans_kernels_lower(dtype):
-    x = jax.ShapeDtypeStruct((2**15, 32), dtype)
-    c = jax.ShapeDtypeStruct((64, 32), jnp.float32)
-    _lowers(lambda x, c: km._fused_em_stats_impl(x, c, x.shape[0] - 5, interpret=False), x, c)
-    _lowers(lambda x, c: km._fused_assign_impl(x, c, interpret=False), x, c)
 
 
 def _gqa_case():
@@ -150,12 +141,3 @@ class TestNoQuietFallback:
         with pytest.raises(RuntimeError, match="kernel refused"):
             fa.flash_attention_gqa(q, q[:, :1], q[:, :1], causal=True)
         assert fa.path_counts["dense"] == dense
-
-    def test_kmeans_raises(self, monkeypatch):
-        x, c = jnp.ones((256, 8), jnp.float32), jnp.ones((4, 8), jnp.float32)
-        monkeypatch.setattr(km, "_fused_em_stats_impl", self._boom)
-        monkeypatch.setattr(km, "_fused_assign_impl", self._boom)
-        with pytest.raises(RuntimeError, match="kernel refused"):
-            km.fused_em_stats(x, c)
-        with pytest.raises(RuntimeError, match="kernel refused"):
-            km.fused_assign(x, c)

@@ -10,81 +10,6 @@ import heat_tpu as ht
 pytestmark = pytest.mark.heavy
 
 
-class TestFusedAssign:
-    def test_matches_oracle(self):
-        import jax.numpy as jnp
-
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.normal(size=(1000, 32)).astype(np.float32))
-        c = jnp.asarray(rng.normal(size=(16, 32)).astype(np.float32))
-        lab, d2 = ht.ops.fused_assign(x, c)
-        D = ((np.asarray(x)[:, None, :] - np.asarray(c)[None, :, :]) ** 2).sum(-1)
-        np.testing.assert_array_equal(np.asarray(lab), D.argmin(1))
-        np.testing.assert_allclose(np.asarray(d2), D.min(1), atol=1e-2)
-
-    def test_ragged_rows(self):
-        import jax.numpy as jnp
-
-        rng = np.random.default_rng(1)
-        # row count not divisible by the kernel tile → padding path
-        x = jnp.asarray(rng.normal(size=(1537, 8)).astype(np.float32))
-        c = jnp.asarray(rng.normal(size=(5, 8)).astype(np.float32))
-        lab, d2 = ht.ops.fused_assign(x, c)
-        assert lab.shape == (1537,)
-        D = ((np.asarray(x)[:, None, :] - np.asarray(c)[None, :, :]) ** 2).sum(-1)
-        np.testing.assert_array_equal(np.asarray(lab), D.argmin(1))
-
-
-class TestFusedEMStats:
-    """Fused assign+accumulate kernel (round-4: wired into KMeans via
-    assign_kernel='pallas'; interpret mode on CPU)."""
-
-    def test_matches_oracle_with_pad(self):
-        import jax.numpy as jnp
-
-        from heat_tpu.ops.kmeans_kernels import fused_em_stats
-
-        rng = np.random.default_rng(0)
-        x = rng.standard_normal((2000, 16)).astype(np.float32)
-        c = rng.standard_normal((8, 16)).astype(np.float32)
-        n = 1987  # tail rows are pad: must contribute nothing
-        s, cnt = fused_em_stats(jnp.asarray(x), jnp.asarray(c), n)
-        d2 = ((x[:n, None, :] - c[None, :, :]) ** 2).sum(-1)
-        lab = d2.argmin(1)
-        want_s = np.zeros((8, 16), np.float32)
-        want_c = np.zeros(8, np.float32)
-        for i, l in enumerate(lab):
-            want_s[l] += x[i]
-            want_c[l] += 1
-        np.testing.assert_allclose(np.asarray(cnt), want_c)
-        np.testing.assert_allclose(np.asarray(s), want_s, rtol=1e-4, atol=1e-3)
-
-    def test_kmeans_kernel_matches_jnp(self):
-        """assign_kernel='pallas' is the same estimator: identical centers,
-        labels, inertia on both fit paths (sharded + global)."""
-        from sklearn.datasets import make_blobs
-
-        X, _ = make_blobs(n_samples=1500, centers=5, n_features=8, random_state=0)
-        X = X.astype(np.float32)
-        for split in (0, None):
-            hx = ht.array(X, split=split)
-            kj = ht.cluster.KMeans(n_clusters=5, random_state=0, init="random",
-                                   assign_kernel="jnp").fit(hx)
-            kp = ht.cluster.KMeans(n_clusters=5, random_state=0, init="random",
-                                   assign_kernel="pallas").fit(hx)
-            np.testing.assert_allclose(
-                kj.cluster_centers_.numpy(), kp.cluster_centers_.numpy(), rtol=1e-4, atol=1e-4
-            )
-            np.testing.assert_array_equal(kj.labels_.numpy(), kp.labels_.numpy())
-            np.testing.assert_array_equal(kp.predict(hx).numpy(), kj.predict(hx).numpy())
-
-    def test_assign_kernel_validation(self):
-        import pytest
-
-        with pytest.raises(ValueError):
-            ht.cluster.KMeans(assign_kernel="bogus")
-
-
 class TestFlashAttention:
     """Flash-fused local attention (round-4b): the (S, S) score matrix never
     materializes.  Interpret mode on the CPU mesh; the same pallas_call runs
