@@ -34,6 +34,7 @@ two block dims to be multiples of (8, 128) or the full extent, which a
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -46,11 +47,15 @@ from ..core.devices import get_default_mesh, platform_of
 
 __all__ = ["flash_attention", "flash_attention_block", "flash_attention_gqa"]
 
-# 512x512 was the best of the block sizes hand-timed on a v5e before PR 1;
-# on the current code: not measured.  Blocks are always rounded to a 128
-# multiple (Mosaic lane alignment).
+# Block shape of the positions-carrying kernels (the ring's building block).
+# Blocks are always rounded to a 128 multiple (Mosaic lane alignment).
 _BLK_Q = 512
 _BLK_K = 512
+# Block sides of the static-offset kernels (``_block_shape``): sequences pad
+# to a multiple of ``_BLK``, and run ``_BLK_WIDE`` blocks where that pads no
+# further.
+_BLK = 512
+_BLK_WIDE = 1024
 
 
 def _round_up(n: int, m: int) -> int:
@@ -94,18 +99,28 @@ def _dense_attention(q, k, v, causal: bool, scale: float, s_valid: int,
     return (out, p) if return_probs else out
 
 
-def _online_update(s, v_ref, m_scr, l_scr, acc_scr):
+def _online_update(s, v_ref, m_scr, l_scr, acc_scr, *, guarded: bool):
     """One step of the online-softmax recurrence against the VMEM scratch —
     shared by the static-offset and positions-carrying forward kernels so
     the numerics cannot diverge.  GEMM operands stay in the storage dtype
-    (bf16 rides the MXU's native input type); accumulation is f32."""
+    (bf16 rides the MXU's native input type); accumulation is f32.
+
+    ``guarded`` (static) keeps a row that has met no live key yet (m = -inf)
+    free of NaN: the positions-carrying kernels can meet one.  In the
+    static-offset kernels every row sees key 0 in its first block, so m is
+    finite from then on, ``exp(-inf - m)`` is an exact 0 and the guards
+    would change no bit."""
     m_prev = m_scr[:, 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    # fully-masked-so-far rows keep m=-inf; exp against a safe 0 stays 0
-    safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - safe_m[:, None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)
-    corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
+    if guarded:
+        # fully-masked-so-far rows keep m=-inf; exp against a safe 0 stays 0
+        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - safe_m[:, None])
+        p = jnp.where(jnp.isfinite(s), p, 0.0)
+        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
+    else:
+        p = jnp.exp(s - m_new[:, None])
+        corr = jnp.exp(m_prev - m_new)
     l_scr[:, 0] = l_scr[:, 0] * corr + jnp.sum(p, axis=-1)
     # p is cast to v's storage dtype for the PV GEMM (bf16 probabilities
     # against bf16 values — the standard TPU flash layout); f32 accum
@@ -132,35 +147,104 @@ def _finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
     lse_ref[0, 0] = jnp.where(l_scr[:, 0] > 0.0, lse, -1e30)
 
 
+def _block_kind(q_lo, k_lo, blk_q: int, blk_k: int, s_valid: int,
+                causal: bool):
+    """``(live, interior)`` of the score block whose first query row is
+    ``q_lo`` and whose first key is ``k_lo`` — decided by position alone, so
+    the kernels (traced grid offsets) and the tests (ints) call this one
+    function.  *Dead* (not live): every key is pad (``>= s_valid``) or,
+    under ``causal``, in the future of every query row — both GEMMs are
+    skipped (the ~2x flop saving that makes causal flash worth it).
+    *Interior*: no mask of the block can be false — every key is valid and,
+    under ``causal``, at or before every query row — so its body runs with
+    no iota, compare or select.  *Edge* (live, not interior): the diagonal
+    or the padding crosses it."""
+    live = k_lo < s_valid
+    interior = k_lo + blk_k <= s_valid
+    if causal:
+        live = live & (k_lo <= q_lo + blk_q - 1)
+        interior = interior & (k_lo + blk_k - 1 <= q_lo)
+    return live, interior
+
+
+def _block_census(Sp: int, s_valid: int, blk_q: int, blk_k: int,
+                  causal: bool) -> dict:
+    """How many of one head's ``Sp/blk_q x Sp/blk_k`` grid steps are
+    interior, edge and dead.  Shapes alone decide it, so this stands in for
+    a run-time counter of the mechanism."""
+    census = {"interior": 0, "edge": 0, "dead": 0}
+    for q_lo in range(0, Sp, blk_q):
+        for k_lo in range(0, Sp, blk_k):
+            live, interior = _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid,
+                                         causal)
+            census["interior" if interior else "edge" if live else "dead"] += 1
+    return census
+
+
+def _on_live_blocks(step, kind, masked: bool):
+    """Run ``step(with_mask)`` of a static-offset kernel on this grid step's
+    block: without the mask arithmetic on an interior block, with it on an
+    edge block, not at all on a dead one.  ``masked`` False (static: not
+    causal and no pad key) means no block has a mask."""
+    live, interior = kind
+    if not masked:
+        step(False)
+        return
+    pl.when(interior)(lambda: step(False))
+    pl.when(live & jnp.logical_not(interior))(lambda: step(True))
+
+
+def _scale_folds(scale: float) -> bool:
+    """Whether ``scale`` may ride on the ``(blk, d)`` operand of the score
+    product instead of on the ``(blk_q, blk_k)`` scores: only where that
+    changes no bit, i.e. ``scale`` is a power of two (``d**-0.5`` for
+    d = 16, 64, 256), which every float dtype multiplies exactly."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _split_scale(scale: float):
+    """``(on the operand, on the scores)``: where ``scale`` goes, the other
+    ``None``."""
+    return (scale, None) if _scale_folds(scale) else (None, scale)
+
+
+def _score_operand(ref, scr, scale):
+    """Stage the sweep's fixed score operand in ``scr``, times ``scale`` if
+    it carries one: once a sweep instead of once a score."""
+    x = ref[0]
+    scr[:] = x if scale is None else (x * scale).astype(scr.dtype)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, scale: float, causal: bool, s_valid: int,
+                  qs_scr, *, scale: float, causal: bool, s_valid: int,
                   blk_q: int, blk_k: int, nk: int, masked: bool):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
+    on_operand, on_scores = _split_scale(scale)
 
     @pl.when(ik == 0)
     def _():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        _score_operand(q_ref, qs_scr, on_operand)
 
     q_lo = iq * blk_q
     k_lo = ik * blk_k
-    # causal: a K block strictly in the future of every query row here
-    # contributes nothing — skip both GEMMs (the ~2x flop saving that makes
-    # causal flash worth it); pad-only K blocks are skipped the same way
-    live = k_lo < s_valid
-    if causal:
-        live = live & (k_lo <= q_lo + blk_q - 1)
 
-    @pl.when(live)
-    def _():
+    def step(with_mask: bool):
         # s: (blk_q, blk_k) f32 — in VMEM only
         s = _masked_scores(
-            q_ref[0], k_ref[0], scale=scale, causal=causal, masked=masked,
-            s_valid=s_valid, q_lo=q_lo, k_lo=k_lo, blk_q=blk_q, blk_k=blk_k,
+            qs_scr[:], k_ref[0], scale=on_scores, causal=causal,
+            masked=with_mask, s_valid=s_valid, q_lo=q_lo, k_lo=k_lo,
+            blk_q=blk_q, blk_k=blk_k,
         )
-        _online_update(s, v_ref, m_scr, l_scr, acc_scr)
+        # block 0 comes first and holds key 0, which no row masks: m is
+        # finite from the first update on, so no guard
+        _online_update(s, v_ref, m_scr, l_scr, acc_scr, guarded=False)
+
+    _on_live_blocks(
+        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal), masked)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -171,10 +255,13 @@ def _masked_scores(q, k, *, scale, causal, masked, s_valid,
                    q_lo, k_lo, blk_q, blk_k):
     """THE score+mask computation — forward and backward share this one
     definition, so the masking convention can never silently diverge
-    between the saved lse and the backward recompute."""
+    between the saved lse and the backward recompute.  ``scale`` None: an
+    operand already carries it (``_score_operand``)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+    )
+    if scale is not None:
+        s = s * scale
     if masked:
         kv_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
         mask = kv_pos < s_valid
@@ -186,10 +273,11 @@ def _masked_scores(q, k, *, scale, causal, masked, s_valid,
 
 
 def _recompute_p(q, k, lse_row, **kw):
-    """Backward-side recompute: p_ij = exp(s_ij - lse_i)."""
+    """Backward-side recompute: p_ij = exp(s_ij - lse_i).  The forward's
+    lse is finite on every row, so a masked score (-inf) recomputes to an
+    exact 0 with no guard."""
     s = _masked_scores(q, k, **kw)
-    p = jnp.exp(s - lse_row[:, None])
-    return jnp.where(jnp.isfinite(s), p, 0.0)
+    return jnp.exp(s - lse_row[:, None])
 
 
 # --------------------------------------------------------------------- #
@@ -257,7 +345,7 @@ def _flash_pos_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref,
             q_ref[0], k_ref[0], qpos, kpos,
             scale=scale, causal=causal, masked=masked, s_valid=s_valid,
         )
-        _online_update(s, v_ref, m_scr, l_scr, acc_scr)
+        _online_update(s, v_ref, m_scr, l_scr, acc_scr, guarded=True)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -340,44 +428,46 @@ def _flash_pos_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                         dq_ref, dq_scr,
+                         dq_ref, dq_scr, qs_scr,
                          *, scale, causal, s_valid, blk_q, blk_k, nk, masked):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
+    on_operand, on_scores = _split_scale(scale)
 
     @pl.when(ik == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        _score_operand(q_ref, qs_scr, on_operand)
 
     q_lo, k_lo = iq * blk_q, ik * blk_k
-    live = k_lo < s_valid
-    if causal:
-        live = live & (k_lo <= q_lo + blk_q - 1)
 
-    @pl.when(live)
-    def _():
+    def step(with_mask: bool):
         p = _recompute_p(
-            q_ref[0], k_ref[0], lse_ref[0, 0], scale=scale, causal=causal,
-            masked=masked, s_valid=s_valid, q_lo=q_lo, k_lo=k_lo,
-            blk_q=blk_q, blk_k=blk_k,
+            qs_scr[:], k_ref[0], lse_ref[0, 0], scale=on_scores,
+            causal=causal, masked=with_mask, s_valid=s_valid, q_lo=q_lo,
+            k_lo=k_lo, blk_q=blk_q, blk_k=blk_k,
         )
         dp = jax.lax.dot_general(  # dOᵢ · Vⱼᵀ  (blk_q, blk_k)
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dd_ref[0, 0][:, None]) * scale
+        # dS without its scale: that is applied once, to the accumulator
+        ds = p * (dp - dd_ref[0, 0][:, None])
         dq_scr[:] += jax.lax.dot_general(  # dSᵢⱼ · Kⱼ
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    _on_live_blocks(
+        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal), masked)
+
     @pl.when(ik == nk - 1)
     def _():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr,
+                          dk_ref, dv_ref, dk_scr, dv_scr, ks_scr,
                           *, scale, causal, s_valid, blk_q, blk_k, nq, masked,
                           nq_inner: int = 0):
     """dk/dv accumulation sweep.  ``nq`` is the TOTAL innermost sweep length
@@ -388,23 +478,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     ik = pl.program_id(1)  # fixed K/V block
     raw = pl.program_id(2)  # sweeping Q blocks (x group heads under GQA)
     iq = raw % (nq_inner or nq)
+    on_operand, on_scores = _split_scale(scale)
 
     @pl.when(raw == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+        _score_operand(k_ref, ks_scr, on_operand)
 
     q_lo, k_lo = iq * blk_q, ik * blk_k
-    live = k_lo < s_valid
-    if causal:
-        live = live & (k_lo <= q_lo + blk_q - 1)
 
-    @pl.when(live)
-    def _():
+    def step(with_mask: bool):
         p = _recompute_p(
-            q_ref[0], k_ref[0], lse_ref[0, 0], scale=scale, causal=causal,
-            masked=masked, s_valid=s_valid, q_lo=q_lo, k_lo=k_lo,
-            blk_q=blk_q, blk_k=blk_k,
+            q_ref[0], ks_scr[:], lse_ref[0, 0], scale=on_scores,
+            causal=causal, masked=with_mask, s_valid=s_valid, q_lo=q_lo,
+            k_lo=k_lo, blk_q=blk_q, blk_k=blk_k,
         )
         dv_scr[:] += jax.lax.dot_general(  # Pᵀ · dOᵢ  (blk_k, d)
             p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
@@ -414,20 +502,19 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dd_ref[0, 0][:, None]) * scale
+        ds = p * (dp - dd_ref[0, 0][:, None])  # unscaled, as in the dq sweep
         dk_scr[:] += jax.lax.dot_general(  # dSᵀ · Qᵢ  (blk_k, d)
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    _on_live_blocks(
+        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal), masked)
+
     @pl.when(raw == nq - 1)
     def _():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _blocks(Sp: int):
-    return _blocks_rect(Sp, Sp)
 
 
 def _row_dot(a, b):
@@ -495,6 +582,31 @@ def _run_flash_padded(flat_ops, S: int, blk: int, call):
     return out if out.shape[:2] == (B, S) else out[:B, :S]
 
 
+def _block_shape(S: int, d: int, itemsize: int):
+    """``(blk_q, blk_k)`` of the static-offset kernels for a sequence of
+    ``S`` rows of width ``d``: one block up to ``_BLK`` rows; beyond, square
+    blocks of ``_BLK_WIDE`` where ``S`` padded to ``_BLK`` is a multiple of
+    it and a row of a block is at most 512 bytes (bfloat16 to d = 256,
+    float32 to d = 128: what Mosaic fits into a v5e's scoped VMEM; float32
+    at d = 256 it refuses), else of ``_BLK``.  A step's lane reductions, row
+    statistics and pipeline turn cost the same whatever the block's width,
+    so the wide block halves them per score: at (128, 8192, 64) causal
+    bfloat16 on a v5e the forward takes 35.6 ms at 512 x 512, 38.1 at
+    1024 x 512, 28.5 at 256 x 1024, 21.7 at 512 x 1024, 22.4 at 512 x 2048
+    and 19.8 at 1024 x 1024, the two backward sweeps 35.8 + 27.5,
+    30.1 + 24.4, 41.6 + 29.7, 29.6 + 25.0, 30.5 + 25.2 and 28.1 + 22.5
+    (PERF.md, PR 31; 1024 x 1024 also won at S = 1024, 4096 and 32768, at
+    d = 128 and without ``causal``).  Idempotent under the padding it
+    causes, so the kernels recover it from the padded length."""
+    r = _round_up(S, 128)
+    if r <= _BLK:
+        return r, r
+    wide = (_round_up(S, _BLK) % _BLK_WIDE == 0
+            and itemsize * _round_up(d, 128) <= 512)
+    blk = _BLK_WIDE if wide else _BLK
+    return blk, blk
+
+
 def _pallas_gate(q, S: int, d: int):
     """THE kernel-dispatch gate, shared by every flash entry point so the
     platform policy and VMEM budget cannot drift between them.  The
@@ -502,132 +614,20 @@ def _pallas_gate(q, S: int, d: int):
     interpreter (slow): test scale only, like the kmeans kernels'
     16384-row gate.  The VMEM estimate covers Q/K/V/O blocks + scores +
     accumulator in f32; shapes past it take the dense form.  Returns
-    ``(use_pallas, blk, platform)``."""
+    ``(use_pallas, blk, platform)``, ``blk`` the multiple to pad ``S`` to."""
     platform = platform_of(q)
     use_pallas = platform == "tpu" or (platform == "cpu" and S <= 512)
-    blk = min(_BLK_Q, _BLK_K, _round_up(S, 128))
+    blk_q, blk_k = _block_shape(S, d, q.dtype.itemsize)
     if use_pallas:
-        vmem = 4 * (3 * blk * d + 2 * blk * d + blk * blk + 2 * blk)
+        vmem = 4 * (3 * blk_q * d + 2 * blk_k * d + blk_q * blk_k + 2 * blk_q)
         use_pallas = vmem <= 12 * 2**20
-    return use_pallas, blk, platform
+    return use_pallas, max(blk_q, blk_k), platform
 
 
 def _blocks_rect(Sq: int, Sk: int):
     blk_q = min(_BLK_Q, _round_up(Sq, 128))
     blk_k = min(_BLK_K, _round_up(Sk, 128))
     return blk_q, blk_k, pl.cdiv(Sq, blk_q), pl.cdiv(Sk, blk_k)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "s_valid", "interpret")
-)
-def _flash_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
-                    interpret: bool):
-    B, Sp, d = q.shape
-    blk_q, blk_k, nq, nk = _blocks(Sp)
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, s_valid=s_valid,
-        blk_q=blk_q, blk_k=blk_k, nk=nk,
-        masked=causal or (Sp != s_valid),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(B, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, iq, ik: (b, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, iq, ik: (b, 0, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Sp, d), q.dtype),
-            jax.ShapeDtypeStruct((B, 1, Sp), jnp.float32),  # logsumexp
-        ],
-        scratch_shapes=[
-            # (blk_q, 1) not (blk_q,): TPU scratch wants >=2-D tiles
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "s_valid", "interpret")
-)
-def _flash_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
-                    s_valid: int, interpret: bool):
-    B, Sp, d = q.shape
-    blk_q, blk_k, nq, nk = _blocks(Sp)
-    masked = causal or (Sp != s_valid)
-    # D_i = Σ_d dOᵢ ⊙ Oᵢ — one cheap fused elementwise pass, fine in XLA;
-    # (B, 1, Sp) like lse (see module docstring)
-    dd = _row_dot(do, out)
-
-    qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, scale=scale, causal=causal, s_valid=s_valid,
-            blk_q=blk_q, blk_k=blk_k, nk=nk, masked=masked,
-        ),
-        grid=(B, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B, Sp, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, do, lse, dd)
-
-    # dk/dv sweep: K/V block fixed per middle grid index, Q blocks stream
-    qspec2 = pl.BlockSpec((1, blk_q, d), lambda b, j, i: (b, i, 0))
-    kspec2 = pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0))
-    rowspec2 = pl.BlockSpec((1, 1, blk_q), lambda b, j, i: (b, 0, i))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel, scale=scale, causal=causal,
-            s_valid=s_valid, blk_q=blk_q, blk_k=blk_k, nq=nq, masked=masked,
-        ),
-        grid=(B, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-        out_specs=[kspec2, kspec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Sp, d), k.dtype),
-            jax.ShapeDtypeStruct((B, Sp, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_k, d), jnp.float32),
-            pltpu.VMEM((blk_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, do, lse, dd)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal: bool, scale: float, s_valid: int,
-           interpret: bool):
-    out, _ = _flash_fwd_impl(q, k, v, causal, scale, s_valid, interpret)
-    return out
-
-
-def _flash_fwd_rule(q, k, v, causal, scale, s_valid, interpret):
-    out, lse = _flash_fwd_impl(q, k, v, causal, scale, s_valid, interpret)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd_rule(causal, scale, s_valid, interpret, res, do):
-    q, k, v, out, lse = res
-    return _flash_bwd_impl(q, k, v, out, lse, do, causal, scale, s_valid,
-                           interpret)
-
-
-_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # --------------------------------------------------------------------- #
@@ -891,9 +891,6 @@ def flash_attention(q, k, v, causal: bool = False,
     B = 1
     for a in lead:
         B *= int(a)
-    # custom_vjp: jax.grad runs the Pallas backward kernels (dq sweep +
-    # dk/dv sweep) instead of failing out of pallas_call's missing
-    # autodiff rule — training keeps the flash memory profile
     out = _run_flash_padded(
         (q.reshape((B, S, d)), k.reshape((B, S, d)), v.reshape((B, S, d))),
         S, blk,
@@ -903,21 +900,40 @@ def flash_attention(q, k, v, causal: bool = False,
 
 
 # --------------------------------------------------------------------- #
-# grouped-query attention (GQA/MQA): head-mapping kernels
+# the static-offset kernels' pallas_call plumbing, grouped-query (GQA/MQA)
 #
-# K/V carry H_kv heads serving H_q = g·H_kv query heads.  The kernels are
-# the SAME bodies as the square local flash above — only the BlockSpec
-# index maps change: each flattened (batch·head) query row b reads K/V row
-# (b // hq)·hk + (b % hq) // g, so the g-fold K/V repeat that
-# ``jnp.repeat`` would materialize in HBM never exists.  The dk/dv sweep
-# runs the g query heads of a K/V head's group through one accumulator
-# (grid (B·hk, nk, g·nq), block offset = sweep index mod nq).
+# K/V carry H_kv heads serving H_q = g·H_kv query heads.  Only the BlockSpec
+# index maps know: each flattened (batch·head) query row b reads K/V row
+# (b // hq)·hk + (b % hq) // g, so the g-fold K/V repeat that ``jnp.repeat``
+# would materialize in HBM never exists.  The dk/dv sweep runs the g query
+# heads of a K/V head's group through one accumulator (grid
+# (B·hk, nk, g·nq), block offset = sweep index mod nq).  Equal heads are the
+# case hq == hk (``_flash``): the row maps are then the identity.
+#
+# The streamed side's block index is clamped to the sweep's nearest live
+# block (``_last_live_k``/``_first_live_q``): consecutive dead steps then
+# name the block already in VMEM and the pipeline copies nothing for them.
 # --------------------------------------------------------------------- #
 
 
 def _gqa_kv_row(b, hq: int, hk: int):
     g = hq // hk
     return (b // hq) * hk + (b % hq) // g
+
+
+def _last_live_k(iq, blk_q: int, blk_k: int, s_valid: int, causal: bool):
+    """Index of the last live K/V block of Q block ``iq`` (``_block_kind``:
+    live blocks of a row are 0 .. this)."""
+    last = (s_valid - 1) // blk_k
+    if causal:
+        last = jnp.minimum(last, (iq * blk_q + blk_q - 1) // blk_k)
+    return last
+
+
+def _first_live_q(ik, blk_q: int, blk_k: int, causal: bool):
+    """Index of the first live Q block of K/V block ``ik`` (live blocks of a
+    column are this .. the end)."""
+    return (ik * blk_k) // blk_q if causal else 0
 
 
 @functools.partial(
@@ -927,20 +943,25 @@ def _gqa_kv_row(b, hq: int, hk: int):
 def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
                         hq: int, hk: int, interpret: bool):
     BHq, Sp, d = q.shape
-    blk_q, blk_k, nq, nk = _blocks(Sp)
+    blk_q, blk_k = _block_shape(Sp, d, q.dtype.itemsize)
+    nq, nk = Sp // blk_q, Sp // blk_k
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, s_valid=s_valid,
         blk_q=blk_q, blk_k=blk_k, nk=nk,
         masked=causal or (Sp != s_valid),
     )
     kvrow = functools.partial(_gqa_kv_row, hq=hq, hk=hk)
+    last_k = functools.partial(_last_live_k, blk_q=blk_q, blk_k=blk_k,
+                               s_valid=s_valid, causal=causal)
+    kspec = pl.BlockSpec(
+        (1, blk_k, d),
+        lambda b, iq, ik: (kvrow(b), jnp.minimum(ik, last_k(iq)), 0))
     return pl.pallas_call(
         kernel,
         grid=(BHq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, iq, ik: (kvrow(b), ik, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, iq, ik: (kvrow(b), ik, 0)),
+            kspec, kspec,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
@@ -948,12 +969,14 @@ def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
-            jax.ShapeDtypeStruct((BHq, 1, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((BHq, 1, Sp), jnp.float32),  # logsumexp
         ],
         scratch_shapes=[
+            # (blk_q, 1) not (blk_q,): TPU scratch wants >=2-D tiles
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, d), jnp.float32),
+            pltpu.VMEM((blk_q, d), q.dtype),  # the score product's Q operand
         ],
         interpret=interpret,
     )(q, k, v)
@@ -968,14 +991,23 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     BHq, Sp, d = q.shape
     BHk = k.shape[0]
     g = hq // hk
-    blk_q, blk_k, nq, nk = _blocks(Sp)
+    blk_q, blk_k = _block_shape(Sp, d, q.dtype.itemsize)
+    nq, nk = Sp // blk_q, Sp // blk_k
     masked = causal or (Sp != s_valid)
+    # D_i = Σ_d dOᵢ ⊙ Oᵢ — one cheap fused elementwise pass, fine in XLA;
+    # (B, 1, Sp) like lse (see module docstring)
     dd = _row_dot(do, out)
     kvrow = functools.partial(_gqa_kv_row, hq=hq, hk=hk)
+    last_k = functools.partial(_last_live_k, blk_q=blk_q, blk_k=blk_k,
+                               s_valid=s_valid, causal=causal)
+    first_q = functools.partial(_first_live_q, blk_q=blk_q, blk_k=blk_k,
+                                causal=causal)
 
-    # dq sweep: identical to the square kernel, K/V rows mapped per group
+    # dq sweep: Q block fixed per middle grid index, K/V blocks stream
     qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (kvrow(b), j, 0))
+    kspec = pl.BlockSpec(
+        (1, blk_k, d),
+        lambda b, i, j: (kvrow(b), jnp.minimum(j, last_k(i)), 0))
     rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(
@@ -986,19 +1018,27 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((blk_q, d), jnp.float32),
+            pltpu.VMEM((blk_q, d), q.dtype),  # the score product's Q operand
+        ],
         interpret=interpret,
     )(q, k, v, do, lse, dd)
 
-    # dk/dv sweep: one K/V head accumulates its whole group — the innermost
-    # grid interleaves the g query heads x nq blocks through ONE scratch
+    # dk/dv sweep: K/V block fixed per middle grid index; one K/V head
+    # accumulates its whole group — the innermost grid interleaves the g
+    # query heads x nq blocks through ONE scratch
     def qrow(b, i):
         return (b // hk) * hq + (b % hk) * g + i // nq
 
-    qspec2 = pl.BlockSpec((1, blk_q, d), lambda b, j, i: (qrow(b, i), i % nq, 0))
+    def qblk(j, i):
+        return jnp.maximum(i % nq, first_q(j))
+
+    qspec2 = pl.BlockSpec((1, blk_q, d),
+                          lambda b, j, i: (qrow(b, i), qblk(j, i), 0))
     kspec2 = pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0))
     rowspec2 = pl.BlockSpec((1, 1, blk_q),
-                            lambda b, j, i: (qrow(b, i), 0, i % nq))
+                            lambda b, j, i: (qrow(b, i), 0, qblk(j, i)))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -1015,12 +1055,16 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
             pltpu.VMEM((blk_k, d), jnp.float32),
+            pltpu.VMEM((blk_k, d), k.dtype),  # the score product's K operand
         ],
         interpret=interpret,
     )(q, k, v, do, lse, dd)
     return dq, dk, dv
 
 
+# custom_vjp: jax.grad runs the Pallas backward kernels (dq sweep + dk/dv
+# sweep) instead of failing out of pallas_call's missing autodiff rule —
+# training keeps the flash memory profile
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_gqa(q, k, v, causal: bool, scale: float, s_valid: int,
                hq: int, hk: int, interpret: bool):
@@ -1042,6 +1086,12 @@ def _flash_gqa_bwd_rule(causal, scale, s_valid, hq, hk, interpret, res, do):
 
 
 _flash_gqa.defvjp(_flash_gqa_fwd_rule, _flash_gqa_bwd_rule)
+
+
+def _flash(q, k, v, causal: bool, scale: float, s_valid: int,
+           interpret: bool):
+    """Equal heads: every query row reads its own K/V row."""
+    return _flash_gqa(q, k, v, causal, scale, s_valid, 1, 1, interpret)
 
 
 def flash_attention_gqa(q, k, v, causal: bool = False,

@@ -58,6 +58,18 @@ class TestFlashLowers:
             a, b, c, qp, kp, True, D**-0.5, S // 2, True, False)), q, kv, kv, qpos, kpos)
 
 
+def test_gqa_lowers_at_the_training_cell_shape():
+    """``lfm2_8b_a1b_train_4x8k``: 4 sequences x 32 query heads over 8 K/V
+    heads, S = 8192, d = 64, causal bfloat16, at the block shape
+    ``_block_shape`` gives that shape: forward and both backward sweeps."""
+    S, d, hq, hk = 8192, 64, 32, 8
+    assert fa._block_shape(S, d, 2) == (1024, 1024)
+    q = jax.ShapeDtypeStruct((4 * hq, S, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((4 * hk, S, d), jnp.bfloat16)
+    _lowers(_vg(lambda a, b, c: fa._flash_gqa(a, b, c, True, d**-0.5, S, hq, hk, False)),
+            q, kv, kv)
+
+
 def _gqa_case():
     import numpy as np
 

@@ -254,3 +254,254 @@ class TestFlashGQA:
             np.asarray(y), np.asarray(self._ref(q, kb, vb, True)),
             rtol=1e-5, atol=1e-5,
         )
+
+
+# ---------------------------------------------------------------------- #
+# more than one block: interior, edge and dead grid steps (PR 31)
+# ---------------------------------------------------------------------- #
+
+
+def _fa():
+    import importlib
+
+    # the module: ``heat_tpu.ops.flash_attention`` the attribute is the function
+    return importlib.import_module("heat_tpu.ops.flash_attention")
+
+
+@pytest.fixture
+def blocks128(monkeypatch):
+    """The static-offset kernels at 128 x 128 blocks, so that a CPU-sized
+    sequence spans interior, edge and dead blocks.  The block shape is read
+    when the jitted plumbing is traced: drop what was traced before and
+    after."""
+    fa = _fa()
+
+    def clear():
+        fa._flash_gqa_fwd_impl.clear_cache()
+        fa._flash_gqa_bwd_impl.clear_cache()
+
+    monkeypatch.setattr(fa, "_BLK", 128)
+    monkeypatch.setattr(fa, "_BLK_WIDE", 128)
+    clear()
+    yield fa
+    clear()
+
+
+def _attention_case(fa, kind, S, d, dtype, seed):
+    import jax.numpy as jnp
+
+    hq, hk = (4, 1) if kind == "gqa" else (2, 2)
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(1, hq, S, d)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(1, hk, S, d)), dtype) for _ in range(2))
+    w = jnp.asarray(rng.normal(size=(1, hq, S, d)), jnp.float32)
+    call = fa.flash_attention_gqa if kind == "gqa" else fa.flash_attention
+
+    def dense(q, k, v, causal, scale):
+        f32 = lambda t: t.astype(jnp.float32)
+        g = hq // hk
+        return fa._dense_attention(f32(q), jnp.repeat(f32(k), g, 1),
+                                   jnp.repeat(f32(v), g, 1), causal, scale, S)
+
+    return q, k, v, w, call, dense
+
+
+def _value_and_grads(f, w, q, k, v):
+    import jax
+    import jax.numpy as jnp
+
+    out = f(q, k, v)
+    grads = jax.grad(lambda *o: jnp.sum(f(*o).astype(jnp.float32) * w),
+                     argnums=(0, 1, 2))(q, k, v)
+    return (out, *grads)
+
+
+class TestFlashManyBlocks:
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("S", [512, 400])  # 400: padding crosses the last column
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("kind", ["mha", "gqa"])
+    def test_matches_dense(self, blocks128, kind, causal, S, dtype):
+        fa = blocks128
+        d = 16
+        q, k, v, w, call, dense = _attention_case(fa, kind, S, d, dtype, seed=S + causal)
+        assert fa._block_census(512, S, 128, 128, causal) == {
+            (True, 512): {"interior": 6, "edge": 4, "dead": 6},
+            (True, 400): {"interior": 6, "edge": 4, "dead": 6},
+            (False, 512): {"interior": 16, "edge": 0, "dead": 0},
+            (False, 400): {"interior": 12, "edge": 4, "dead": 0},
+        }[causal, S]
+        assert fa._scale_folds(d**-0.5)
+        before = fa.path_counts["pallas"]
+        got = _value_and_grads(lambda *o: call(*o, causal=causal), w, q, k, v)
+        assert fa.path_counts["pallas"] == before + 2  # the kernels, forward and backward
+        want = _value_and_grads(lambda *o: dense(*o, causal, d**-0.5), w, q, k, v)
+        tol = dict(rtol=1e-4, atol=1e-5) if dtype == "float32" else dict(rtol=5e-2, atol=5e-2)
+        for g, r, what in zip(got, want, ("out", "dq", "dk", "dv")):
+            assert g.dtype == q.dtype
+            np.testing.assert_allclose(np.float32(g), np.float32(r), err_msg=what, **tol)
+
+    @pytest.mark.parametrize("how", ["d48", "explicit"])
+    def test_a_scale_that_is_no_power_of_two_stays_on_the_scores(self, blocks128, how):
+        fa = blocks128
+        d = 48 if how == "d48" else 16
+        scale = d**-0.5 if how == "d48" else 0.3
+        assert not fa._scale_folds(scale)
+        q, k, v, w, call, dense = _attention_case(fa, "gqa", 400, d, "float32", seed=48)
+        kw = {} if how == "d48" else {"scale": scale}
+        got = _value_and_grads(lambda *o: call(*o, causal=True, **kw), w, q, k, v)
+        want = _value_and_grads(lambda *o: dense(*o, True, scale), w, q, k, v)
+        for g, r, what in zip(got, want, ("out", "dq", "dk", "dv")):
+            np.testing.assert_allclose(np.float32(g), np.float32(r), rtol=1e-4, atol=1e-5,
+                                       err_msg=what)
+
+    @pytest.mark.parametrize("scale", [0.25, 1.0, 2.0**-10, 0.5])
+    def test_powers_of_two_fold(self, scale):
+        fa = _fa()
+        assert fa._scale_folds(scale)
+        assert not fa._scale_folds(scale * 1.5) and not fa._scale_folds(scale / 3)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal,s_valid", [(True, 512), (True, 400), (False, 400)])
+    def test_every_bit_as_before(self, blocks128, causal, s_valid, dtype):
+        """Folding a power of two into an operand, scaling an accumulator
+        instead of every ``ds``, and dropping guards that guard nothing are
+        all exact: at equal block sizes the kernels give the bits that the
+        arithmetic before PR 31 gave (written out below in ``jax.numpy``,
+        scale on the scores, mask and guards on every live block)."""
+        import jax
+        import jax.numpy as jnp
+
+        fa = blocks128
+        Sp, d, blk, scale = 512, 16, 128, 0.25
+        rng = np.random.default_rng(s_valid)
+        q, k, v, do = (jnp.asarray(rng.normal(size=(1, Sp, d)), dtype) for _ in range(4))
+        out, lse = fa._flash_gqa_fwd_impl(q, k, v, causal, scale, s_valid, 1, 1, True)
+        dq, dk, dv = fa._flash_gqa_bwd_impl(q, k, v, out, lse, do, causal, scale, s_valid,
+                                            1, 1, True)
+        # jitted like the interpreted kernel: op by op, XLA's CPU backend
+        # rounds a few of these expressions differently
+        want = jax.jit(_as_before, static_argnums=(4, 5, 6, 7))(
+            q[0], k[0], v[0], do[0], causal, scale, s_valid, blk)
+        for g, r, what in zip((out[0], lse[0, 0], dq[0], dk[0], dv[0]), want,
+                              ("out", "lse", "dq", "dk", "dv")):
+            np.testing.assert_array_equal(np.float32(g), np.float32(r), err_msg=what)
+
+
+def _as_before(q, k, v, do, causal, scale, s_valid, blk):
+    """One head's forward and backward as the kernels computed them before
+    PR 31, block by block: ``_masked_scores`` (scale on the scores, the mask
+    on every live block), ``_online_update`` and ``_recompute_p`` with their
+    ``isfinite`` guards, ``ds`` scaled score by score."""
+    import jax
+    import jax.numpy as jnp
+
+    Sp, d = q.shape
+    n = Sp // blk
+    dot = lambda a, b, dims: jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32)
+    rows = lambda i: slice(i * blk, (i + 1) * blk)
+
+    def live(i, j):
+        return j * blk < s_valid and (not causal or j * blk <= i * blk + blk - 1)
+
+    def scores(i, j):
+        s = dot(q[rows(i)], k[rows(j)], ((1,), (1,))) * scale
+        kv_pos = j * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+        mask = kv_pos < s_valid
+        if causal:
+            mask = mask & (i * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0) >= kv_pos)
+        return jnp.where(mask, s, -jnp.inf)
+
+    out, lse = [], []
+    for i in range(n):
+        m = jnp.full((blk,), -jnp.inf, jnp.float32)
+        l = jnp.zeros((blk,), jnp.float32)
+        acc = jnp.zeros((blk, d), jnp.float32)
+        for j in range(n):
+            if not live(i, j):
+                continue
+            s = scores(i, j)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.where(jnp.isfinite(s), jnp.exp(s - safe_m[:, None]), 0.0)
+            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
+            l = l * corr + jnp.sum(p, axis=-1)
+            acc = acc * corr[:, None] + dot(p.astype(v.dtype), v[rows(j)], ((1,), (0,)))
+            m = m_new
+        out.append((acc / jnp.maximum(l, 1e-30)[:, None]).astype(q.dtype))
+        lse.append(jnp.where(jnp.isfinite(m), m, 0.0) + jnp.log(jnp.maximum(l, 1e-30)))
+    out, lse = jnp.concatenate(out), jnp.concatenate(lse)
+    dd = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+
+    def p_ds(i, j):
+        s = scores(i, j)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse[rows(i)][:, None]), 0.0)
+        dp = dot(do[rows(i)], v[rows(j)], ((1,), (1,)))
+        return p, p * (dp - dd[rows(i)][:, None]) * scale
+
+    dq, dk, dv = [], [], []
+    for i in range(n):
+        acc = jnp.zeros((blk, d), jnp.float32)
+        for j in range(n):
+            if live(i, j):
+                acc = acc + dot(p_ds(i, j)[1].astype(k.dtype), k[rows(j)], ((1,), (0,)))
+        dq.append(acc.astype(q.dtype))
+    for j in range(n):
+        acc_k = jnp.zeros((blk, d), jnp.float32)
+        acc_v = jnp.zeros((blk, d), jnp.float32)
+        for i in range(n):
+            if live(i, j):
+                p, ds = p_ds(i, j)
+                acc_v = acc_v + dot(p.astype(do.dtype), do[rows(i)], ((0,), (0,)))
+                acc_k = acc_k + dot(ds.astype(q.dtype), q[rows(i)], ((0,), (0,)))
+        dk.append(acc_k.astype(k.dtype))
+        dv.append(acc_v.astype(v.dtype))
+    return out, lse, jnp.concatenate(dq), jnp.concatenate(dk), jnp.concatenate(dv)
+
+
+class TestBlockKind:
+    """The classification that decides which body a grid step runs."""
+
+    CASES = [  # Sp, s_valid, blk_q, blk_k, causal
+        (512, 512, 128, 128, True), (512, 400, 128, 128, True),
+        (512, 400, 128, 128, False), (512, 512, 128, 128, False),
+        (1024, 1000, 128, 256, True), (1024, 770, 256, 128, True),
+        (1024, 513, 512, 256, False), (1024, 1, 256, 512, True),
+        (384, 300, 128, 384, True), (768, 768, 384, 128, True),
+    ]
+
+    @pytest.mark.parametrize("Sp,s_valid,blk_q,blk_k,causal", CASES)
+    def test_against_the_mask_itself(self, Sp, s_valid, blk_q, blk_k, causal):
+        fa = _fa()
+        rows, keys = np.arange(Sp)[:, None], np.arange(Sp)[None, :]
+        mask = np.broadcast_to(keys < s_valid, (Sp, Sp))
+        if causal:
+            mask = mask & (rows >= keys)
+        counted = {"interior": 0, "edge": 0, "dead": 0}
+        for iq in range(Sp // blk_q):
+            for ik in range(Sp // blk_k):
+                q_lo, k_lo = iq * blk_q, ik * blk_k
+                block = mask[q_lo:q_lo + blk_q, k_lo:k_lo + blk_k]
+                live, interior = fa._block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal)
+                assert bool(interior) == bool(block.all()), (iq, ik)
+                assert bool(live) == bool(block.any()), (iq, ik)
+                counted["interior" if block.all() else "edge" if block.any() else "dead"] += 1
+                # the clamped index maps: a live step fetches its own block,
+                # a dead one the nearest live block of its sweep
+                last = int(fa._last_live_k(iq, blk_q, blk_k, s_valid, causal))
+                first = int(fa._first_live_q(ik, blk_q, blk_k, causal))
+                assert bool(live) == (ik <= last), (iq, ik)
+                if k_lo < s_valid:
+                    assert bool(live) == (iq >= first), (iq, ik)
+        assert fa._block_census(Sp, s_valid, blk_q, blk_k, causal) == counted
+
+    def test_the_training_cell(self):
+        fa = _fa()
+        # lfm2_8b_a1b_train_4x8k: S = 8192, causal, no padding
+        assert fa._block_census(8192, 8192, 512, 512, True) == {
+            "interior": 120, "edge": 16, "dead": 120}
+        # ... and at the block shape the kernels choose for it
+        assert fa._block_shape(8192, 64, 2) == (1024, 1024)
+        assert fa._block_census(8192, 8192, 1024, 1024, True) == {
+            "interior": 28, "edge": 8, "dead": 28}
