@@ -8,7 +8,8 @@ from .spatial import *
 from .padshuffle import *
 from .extended import *
 from . import activations, extended, losses, padshuffle, spatial
-from .attention import MultiheadAttention, apply_rope
+from .attention import LatentAttention, MultiheadAttention, apply_rope
+from .linear_attention import KimiDeltaAttention
 from .moe import MoE
 from .pipelined import Pipelined
 from .recurrent import GRU, GRUCell, LSTM, LSTMCell, RNN, RNNCell

@@ -9,7 +9,9 @@ demo).
 adds ``comm=`` — with a communicator the sequence axis is sharded over the
 mesh and scores accumulate flash-style while K/V rotate on the ICI ring,
 so context length scales with the chip count (any length: the ring pads
-and masks ragged sequences).  With ``num_kv_heads < num_heads``
+and masks ragged sequences).  ``LatentAttention`` is multi-head latent
+attention as trained: keys and values expanded from one low-rank latent, a
+key part shared by all heads, values narrower than keys.  With ``num_kv_heads < num_heads``
 (grouped-query attention, beyond torch's module) the packed projection
 shrinks to (E + 2·num_kv_heads·head_dim, E) rows — torch state dicts then
 no longer round-trip, by construction.
@@ -19,9 +21,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .modules import Module
+from .modules import Linear, Module, rms_normalize
 
-__all__ = ["MultiheadAttention", "apply_rope"]
+__all__ = ["MultiheadAttention", "LatentAttention", "apply_rope"]
 
 
 def apply_rope(x, positions, base: float = 10000.0, pairing: str = "interleaved"):
@@ -422,3 +424,58 @@ class MultiheadAttention(Module):
                 probs = probs.mean(axis=1)
             return y, probs
         return y
+
+
+class LatentAttention(Module):
+    """Causal multi-head latent attention (MLA) without positional encoding,
+    in its training form: the latent is expanded to full keys and values,
+    not absorbed into the query.
+
+    ``q = x W_q`` as ``num_heads`` heads of ``qk_nope_dim + qk_shared_dim``;
+    ``[c, k_shared] = x W_kva`` (``kv_rank`` and ``qk_shared_dim`` wide);
+    ``[k_nope, v] = RMSNorm(c) W_kvb`` as heads of ``qk_nope_dim`` and
+    ``v_dim``; a head's key is ``[k_nope, k_shared]``, ``k_shared`` the same
+    for every head (published configurations call its width
+    ``qk_rope_head_dim``: with rotary positions it is the part that carries
+    them; here nothing is rotated).  Softmax of ``q k^T / sqrt(key width)``
+    over the earlier positions, the ``num_heads x v_dim`` values through
+    ``W_o``.  No bias anywhere.  Keys are wider than values, which
+    ``ops.flash_attention`` takes as they are.
+
+    The scores-softmax-values part runs under the scope ``ht.attention``,
+    as ``MultiheadAttention``'s does.  A weight matrix may be kept in a wider
+    dtype than ``x``: it is brought to ``x``'s dtype where it is used.
+    """
+
+    def __init__(self, embed_dim: int, num_heads: int, *, kv_rank: int, qk_nope_dim: int,
+                 qk_shared_dim: int, v_dim: int, eps: float = 1e-5):
+        self.embed_dim, self.num_heads, self.kv_rank = embed_dim, num_heads, kv_rank
+        self.qk_nope_dim, self.qk_shared_dim, self.v_dim, self.eps = qk_nope_dim, qk_shared_dim, v_dim, eps
+
+    def init(self, key):
+        e, h, r = self.embed_dim, self.num_heads, self.kv_rank
+        shapes = {"q_proj": (e, h * (self.qk_nope_dim + self.qk_shared_dim)),
+                  "kv_a_proj": (e, r + self.qk_shared_dim),
+                  "kv_b_proj": (r, h * (self.qk_nope_dim + self.v_dim)),
+                  "out_proj": (h * self.v_dim, e)}
+        out = {name: Linear(*shape, bias=False).init(jax.random.fold_in(key, i))
+               for i, (name, shape) in enumerate(shapes.items())}
+        out["kv_a_norm"] = {"weight": jnp.ones((r,))}
+        return out
+
+    def apply(self, params, x, *, causal: bool = True, **kw):
+        from ..ops.flash_attention import flash_attention
+
+        b, s, _ = x.shape
+        h, nope, shared = self.num_heads, self.qk_nope_dim, self.qk_shared_dim
+        w = {n: params[n]["weight"].astype(x.dtype) for n in ("q_proj", "kv_a_proj", "kv_b_proj", "out_proj")}
+        heads = lambda t: t.reshape(b, s, h, -1).transpose(0, 2, 1, 3)  # noqa: E731
+        q = heads(x @ w["q_proj"].T)
+        c, k_shared = jnp.split(x @ w["kv_a_proj"].T, [self.kv_rank], axis=-1)
+        c = rms_normalize(c, params["kv_a_norm"]["weight"], self.eps)
+        kv = heads(c @ w["kv_b_proj"].T)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_shared[:, None], (b, h, s, shared))], axis=-1)
+        with jax.named_scope("ht.attention"):
+            out = flash_attention(q, k, kv[..., nope:], causal=causal, scale=(nope + shared) ** -0.5)
+        return out.transpose(0, 2, 1, 3).reshape(b, s, h * self.v_dim) @ w["out_proj"].T

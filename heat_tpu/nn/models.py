@@ -12,9 +12,10 @@ trainers' baselines); ``transformer_encoder`` / ``transformer_decoder`` /
 learned/rope/sinusoidal positions, GQA, an optional capacity-routed ``MoE``
 FFN, KV-cache generation); and ``PatternLM``, a pre-norm causal LM whose
 every layer is chosen from the configuration (sequence operator: gated
-short convolution or QK-normed rotary GQA; feed-forward: SwiGLU or
-sigmoid-routed drop-free experts, of which this rank may hold a range),
-RMSNorm, tied embedding, float32 parameters under lower-precision
+short convolution, QK-normed rotary GQA, Kimi Delta Attention or latent
+attention without positions; feed-forward: SwiGLU or sigmoid-routed
+drop-free experts, of which this rank may hold a range, with or without a
+shared expert), RMSNorm, tied or untied head, float32 parameters under lower-precision
 activations.  ``PatternLM`` trains through ``DataParallel.make_train_step``;
 it has no decode path yet.
 """
@@ -24,6 +25,7 @@ from __future__ import annotations
 from typing import Sequence
 
 from . import modules as nn
+from .linear_attention import KimiDeltaAttention
 
 __all__ = ["resnet", "resnet18", "resnet34", "resnet50", "resnet50_ish", "mlp", "transformer_encoder", "transformer_decoder", "TransformerLM", "Seq2SeqTransformer", "PatternLM"]
 
@@ -1098,6 +1100,10 @@ def _names(path):
     return [str(getattr(k, "key", "")) for k in path]
 
 
+# a Kimi Delta Attention layer's decay vectors: drawn by the operator, never decayed
+_DECAY_VECTORS = ("A_log", "dt_bias")
+
+
 def _is_norm(names) -> bool:
     return any(n.endswith("norm") for n in names)
 
@@ -1147,7 +1153,8 @@ class _PatternBlock(nn.Module):
         self.ffn = ffn
         self.routed = hasattr(ffn, "apply_with_stats")
         # the operator's projections, beside the scope the operator gives its core
-        self.scope = "ht.shortconv.proj" if isinstance(operator, _ShortConvOperator) else "ht.attention.proj"
+        self.scope = ("ht.shortconv.proj" if isinstance(operator, _ShortConvOperator)
+                      else "ht.kda.proj" if isinstance(operator, KimiDeltaAttention) else "ht.attention.proj")
 
     def init(self, key):
         import jax
@@ -1176,10 +1183,15 @@ class PatternLM(nn.Module):
     """Pre-norm causal language model whose layers follow a pattern.
 
     ``layer_types[l]`` names layer ``l``'s sequence operator: ``"conv"`` (a
-    gated short convolution of ``conv_taps`` positions) or
-    ``"full_attention"`` (causal grouped-query attention with RMS-normalised
-    query and key heads and rotate-half rotary positions of base
-    ``rope_base``).  The first ``num_dense_layers`` layers have a SwiGLU
+    gated short convolution of ``conv_taps`` positions), ``"full_attention"``
+    (causal grouped-query attention with RMS-normalised query and key heads
+    and rotate-half rotary positions of base ``rope_base``), ``"kda"`` (Kimi
+    Delta Attention, ``kda_heads`` heads of ``kda_head_dim`` with a
+    convolution of ``conv_taps`` positions, low-rank gates of ``kda_gate_rank``
+    and chunks of ``kda_chunk`` tokens: :class:`~heat_tpu.nn.KimiDeltaAttention`)
+    or ``"mla"`` (latent attention without positions, ``num_heads`` heads of
+    ``qk_nope_dim + qk_shared_dim`` on a latent of ``kv_rank`` with values of
+    ``v_dim``: :class:`~heat_tpu.nn.LatentAttention`).  The first ``num_dense_layers`` layers have a SwiGLU
     feed-forward of width ``ffn_dim``; with ``num_experts`` set, every later
     layer has ``num_experts`` SwiGLU experts of width ``expert_dim``,
     ``experts_per_token`` of them a token, chosen by sigmoid scores plus a
@@ -1187,11 +1199,16 @@ class PatternLM(nn.Module):
     without drops (``MoE(dispatch="sorted")``).  ``experts_held`` (a
     ``range``) says which experts' weights live on this rank: the router
     still scores all of them, and what the absent ones would add is left
-    out.  RMSNorm everywhere, no bias anywhere, the token embedding is also
-    the output head.
+    out.  ``shared_expert_dim`` adds a SwiGLU of that width that every token
+    of an expert layer goes through, and ``expert_rows_bound`` sizes the
+    expert layers' buffers (``MoE(shared_dim=, rows_bound=)``).  RMSNorm
+    everywhere, no bias anywhere; the token embedding is also the output
+    head unless ``tie_embedding=False`` gives the head a matrix of its own.
 
     Parameters are float32, drawn ``N(0, init_std)`` (norm weights 1, the
-    selection bias ``N(0, bias_std)`` and then fixed).  ``dtype`` is the
+    selection bias ``N(0, bias_std)`` and then fixed, a ``"kda"`` layer's
+    ``A_log = log U(1, 16)`` and ``dt_bias = softplus^-1(dt)`` with ``log dt
+    ~ U(log 0.001, log 0.1)``).  ``dtype`` is the
     dtype of the activations and of the operands of the matrix products
     (``None``: the parameters'); norms' statistics, routing scores, softmax
     and loss stay float32.  Every block is rematerialised under ``grad``.
@@ -1200,8 +1217,9 @@ class PatternLM(nn.Module):
     (B, S, vocab) in dtype, stats)``; ``stats`` holds, per expert layer, the
     rows routed to each expert held and the rows dropped (always 0 on this
     path), for the ``stats=`` hook of ``DataParallel.make_train_step``.
-    ``decay_mask(params)`` is the usual weight-decay mask (matrices yes;
-    norms, selection bias and embedding no).
+    ``decay_mask(params)`` is the usual weight-decay mask (matrices, an
+    untied head among them, yes; norms, selection bias, embedding, ``A_log``
+    and ``dt_bias`` no).
     """
 
     def __init__(self, vocab_size: int, embed_dim: int, layer_types: Sequence[str], *,
@@ -1210,38 +1228,58 @@ class PatternLM(nn.Module):
                  experts_per_token: int = 2, expert_dim: int = None, experts_held=None,
                  routed_scaling: float = 1.0, norm_topk: bool = True,
                  conv_taps: int = 3, rope_base: float = 1e6, norm_eps: float = 1e-5,
-                 init_std: float = 0.02, bias_std: float = 0.0, dtype=None):
-        from .attention import MultiheadAttention
+                 init_std: float = 0.02, bias_std: float = 0.0, dtype=None,
+                 tie_embedding: bool = True, shared_expert_dim: int = None,
+                 expert_rows_bound: int = None, kda_heads: int = None, kda_head_dim: int = None,
+                 kda_gate_rank: int = None, kda_chunk: int = 64, kv_rank: int = None,
+                 qk_nope_dim: int = None, qk_shared_dim: int = None, v_dim: int = None):
+        from .attention import LatentAttention, MultiheadAttention
         from .moe import MoE
 
-        unknown = sorted(set(layer_types) - {"conv", "full_attention"})
+        unknown = sorted(set(layer_types) - {"conv", "full_attention", "kda", "mla"})
         if unknown:
-            raise ValueError(f"layer_types may hold 'conv' and 'full_attention', got {unknown}")
+            raise ValueError(
+                f"layer_types may hold 'conv', 'full_attention', 'kda' and 'mla', got {unknown}")
         n_dense = len(layer_types) if num_dense_layers is None or not num_experts else num_dense_layers
         self.vocab_size, self.embed_dim = vocab_size, embed_dim
         self.layer_types = tuple(layer_types)
         self.init_std, self.bias_std, self.dtype = init_std, bias_std, dtype
         self.embed = nn.Embedding(vocab_size, embed_dim)
+        self.head = None if tie_embedding else nn.Linear(embed_dim, vocab_size, bias=False)
+        operators = {
+            "conv": lambda: _ShortConvOperator(embed_dim, conv_taps),
+            "full_attention": lambda: MultiheadAttention(
+                embed_dim, num_heads, bias=False, rope=True, rope_base=rope_base,
+                rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=True, qk_norm_eps=norm_eps),
+            "kda": lambda: KimiDeltaAttention(
+                embed_dim, kda_heads, kda_head_dim, conv_taps=conv_taps, gate_rank=kda_gate_rank,
+                chunk=kda_chunk, eps=norm_eps),
+            "mla": lambda: LatentAttention(
+                embed_dim, num_heads, kv_rank=kv_rank, qk_nope_dim=qk_nope_dim,
+                qk_shared_dim=qk_shared_dim, v_dim=v_dim, eps=norm_eps),
+        }
         self.blocks = []
         for i, kind in enumerate(self.layer_types):
-            operator = _ShortConvOperator(embed_dim, conv_taps) if kind == "conv" else MultiheadAttention(
-                embed_dim, num_heads, bias=False, rope=True, rope_base=rope_base,
-                rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=True, qk_norm_eps=norm_eps)
             ffn = nn.SwiGLU(embed_dim, ffn_dim) if i < n_dense else MoE(
                 embed_dim, num_experts, hidden_dim=expert_dim, top_k=experts_per_token,
                 gated=True, scoring="sigmoid", expert_bias=True, norm_topk=norm_topk,
-                routed_scaling=routed_scaling, dispatch="sorted", experts_held=experts_held)
-            self.blocks.append(_PatternBlock(embed_dim, operator, ffn, norm_eps))
+                routed_scaling=routed_scaling, dispatch="sorted", experts_held=experts_held,
+                shared_dim=shared_expert_dim, rows_bound=expert_rows_bound)
+            self.blocks.append(_PatternBlock(embed_dim, operators[kind](), ffn, norm_eps))
         self.norm = nn.RMSNorm(embed_dim, eps=norm_eps)
         self._remat_fns = [{} for _ in self.blocks]
 
     def _structure(self, key):
-        return {"embed": self.embed.init(key), "blocks": [b.init(key) for b in self.blocks],
-                "norm": self.norm.init(None)}
+        out = {"embed": self.embed.init(key), "blocks": [b.init(key) for b in self.blocks],
+               "norm": self.norm.init(None)}
+        if self.head is not None:
+            out["head"] = self.head.init(key)
+        return out
 
     def init(self, key):
         """Every matrix ``N(0, init_std)``, every norm weight 1, the selection
-        bias ``N(0, bias_std)``: one draw per leaf, keyed by its place."""
+        bias ``N(0, bias_std)``, ``A_log`` and ``dt_bias`` as their operator
+        draws them: one draw per leaf, keyed by its place."""
         import jax
         import jax.numpy as jnp
 
@@ -1252,6 +1290,9 @@ class PatternLM(nn.Module):
             names = _names(path)
             if _is_norm(names):
                 return jnp.ones(leaf.shape, jnp.float32)
+            if names[-1] in _DECAY_VECTORS:  # the operator's own draw, under this leaf's key
+                drawn = self.blocks[path[1].idx].operator.init_decay(jax.random.fold_in(key, i))
+                return drawn[names[-1]].astype(jnp.float32)
             std = self.bias_std if "expert_bias" in names else self.init_std
             return std * jax.random.normal(jax.random.fold_in(key, i), leaf.shape, jnp.float32)
 
@@ -1263,13 +1304,15 @@ class PatternLM(nn.Module):
 
         def decays(path, _):
             names = _names(path)
-            return not ("embed" in names or "expert_bias" in names or _is_norm(names))
+            return not ("embed" in names or "expert_bias" in names or _is_norm(names)
+                        or names[-1] in _DECAY_VECTORS)
 
         return jax.tree_util.tree_map_with_path(decays, params)
 
     def _cast(self, tree):
         """The matrices in the activations' dtype; vectors (norm weights,
-        the selection bias) and the router stay float32."""
+        the selection bias, ``A_log``, ``dt_bias``) and the router stay
+        float32."""
         import jax
 
         if self.dtype is None:
@@ -1283,7 +1326,8 @@ class PatternLM(nn.Module):
     def apply(self, params, tokens, *, train: bool = False, key=None):
         import jax
 
-        embedding = self._cast(params["embed"])["weight"]  # also the output head
+        embedding = self._cast(params["embed"])["weight"]  # also the output head, if tied
+        head = embedding if self.head is None else self._cast(params["head"])["weight"]
         with jax.named_scope("ht.lm.embed"):
             h = embedding[tokens]
         stats = []
@@ -1296,5 +1340,5 @@ class PatternLM(nn.Module):
                 stats.append(s)
         with jax.named_scope("ht.lm.head_loss"):
             h = self.norm.apply(params["norm"], h)
-            logits = h @ embedding.T
+            logits = h @ head.T
         return logits, stats

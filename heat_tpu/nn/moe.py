@@ -27,6 +27,14 @@ The router still scores every expert and picks ``top_k`` of them; only the
 held experts have parameters, and what the others would add is left out of
 the result.  It runs on one chip without any exchange (``comm`` must be
 ``None``): the exchange of a multi-chip no-drop layer is not built yet.
+``rows_bound`` sizes this path's buffers: by default they hold a row for
+every token-slot (``tokens x top_k``), although a rank that holds 8 of 256
+experts computes a thirty-second of them; with ``rows_bound`` the gathered
+rows, the grouped products and the way back have that many rows, and a slot
+of a held expert past the bound is left out of the result and counted in
+``stats["dropped"]`` (a bound to size generously and to watch, not a
+capacity factor).  ``shared_dim`` adds a gated FFN of that width that every
+token goes through, beside the routed experts (a shared expert).
 
 Routing is token-choice top-k.  ``scoring="softmax"`` renormalizes the
 selected probabilities by their sum; ``scoring="sigmoid"`` scores each
@@ -46,7 +54,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
-from .modules import Module
+from .modules import Module, SwiGLU
 from ..core._cache import comm_cached
 
 __all__ = ["MoE"]
@@ -189,6 +197,8 @@ class MoE(Module):
         routed_scaling: float = 1.0,
         dispatch: str = "capacity",
         experts_held=None,
+        shared_dim: int | None = None,
+        rows_bound: int | None = None,
     ):
         if top_k < 1 or top_k > num_experts:
             raise ValueError(f"top_k {top_k} must be in [1, num_experts={num_experts}]")
@@ -201,8 +211,11 @@ class MoE(Module):
             raise ValueError(f"experts_held {experts_held!r} is not a range of the {num_experts} experts")
         held = (held.start, held.stop)
         if dispatch == "capacity" and (held != (0, num_experts) or gated or scoring != "softmax"
-                                       or expert_bias):
-            raise ValueError("experts_held, gated experts and sigmoid/bias routing need dispatch='sorted'")
+                                       or expert_bias or shared_dim or rows_bound):
+            raise ValueError("experts_held, gated experts, sigmoid/bias routing, a shared expert "
+                             "and rows_bound need dispatch='sorted'")
+        if rows_bound is not None and rows_bound < 1:
+            raise ValueError(f"rows_bound {rows_bound} must be at least 1")
         if dispatch == "sorted" and comm is not None:
             raise ValueError("dispatch='sorted' runs on one chip: the no-drop exchange is not built")
         if batch_axis is not None:
@@ -229,6 +242,8 @@ class MoE(Module):
         self.routed_scaling = routed_scaling
         self.dispatch = dispatch
         self.experts_held = held  # (first id, one past the last)
+        self.shared = SwiGLU(embed_dim, shared_dim) if shared_dim else None
+        self.rows_bound = rows_bound
 
     @property
     def _program_key(self):
@@ -256,6 +271,8 @@ class MoE(Module):
                 out.update(b1=jnp.zeros((held, H)), b2=jnp.zeros((held, D)))
             if self.expert_bias:
                 out["expert_bias"] = jnp.zeros((E,))
+            if self.shared is not None:
+                out["shared"] = self.shared.init(jax.random.fold_in(key, 1))
             return out
         return {
             "router": jax.random.uniform(kr, (D, E), minval=-bound1, maxval=bound1),
@@ -326,40 +343,80 @@ class MoE(Module):
         """``(y, stats)``: every token-slot whose expert is held goes through
         that expert; nothing else is computed and nothing is dropped."""
         n, k = x2d.shape[0], self.top_k
-        lo, hi = self.experts_held
-        held = hi - lo
         with jax.named_scope("ht.moe.route"):
             val, idx = self._route(params, x2d)
+        if self.rows_bound is not None:
+            return self._sorted_bounded(params, x2d, val, idx)
         with jax.named_scope("ht.moe.dispatch"):
-            # slot i = token i // k; the slots of experts not held sort last
-            flat = idx.reshape(-1)
-            here = (flat >= lo) & (flat < hi)
-            group = jnp.where(here, flat - lo, held)
+            here, group, rows = self._held_groups(idx)
             order = jnp.argsort(group, stable=True)
             back = jnp.argsort(order)
-            rows = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
             in_group = jnp.arange(n * k) < jnp.sum(rows)
             xs = _permute_rows(jnp.repeat(x2d, k, axis=0), order, back)
             # a grouped product leaves the rows past its groups undefined
             xs = jnp.where(in_group[:, None], xs, 0)
         with jax.named_scope("ht.moe.experts"):
-            dt = x2d.dtype
-            h = jax.lax.ragged_dot(xs, params["w1"].astype(dt), rows)
-            if self.gated:
-                h = jax.nn.silu(h) * jax.lax.ragged_dot(xs, params["w3"].astype(dt), rows)
-            else:
-                h = jax.nn.gelu(h + jnp.repeat(params["b1"].astype(dt), rows, axis=0,
-                                               total_repeat_length=n * k))
-            ys = jax.lax.ragged_dot(h, params["w2"].astype(dt), rows)
-            if not self.gated:
-                ys = ys + jnp.repeat(params["b2"].astype(dt), rows, axis=0, total_repeat_length=n * k)
-            ys = jnp.where(in_group[:, None], ys, 0)
+            ys = self._experts_grouped(params, xs, rows, in_group)
         with jax.named_scope("ht.moe.combine"):
             per_slot = _permute_rows(ys, back, order).reshape(n, k, -1)
             y = jnp.einsum("nk,nkd->nd", val.astype(jnp.float32), per_slot.astype(jnp.float32))
         # the buffer has a row for every token-slot, so this is 0 by construction
         stats = {"rows": rows, "dropped": jnp.sum(here, dtype=jnp.int32) - jnp.sum(rows)}
-        return y.astype(x2d.dtype), stats
+        return self._add_shared(params, x2d, y.astype(x2d.dtype)), stats
+
+    def _held_groups(self, idx):
+        """Of every token-slot (slot ``i`` is token ``i // k``): whether its
+        expert is held, its group (the held expert's place, the experts not
+        held last), and the slots routed to each expert held."""
+        lo, hi = self.experts_held
+        flat = idx.reshape(-1)
+        here = (flat >= lo) & (flat < hi)
+        group = jnp.where(here, flat - lo, hi - lo)
+        rows = jnp.sum(group[:, None] == jnp.arange(hi - lo)[None, :], axis=0, dtype=jnp.int32)
+        return here, group, rows
+
+    def _experts_grouped(self, params, xs, rows, in_group):
+        """The held experts over rows sorted by expert, ``rows[e]`` of them
+        for expert ``e``; rows past the groups come out 0."""
+        dt, n_rows = xs.dtype, xs.shape[0]
+        h = jax.lax.ragged_dot(xs, params["w1"].astype(dt), rows)
+        if self.gated:
+            h = jax.nn.silu(h) * jax.lax.ragged_dot(xs, params["w3"].astype(dt), rows)
+        else:
+            h = jax.nn.gelu(h + jnp.repeat(params["b1"].astype(dt), rows, axis=0,
+                                           total_repeat_length=n_rows))
+        ys = jax.lax.ragged_dot(h, params["w2"].astype(dt), rows)
+        if not self.gated:
+            ys = ys + jnp.repeat(params["b2"].astype(dt), rows, axis=0, total_repeat_length=n_rows)
+        return jnp.where(in_group[:, None], ys, 0)
+
+    def _sorted_bounded(self, params, x2d, val, idx):
+        """The sorted path with buffers of ``rows_bound`` rows: the first
+        ``rows_bound`` token-slots in expert order are gathered, computed and
+        added back into their tokens' rows; the rest are counted as dropped."""
+        n, k, bound = x2d.shape[0], self.top_k, self.rows_bound
+        with jax.named_scope("ht.moe.dispatch"):
+            _, group, routed = self._held_groups(idx)
+            take = jnp.argsort(group, stable=True)[:bound]  # held slots first, by expert
+            before = jnp.cumsum(routed) - routed
+            rows = jnp.clip(bound - before, 0, routed)  # what the buffer takes of each expert
+            in_group = jnp.arange(take.shape[0]) < jnp.sum(rows)
+            token = take // k
+            xs = jnp.where(in_group[:, None], x2d[token], 0)
+        with jax.named_scope("ht.moe.experts"):
+            ys = self._experts_grouped(params, xs, rows, in_group)
+        with jax.named_scope("ht.moe.combine"):
+            weight = jnp.where(in_group, val.reshape(-1)[take], 0).astype(jnp.float32)
+            y = jnp.zeros((n, ys.shape[-1]), jnp.float32).at[token].add(
+                weight[:, None] * ys.astype(jnp.float32))
+        stats = {"rows": routed, "dropped": jnp.sum(routed) - jnp.sum(rows)}
+        return self._add_shared(params, x2d, y.astype(x2d.dtype)), stats
+
+    def _add_shared(self, params, x2d, y):
+        if self.shared is None:
+            return y
+        with jax.named_scope("ht.moe.shared"):
+            return y + self.shared.apply(params["shared"], x2d)
 
     def apply_with_stats(self, params, x):
         """``(y, {"rows": rows routed to each expert held, "dropped": token-

@@ -9,5 +9,6 @@ path is testable on the dev mesh); a selected kernel runs or raises.
 """
 
 from .flash_attention import flash_attention
+from .kda import chunk_kda
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "chunk_kda"]

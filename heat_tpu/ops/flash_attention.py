@@ -607,16 +607,18 @@ def _block_shape(S: int, d: int, itemsize: int):
     return blk, blk
 
 
-def _pallas_gate(q, S: int, d: int):
+def _pallas_gate(q, S: int, d: int, dv: Optional[int] = None):
     """THE kernel-dispatch gate, shared by every flash entry point so the
     platform policy and VMEM budget cannot drift between them.  The
     platform is that of ``q``'s devices (``platform_of``).  CPU runs the
     interpreter (slow): test scale only, like the kmeans kernels'
     16384-row gate.  The VMEM estimate covers Q/K/V/O blocks + scores +
-    accumulator in f32; shapes past it take the dense form.  Returns
+    accumulator in f32; shapes past it take the dense form; ``d`` is the
+    wider of the key's and the value's width (``dv``).  Returns
     ``(use_pallas, blk, platform)``, ``blk`` the multiple to pad ``S`` to."""
     platform = platform_of(q)
     use_pallas = platform == "tpu" or (platform == "cpu" and S <= 512)
+    d = max(d, dv or d)
     blk_q, blk_k = _block_shape(S, d, q.dtype.itemsize)
     if use_pallas:
         vmem = 4 * (3 * blk_q * d + 2 * blk_k * d + blk_q * blk_k + 2 * blk_q)
@@ -866,23 +868,26 @@ def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None):
     """Softmax attention over a local block, flash-fused on TPU.
 
-    ``q, k, v``: identical shapes ``(..., S, d)`` (leading batch/head axes
-    collapse internally).  Returns ``(..., S, d)`` in ``q``'s dtype.  The
-    causal mask is top-left aligned (torch ``is_causal``).  Accumulation is
-    f32 regardless of input dtype (bf16 inputs stay bf16 through the GEMM
-    operands — the MXU's native layout).
+    ``q, k``: identical shapes ``(..., S, d)`` (leading batch/head axes
+    collapse internally); ``v``: ``(..., S, d_v)``, of the same width or of
+    another (latent attention: 192-wide keys, 128-wide values).  Returns
+    ``(..., S, d_v)`` in ``q``'s dtype.  The causal mask is top-left aligned
+    (torch ``is_causal``).  Accumulation is f32 regardless of input dtype
+    (bf16 inputs stay bf16 through the GEMM operands — the MXU's native
+    layout).
     """
-    if k.shape != q.shape or v.shape != q.shape:
+    if k.shape != q.shape or v.shape[:-1] != q.shape[:-1]:
         raise ValueError(
-            f"flash_attention requires identically-shaped q/k/v, got "
-            f"{q.shape}, {k.shape}, {v.shape}"
+            f"flash_attention requires q and k of one shape and v of their "
+            f"leading axes, got {q.shape}, {k.shape}, {v.shape}"
         )
     S, d = q.shape[-2:]
+    dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
     scale = float(scale)
 
-    use_pallas, blk, platform = _pallas_gate(q, S, d)
+    use_pallas, blk, platform = _pallas_gate(q, S, d, dv)
     if not use_pallas:
         path_counts["dense"] += 1
         return _dense_attention(q, k, v, causal, scale, S)
@@ -892,11 +897,11 @@ def flash_attention(q, k, v, causal: bool = False,
     for a in lead:
         B *= int(a)
     out = _run_flash_padded(
-        (q.reshape((B, S, d)), k.reshape((B, S, d)), v.reshape((B, S, d))),
+        (q.reshape((B, S, d)), k.reshape((B, S, d)), v.reshape((B, S, dv))),
         S, blk,
         lambda a, b, c: _flash(a, b, c, causal, scale, S, platform == "cpu"),
     )
-    return out.reshape(q.shape)
+    return out.reshape(v.shape)
 
 
 # --------------------------------------------------------------------- #
@@ -943,7 +948,8 @@ def _first_live_q(ik, blk_q: int, blk_k: int, causal: bool):
 def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
                         hq: int, hk: int, interpret: bool):
     BHq, Sp, d = q.shape
-    blk_q, blk_k = _block_shape(Sp, d, q.dtype.itemsize)
+    dv = v.shape[-1]
+    blk_q, blk_k = _block_shape(Sp, max(d, dv), q.dtype.itemsize)
     nq, nk = Sp // blk_q, Sp // blk_k
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, s_valid=s_valid,
@@ -953,29 +959,31 @@ def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
     kvrow = functools.partial(_gqa_kv_row, hq=hq, hk=hk)
     last_k = functools.partial(_last_live_k, blk_q=blk_q, blk_k=blk_k,
                                s_valid=s_valid, causal=causal)
-    kspec = pl.BlockSpec(
-        (1, blk_k, d),
-        lambda b, iq, ik: (kvrow(b), jnp.minimum(ik, last_k(iq)), 0))
+    def kspec(width):
+        return pl.BlockSpec(
+            (1, blk_k, width),
+            lambda b, iq, ik: (kvrow(b), jnp.minimum(ik, last_k(iq)), 0))
+
     return pl.pallas_call(
         kernel,
         grid=(BHq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
-            kspec, kspec,
+            kspec(d), kspec(dv),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, blk_q, dv), lambda b, iq, ik: (b, iq, 0)),
             pl.BlockSpec((1, 1, blk_q), lambda b, iq, ik: (b, 0, iq)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
+            jax.ShapeDtypeStruct((BHq, Sp, dv), q.dtype),
             jax.ShapeDtypeStruct((BHq, 1, Sp), jnp.float32),  # logsumexp
         ],
         scratch_shapes=[
             # (blk_q, 1) not (blk_q,): TPU scratch wants >=2-D tiles
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, d), jnp.float32),
+            pltpu.VMEM((blk_q, dv), jnp.float32),
             pltpu.VMEM((blk_q, d), q.dtype),  # the score product's Q operand
         ],
         interpret=interpret,
@@ -989,9 +997,9 @@ def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
 def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
                         s_valid: int, hq: int, hk: int, interpret: bool):
     BHq, Sp, d = q.shape
-    BHk = k.shape[0]
+    BHk, dv = k.shape[0], v.shape[-1]
     g = hq // hk
-    blk_q, blk_k = _block_shape(Sp, d, q.dtype.itemsize)
+    blk_q, blk_k = _block_shape(Sp, max(d, dv), q.dtype.itemsize)
     nq, nk = Sp // blk_q, Sp // blk_k
     masked = causal or (Sp != s_valid)
     # D_i = Σ_d dOᵢ ⊙ Oᵢ — one cheap fused elementwise pass, fine in XLA;
@@ -1004,10 +1012,15 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
                                 causal=causal)
 
     # dq sweep: Q block fixed per middle grid index, K/V blocks stream
-    qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec(
-        (1, blk_k, d),
-        lambda b, i, j: (kvrow(b), jnp.minimum(j, last_k(i)), 0))
+    # q and k are ``d`` wide, v and the output's cotangent ``dv``
+    def qspec(width):
+        return pl.BlockSpec((1, blk_q, width), lambda b, i, j: (b, i, 0))
+
+    def kspec(width):
+        return pl.BlockSpec(
+            (1, blk_k, width),
+            lambda b, i, j: (kvrow(b), jnp.minimum(j, last_k(i)), 0))
+
     rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(
@@ -1015,8 +1028,8 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
             blk_q=blk_q, blk_k=blk_k, nk=nk, masked=masked,
         ),
         grid=(BHq, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=qspec,
+        in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), rowspec, rowspec],
+        out_specs=qspec(d),
         out_shape=jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, d), jnp.float32),
@@ -1034,9 +1047,13 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     def qblk(j, i):
         return jnp.maximum(i % nq, first_q(j))
 
-    qspec2 = pl.BlockSpec((1, blk_q, d),
-                          lambda b, j, i: (qrow(b, i), qblk(j, i), 0))
-    kspec2 = pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0))
+    def qspec2(width):
+        return pl.BlockSpec((1, blk_q, width),
+                            lambda b, j, i: (qrow(b, i), qblk(j, i), 0))
+
+    def kspec2(width):
+        return pl.BlockSpec((1, blk_k, width), lambda b, j, i: (b, j, 0))
+
     rowspec2 = pl.BlockSpec((1, 1, blk_q),
                             lambda b, j, i: (qrow(b, i), 0, qblk(j, i)))
     dk, dv = pl.pallas_call(
@@ -1046,15 +1063,15 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
             nq_inner=nq, masked=masked,
         ),
         grid=(BHk, nk, g * nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec2(d), kspec2(d), kspec2(dv), qspec2(dv), rowspec2, rowspec2],
+        out_specs=[kspec2(d), kspec2(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((BHk, Sp, d), k.dtype),
-            jax.ShapeDtypeStruct((BHk, Sp, d), v.dtype),
+            jax.ShapeDtypeStruct((BHk, Sp, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
-            pltpu.VMEM((blk_k, d), jnp.float32),
+            pltpu.VMEM((blk_k, dv), jnp.float32),
             pltpu.VMEM((blk_k, d), k.dtype),  # the score product's K operand
         ],
         interpret=interpret,
@@ -1098,22 +1115,23 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
                         scale: Optional[float] = None):
     """Grouped-query attention, flash-fused on TPU without repeating K/V.
 
-    ``q``: ``(..., H_q, S, d)``; ``k, v``: ``(..., H_kv, S, d)`` with
-    ``H_q % H_kv == 0`` and identical leading axes.  Each query head
+    ``q``: ``(..., H_q, S, d)``; ``k``: ``(..., H_kv, S, d)``; ``v``:
+    ``(..., H_kv, S, d_v)`` with ``H_q % H_kv == 0`` and identical leading
+    axes.  Each query head
     attends its group's shared K/V head straight from the kernel's index
     map — the ``H_q/H_kv``-fold K/V broadcast that ``jnp.repeat`` would
     write to HBM never materializes, forward or backward.  Returns
-    ``(..., H_q, S, d)`` in q's dtype; same causal/masked-row semantics as
+    ``(..., H_q, S, d_v)`` in q's dtype; same causal/masked-row semantics as
     :func:`flash_attention`.  Dispatch follows ``_pallas_gate`` exactly
     like :func:`flash_attention` (TPU kernel; CPU interpreter at test
     scale; dense path over a repeated K/V everywhere else, incl. past the
     VMEM gate).
     """
-    if q.ndim < 3 or k.shape != v.shape or q.shape[:-3] != k.shape[:-3] \
+    if q.ndim < 3 or k.shape[:-1] != v.shape[:-1] or q.shape[:-3] != k.shape[:-3] \
             or q.shape[-2:] != k.shape[-2:]:
         raise ValueError(
-            f"flash_attention_gqa requires (..., H_q, S, d) q and "
-            f"(..., H_kv, S, d) k == v, got {q.shape}, {k.shape}, {v.shape}"
+            f"flash_attention_gqa requires (..., H_q, S, d) q, (..., H_kv, S, d) k "
+            f"and (..., H_kv, S, d_v) v, got {q.shape}, {k.shape}, {v.shape}"
         )
     hq, hk = q.shape[-3], k.shape[-3]
     if hq % hk:
@@ -1121,13 +1139,14 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
             f"query heads ({hq}) must be a multiple of key/value heads ({hk})"
         )
     S, d = q.shape[-2:]
+    dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
     scale = float(scale)
     if hq == hk:
         return flash_attention(q, k, v, causal=causal, scale=scale)
 
-    use_pallas, blk, platform = _pallas_gate(q, S, d)
+    use_pallas, blk, platform = _pallas_gate(q, S, d, dv)
     if not use_pallas:
         path_counts["dense"] += 1
         g = hq // hk
@@ -1142,9 +1161,9 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
         B *= int(a)
     out = _run_flash_padded(
         (q.reshape((B * hq, S, d)), k.reshape((B * hk, S, d)),
-         v.reshape((B * hk, S, d))),
+         v.reshape((B * hk, S, dv))),
         S, blk,
         lambda a, b, c: _flash_gqa(a, b, c, causal, scale, S, hq, hk,
                                    platform == "cpu"),
     )
-    return out.reshape(q.shape)
+    return out.reshape(q.shape[:-1] + (dv,))
