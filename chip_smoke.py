@@ -4,7 +4,8 @@ One process drives the three main paths through their ordinary entry points
 over the default mesh of every attached chip: the ``ht.*`` array path at
 BASELINE widths, the two trainers (``DataParallel`` MLP, ``DASO`` ResNet-50),
 and the model layer with the Pallas kernels engaged (``TransformerLM``, the
-flash-attention family against the dense reference); on more than one chip
+flash-attention family against the dense reference, ``chunk_kda``'s kernels
+against its XLA form); on more than one chip
 also the ring, expert-parallel, pipeline and two-tier DASO paths.  Every
 phase checks its result (shape, finiteness, agreement with a reference,
 placement on every chip) and the first fault raises: there is no ``try``
@@ -45,6 +46,7 @@ from heat_tpu.ops.flash_attention import (
     _dense_attention, flash_attention, flash_attention_block,
     flash_attention_gqa, path_counts,
 )
+from heat_tpu.ops import kda
 from heat_tpu.parallel.ring_attention import (
     _block_impl, path_counts as ring_counts, ring_attention,
 )
@@ -62,6 +64,7 @@ FULL = dict(
     lm=dict(vocab_size=32768, embed_dim=512, num_heads=8, depth=8, max_len=1024),
     lm_batch=8, lm_seq=1024, lm_prompt=64, lm_new=64,
     attn=(4, 8, 4096, 64), attn_kv_heads=2, attn_long=(2, 8, 32768, 64),
+    kda=(32, 8192, 128),  # one sequence of the cell kimi_linear_48b_a3b_train_2x8k
     ring=(2, 8, 4096, 64),  # S is per chip
     moe=dict(embed=1024, hidden=4096, experts_per_chip=8, tokens_per_chip=512),
     pipe=dict(embed=512, heads=8, seq=1024, batch_per_chip=2),
@@ -429,6 +432,48 @@ def model_flash(shape, kv_heads: int, long_shape) -> None:
           **{f"{n}_rel_err": f"{e:.2e}" for n, e in errs.items()})
 
 
+def model_kda(shape, chunk: int = 64) -> None:
+    """chunk_kda with the Pallas kernels as its chunk-local part, forward and
+    backward, bf16 with a float32 decay, against the XLA form of that part on
+    the same device(s); the heads are what the chips share."""
+    t0 = time.perf_counter()
+    comm = ht.communication.get_comm()
+    H, S, d = shape
+    assert H % comm.size == 0, "the head axis is what the chips share"
+    keys = jax.random.split(jax.random.key(11), 6)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], shape)) * d**-0.5
+    k = unit(jax.random.normal(keys[1], shape))
+    v, w = jax.random.normal(keys[2], shape), jax.random.normal(keys[3], shape)
+    g = -jax.random.uniform(keys[4], shape, minval=1e-3, maxval=0.5)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:2]))
+    q, k, v, w = (comm.shard(t.astype(jnp.bfloat16), 0) for t in (q, k, v, w))
+    ops = (q, k, v, comm.shard(g, 0), comm.shard(beta, 0))
+    before = dict(kda.path_counts)
+    out, state = kda.chunk_kda(*ops, chunk=chunk)
+    assert kda.path_counts == {**before, "pallas": before["pallas"] + 1}, (
+        f"chunk_kda: kda path_counts went {before} -> {kda.path_counts}; the "
+        f"Pallas path must rise and the dense path must not")
+    tile = kda._pallas_gate(q, v, chunk)
+
+    def both(tile):
+        # w an argument: closed over, it would be a constant of the executable
+        def loss(w, *a):
+            o, final = kda._chunk_kda(*a, chunk, tile)
+            return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)) + jnp.sum(final), o
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5), has_aux=True))(w, *ops)
+
+    ((_, o), grads), ((_, ref), ref_grads) = both(tile), both(0)
+    _spans_all((out, state, grads), "chunk_kda")
+    err = max(_rel_err(out, ref), _rel_err(o, ref))
+    grad_err = max(_rel_err(a, b) for a, b in zip(grads, ref_grads))
+    assert _finite(out) and _finite(state), "chunk_kda: not finite"
+    assert err < BF16_TOL and grad_err < BF16_TOL, (err, grad_err)
+    _done("model.kda", t0, shape="x".join(map(str, shape)), chunk=chunk, tile=tile,
+          dtype="bfloat16", kda_rel_err=f"{err:.2e}", kda_grad_rel_err=f"{grad_err:.2e}")
+
+
 # ---------------------------------------------------------------------- #
 # more than one chip
 # ---------------------------------------------------------------------- #
@@ -519,6 +564,7 @@ def run(s: dict) -> None:
                            s["daso_batch_per_chip"]),
         lambda: model_lm(s["lm"], s["lm_batch"], s["lm_seq"], s["lm_prompt"], s["lm_new"]),
         lambda: model_flash(s["attn"], s["attn_kv_heads"], s["attn_long"]),
+        lambda: model_kda(s["kda"]),
     ]
     n = len(jax.devices())
     if n > 1:

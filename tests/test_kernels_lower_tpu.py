@@ -70,6 +70,31 @@ def test_gqa_lowers_at_the_training_cell_shape():
             q, kv, kv)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_kernels_lower_at_the_training_cell_shape(monkeypatch, dtype):
+    """``kimi_linear_48b_a3b_train_2x8k``: 2 sequences x 32 heads of 128,
+    S = 8192, chunk 64, a float32 decay: the forward kernel (with and without
+    ``T`` among its results) and the backward kernel, as ``chunk_kda``'s
+    ``custom_vjp`` calls them."""
+    kda = importlib.import_module("heat_tpu.ops.kda")
+    monkeypatch.setattr(kda, "platform_of", lambda q: "tpu")  # the kernels, not their interpreter
+    wide = jax.ShapeDtypeStruct((2, 32, 8192, 128), dtype)
+    tile = kda._pallas_gate(wide, wide, 64)
+    assert tile == 8 and kda._backward_tile(tile, 64, 128) == 4
+
+    def f(q, k, v, g, beta):
+        def loss(*a):
+            o, final = kda._chunk_kda(*a, 64, tile)
+            return jnp.sum(o.astype(jnp.float32)) + jnp.sum(final)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    exported = jax.export.export(jax.jit(f), platforms=["tpu"])(
+        wide, wide, wide, jax.ShapeDtypeStruct(wide.shape, jnp.float32),
+        jax.ShapeDtypeStruct(wide.shape[:-1], jnp.float32))
+    assert exported.mlir_module().count("tpu_custom_call") >= 3
+
+
 def _gqa_case():
     import numpy as np
 
@@ -133,6 +158,38 @@ def test_per_shard_kernels_match_dense(monkeypatch):
                                         scale=d**-0.5, s_valid=S, impl="interpret")
     assert lse.shape == q.shape[:-1]
     close(out, dense(q, q[:, ::-1], q * 0.5), "block")
+
+
+def test_kda_kernels_per_shard_match_the_xla_form(monkeypatch):
+    """``chunk_kda``'s kernels (the interpreter here) a shard of the sequences
+    a device of the CPU mesh, as across chips: forward and five gradients
+    against the XLA form of the chunk-local part."""
+    import numpy as np
+
+    from heat_tpu.core.devices import get_default_mesh
+
+    kda = importlib.import_module("heat_tpu.ops.kda")
+    mesh = get_default_mesh()
+    monkeypatch.setattr(kda, "_kernel_mesh", lambda q: mesh)
+    keys = jax.random.split(jax.random.key(0), 5)
+    shape = (mesh.size, 128, 128)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    args = (unit(jax.random.normal(keys[0], shape)) * 128**-0.5, unit(jax.random.normal(keys[1], shape)),
+            jax.random.normal(keys[2], shape), -jax.random.uniform(keys[3], shape, minval=1e-3, maxval=0.5),
+            jax.nn.sigmoid(jax.random.normal(keys[4], shape[:2])))
+    assert kda._pallas_gate(args[0], args[2], 64) == 2
+
+    def both(tile):
+        def loss(*a):
+            o, final = kda._chunk_kda(*a, 64, tile)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(final), o
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        ((_, got), d_got), ((_, want), d_want) = both(2), both(0)
+    for a, b in zip((got, *d_got), (want, *d_want)):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=0)
 
 
 class TestNoQuietFallback:

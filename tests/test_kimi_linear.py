@@ -22,6 +22,7 @@ from heat_tpu.ops.kda import chunk_kda
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 fa = sys.modules["heat_tpu.ops.flash_attention"]  # ``heat_tpu.ops.flash_attention`` is the function
+kda = sys.modules["heat_tpu.ops.kda"]
 
 CFG = {
     "hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 32, "vocab_size": 96,
@@ -92,46 +93,111 @@ def _chunked(q, k, v, g, beta, chunk):
     return jnp.moveaxis(o, 0, 1), state
 
 
+# toy shapes take the XLA form of the chunk-local part, heads of 128 the Pallas
+# kernels (under the interpreter here): (chunk, length, d_k, d_v, path)
+TOY_16, TOY_32, KERNEL = (16, 48, 16, 8, "dense"), (32, 48, 16, 8, "dense"), (64, 192, 128, 128, "pallas")
+KERNEL_32 = (32, 96, 128, 256, "pallas")  # values wider than the keys
+
+
+def _value_and_grads(fn, args):
+    """``(fn(*args), its five gradients under a fixed cotangent)``, one program."""
+    def scalar(*a):
+        o, state = fn(*a)
+        w_o, w_s = jax.random.normal(jax.random.key(4), o.shape), jax.random.normal(jax.random.key(5), state.shape)
+        return jnp.sum(o * w_o) + jnp.sum(state * w_s), (o, state)
+
+    (_, out), grads = jax.jit(jax.value_and_grad(scalar, argnums=tuple(range(5)), has_aux=True))(*args)
+    return out, grads
+
+
 @pytest.mark.parametrize("strong", [False, True], ids=["mild_decay", "strong_decay"])
-@pytest.mark.parametrize("chunk", [16, 32])
-def test_chunk_kda_is_the_token_recurrence(chunk, strong):
+@pytest.mark.parametrize("chunk, length, dk, dv, path", [TOY_16, TOY_32, KERNEL, KERNEL_32],
+                         ids=["16", "32", "kernel_64", "kernel_32"])
+def test_chunk_kda_is_the_token_recurrence(chunk, length, dk, dv, path, strong):
     """Output, final state and all five gradients for a sequence of several
     chunks.  With the strong decay ``-G`` passes 88 inside a chunk, where a
     form that exponentiates ``-G`` alone has left float32."""
     with jax.default_matmul_precision("highest"):
-        args = _kda_inputs(strong=strong)
+        args = _kda_inputs(length, dk=dk, dv=dv, strong=strong)
         if strong:
             in_chunk = -jnp.cumsum(args[3][:chunk], axis=0)
             assert float(in_chunk.max()) > 88.8 and not np.isfinite(np.exp(np.float32(in_chunk.max())))
-        w_o, w_s = jax.random.normal(jax.random.key(4), (48, 2, 8)), jax.random.normal(jax.random.key(5), (2, 16, 8))
-        scalar = lambda out: jnp.sum(out[0] * w_o) + jnp.sum(out[1] * w_s)  # noqa: E731
-        both = lambda fn: jax.jit(jax.value_and_grad(  # noqa: E731
-            lambda *a: (lambda out: (scalar(out), out))(fn(*a)), argnums=tuple(range(5)), has_aux=True))(*args)
-        ((_, want), d_want), ((_, got), d_got) = both(ref.delta_rule), both(lambda *a: _chunked(*a, chunk))
+        before = dict(kda.path_counts)
+        want, d_want = _value_and_grads(ref.delta_rule, args)
+        got, d_got = _value_and_grads(lambda *a: _chunked(*a, chunk), args)
+        assert {n: kda.path_counts[n] - before[n] for n in before} == {"pallas": 0, "dense": 0, path: 1}
         close(got[0], want[0], 1e-5)
         close(got[1], want[1], 1e-5)
-        for a, b in zip(d_got, d_want):
+        for name, a, b in zip("qkvgb", d_got, d_want):
             assert np.all(np.isfinite(a))
-            close(a, b, 1e-5)
+            # d g at heads of 128 under the strong decay: the float32 token recurrence lies 2e-5
+            # (these kernels) to 5e-5 (the XLA form at the same shapes) from either
+            close(a, b, 5e-5 if (name, path, strong) == ("g", "pallas", True) else 1e-5)
 
 
-def test_chunk_kda_pads_a_ragged_length_and_batches_leading_axes():
+@pytest.mark.parametrize("strong", [False, True], ids=["mild_decay", "strong_decay"])
+def test_chunk_kda_kernels_are_the_xla_form(strong):
+    """The same inputs through both executors of the chunk-local part, a batch
+    axis before the heads: outputs and the five gradients."""
     with jax.default_matmul_precision("highest"):
-        q, k, v, g, beta = _kda_inputs(length=40)
+        args = tuple(jnp.stack([jnp.moveaxis(t, 1, 0), jnp.moveaxis(t[::-1], 1, 0)])
+                     for t in _kda_inputs(128, dk=128, dv=128, strong=strong))
+        assert kda._pallas_gate(args[0], args[2], 64) == 2
+        (want, d_want), (got, d_got) = (_value_and_grads(lambda *a, tile=tile: kda._chunk_kda(*a, 64, tile), args)
+                                        for tile in (0, 2))
+        for name, a, b in zip("osqkvgb", (*got, *d_got), (*want, *d_want)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            close(a, b, 1e-4 if (name, strong) == ("g", True) else 1e-5)  # see the test above
+
+
+@pytest.mark.parametrize("chunk, length, dk, dv, path", [(16, 40, 16, 8, "dense"), (64, 130, 128, 128, "pallas")],
+                         ids=["16", "kernel_64"])
+def test_chunk_kda_pads_a_ragged_length_and_batches_leading_axes(chunk, length, dk, dv, path):
+    """40 tokens are two and a half chunks of 16; 130 are three chunks of 64,
+    which the kernels take as one tile."""
+    with jax.default_matmul_precision("highest"):
+        q, k, v, g, beta = _kda_inputs(length, dk=dk, dv=dv)
         want_o, want_s = jax.jit(ref.delta_rule)(q, k, v, g, beta)
         two = lambda t: jnp.stack([jnp.moveaxis(t, 1, 0)] * 2)  # noqa: E731  (batch, heads, S, ...)
-        o, state = chunk_kda(two(q), two(k), two(v), two(g), two(beta), chunk=16)
-        assert o.shape == (2, 2, 40, 8) and state.shape == (2, 2, 16, 8) and state.dtype == jnp.float32
+        before = kda.path_counts[path]
+        o, state = chunk_kda(two(q), two(k), two(v), two(g), two(beta), chunk=chunk)
+        assert kda.path_counts[path] == before + 1
+        assert o.shape == (2, 2, length, dv) and state.shape == (2, 2, dk, dv) and state.dtype == jnp.float32
         close(jnp.moveaxis(o[1], 0, 1), want_o, 1e-5)
         close(state[0], want_s, 1e-5)
 
 
-def test_chunk_kda_in_bfloat16_stays_near():
-    q, k, v, g, beta = _kda_inputs()
+@pytest.mark.parametrize("chunk, length, dk, dv, path", [TOY_16, KERNEL], ids=["16", "kernel_64"])
+def test_chunk_kda_in_bfloat16_stays_near(chunk, length, dk, dv, path):
+    q, k, v, g, beta = _kda_inputs(length, dk=dk, dv=dv)
     want, _ = jax.jit(ref.delta_rule)(q, k, v, g, beta)
-    got, _ = _chunked(*(t.astype(jnp.bfloat16) for t in (q, k, v)), g, beta, 16)
-    assert got.dtype == jnp.bfloat16
+    before = kda.path_counts[path]
+    got, _ = _chunked(*(t.astype(jnp.bfloat16) for t in (q, k, v)), g, beta, chunk)
+    assert got.dtype == jnp.bfloat16 and kda.path_counts[path] == before + 1
     close(got.astype(jnp.float32), want, 3e-2)
+
+
+def test_chunk_kda_takes_the_kernels_by_platform_and_shapes(monkeypatch):
+    """The gate reads the platform of the data and the shapes and nothing
+    else: the chunks a grid step, or 0 for the XLA form."""
+    probe = lambda length, dk, dv: (jax.ShapeDtypeStruct((2, 4, length, dk), jnp.bfloat16),  # noqa: E731
+                                    jax.ShapeDtypeStruct((2, 4, length, dv), jnp.bfloat16))
+    assert kda._pallas_gate(*probe(128, 128, 128), 64) == 2
+    assert kda._pallas_gate(*probe(500, 128, 128), 64) == 8  # padded to a tile of eight chunks
+    assert kda._pallas_gate(*probe(500, 256, 128), 64) == 4  # half as many of heads twice as wide
+    assert kda._pallas_gate(*probe(8192, 128, 128), 64) == 0  # the interpreter, at test scale only
+    assert kda._pallas_gate(*probe(500, 128, 256), 128) == 2 and kda._backward_tile(2, 128, 256) == 1
+    for dk, dv, chunk in [(16, 8, 16), (128, 64, 64), (64, 128, 64), (128, 128, 8), (128, 128, 48), (128, 128, 256)]:
+        assert kda._pallas_gate(*probe(128, dk, dv), chunk) == 0
+    monkeypatch.setattr(kda, "platform_of", lambda q: "tpu")
+    monkeypatch.setattr(kda, "_kernel_mesh", lambda q: None)
+    assert kda._pallas_gate(*probe(8192, 128, 128), 64) == 8
+    monkeypatch.setattr(kda, "platform_of", lambda q: "gpu")
+    assert kda._pallas_gate(*probe(128, 128, 128), 64) == 0
+    q, k, v, g, beta = (jnp.moveaxis(t, 1, 0) for t in _kda_inputs(128, dk=128, dv=128))
+    before = dict(kda.path_counts)
+    chunk_kda(q, k, v, g, beta, chunk=64)
+    assert kda.path_counts == {**before, "dense": before["dense"] + 1}
 
 
 # ---------------------------------------------------------------------- #
