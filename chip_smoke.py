@@ -348,7 +348,7 @@ def model_lm(cfg: dict, batch: int, seq: int, prompt: int, new: int,
           loss="/".join(f"{v:.4f}" for v in losses), generated=new)
 
 
-def _dense_reference(comm, qkv, w, rep: int):
+def _dense_reference(comm, qkv, w, rep: int, window=None):
     """Forward and backward of THE dense softmax path on f32 copies, the
     batch split over the chips and one entry at a time on each, so the
     (S, S) scores of the whole batch never exist at once.  ``rep``: K/V
@@ -360,7 +360,8 @@ def _dense_reference(comm, qkv, w, rep: int):
 
         def f(q, k, v):
             out = _dense_attention(q, jnp.repeat(k, rep, axis=-3),
-                                   jnp.repeat(v, rep, axis=-3), True, d**-0.5, S)
+                                   jnp.repeat(v, rep, axis=-3), True, d**-0.5, S,
+                                   window=window)
             return jnp.sum(out * w), out
 
         (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
@@ -376,8 +377,9 @@ def _dense_reference(comm, qkv, w, rep: int):
 
 
 def model_flash(shape, kv_heads: int, long_shape) -> None:
-    """flash_attention, _gqa and _block, forward and backward, causal bf16,
-    against the dense reference on the same device(s); then the long forward."""
+    """flash_attention, _gqa (also under a window of a quarter of the
+    sequence) and _block, forward and backward, causal bf16, against the dense
+    reference on the same device(s); then the long forward."""
     t0 = time.perf_counter()
     comm = ht.communication.get_comm()
     B, H, S, d = shape
@@ -395,6 +397,8 @@ def model_flash(shape, kv_heads: int, long_shape) -> None:
         "flash_attention": ((q, k, v), lambda a, b, c: flash_attention(a, b, c, causal=True)),
         "flash_attention_gqa": ((q, kg, vg),
                                 lambda a, b, c: flash_attention_gqa(a, b, c, causal=True)),
+        "flash_attention_gqa_window": ((q, kg, vg), lambda a, b, c: flash_attention_gqa(
+            a, b, c, causal=True, window=S // 4)),
         "flash_attention_block": ((q, k, v), lambda a, b, c: flash_attention_block(
             a, b, c, pos, pos, causal=True, scale=d**-0.5, s_valid=S,
             impl=_block_impl(comm, "flash"))[0]),
@@ -410,7 +414,8 @@ def model_flash(shape, kv_heads: int, long_shape) -> None:
         (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*ops)
         _kernels_engaged(before, name)
         _spans_all((out, grads), name)
-        ref = _dense_reference(comm, ops, w, ops[0].shape[1] // ops[1].shape[1])
+        ref = _dense_reference(comm, ops, w, ops[0].shape[1] // ops[1].shape[1],
+                               window=S // 4 if name.endswith("_window") else None)
         errs[name] = max(_rel_err(g, r) for g, r in zip((out, *grads), ref))
         assert _finite(out) and errs[name] < BF16_TOL, f"{name} vs dense: {errs[name]}"
 

@@ -14,7 +14,9 @@ attention as trained: keys and values expanded from one low-rank latent, a
 key part shared by all heads, values narrower than keys.  With ``num_kv_heads < num_heads``
 (grouped-query attention, beyond torch's module) the packed projection
 shrinks to (E + 2·num_kv_heads·head_dim, E) rows — torch state dicts then
-no longer round-trip, by construction.
+no longer round-trip, by construction; nor do they with ``head_dim=`` a head
+width of its own (``num_heads·head_dim`` query rows, whatever ``E`` is).
+``window=`` keeps causal self-attention to the nearest ``window`` keys.
 """
 
 from __future__ import annotations
@@ -64,7 +66,11 @@ class MultiheadAttention(Module):
     (torch names; only ``batch_first=True`` layouts are produced by the rest
     of this framework, so it is the default here), and ``comm`` — when set,
     ``apply`` runs the sequence-parallel ring path over that communicator's
-    mesh.
+    mesh.  ``head_dim`` gives the heads a width that is not ``embed_dim /
+    num_heads`` (the output projection then maps ``num_heads * head_dim``
+    back to ``embed_dim``); ``window`` lets a query of causal self-attention
+    see only the nearest ``window`` keys (itself among them), in the flash
+    kernels, the dense path and ``decode_step`` alike, not on the ring.
 
     ``apply(params, x, kv=None, causal=False, key_padding_mask=None,
     attn_mask=None)`` performs self-attention on ``x`` (B, S, E), or
@@ -93,13 +99,19 @@ class MultiheadAttention(Module):
         rope_pairing: str = "interleaved",
         qk_norm: bool = False,
         qk_norm_eps: float = 1e-5,
+        head_dim: int = None,
+        window: int = None,
     ):
-        if embed_dim % num_heads:
-            raise ValueError(f"embed_dim {embed_dim} not divisible by num_heads {num_heads}")
+        if head_dim is None:
+            if embed_dim % num_heads:
+                raise ValueError(f"embed_dim {embed_dim} not divisible by num_heads {num_heads}")
+            head_dim = embed_dim // num_heads
         if not batch_first:
             raise ValueError("only batch_first=True is supported (framework layout)")
-        if rope and (embed_dim // num_heads) % 2:
+        if rope and head_dim % 2:
             raise ValueError("rope requires an even head dim")
+        if window is not None and (window < 1 or comm is not None):
+            raise ValueError("window must be at least 1 and is not available on the ring (comm=)")
         if num_kv_heads is None:
             num_kv_heads = num_heads
         if num_kv_heads < 1 or num_heads % num_kv_heads:
@@ -108,9 +120,11 @@ class MultiheadAttention(Module):
             )
         self.embed_dim = embed_dim
         self.num_heads = num_heads
-        self.head_dim = embed_dim // num_heads
+        self.head_dim = head_dim
+        self.q_dim = num_heads * head_dim  # == embed_dim unless head_dim= was given
         self.num_kv_heads = num_kv_heads  # < num_heads = grouped-query attention
         self.kv_dim = num_kv_heads * self.head_dim
+        self.window = window
         self.bias = bias
         self.comm = comm
         self.rope = rope  # rotary positions on SELF-attention q/k (not cross)
@@ -123,17 +137,17 @@ class MultiheadAttention(Module):
 
     def init(self, key):
         k1, k2 = jax.random.split(key)
-        E = self.embed_dim
+        E, Q = self.embed_dim, self.q_dim
         # torch init: xavier_uniform over the packed projection (rows
-        # E + 2*kv_dim — equals (3E, E) when num_kv_heads == num_heads,
-        # keeping torch state-dict round-trip in the non-GQA case)
-        rows = E + 2 * self.kv_dim
+        # Q + 2*kv_dim — equals (3E, E) when num_kv_heads == num_heads and
+        # the heads divide E, keeping torch state-dict round-trip there)
+        rows = Q + 2 * self.kv_dim
         bound = (6.0 / (rows + E)) ** 0.5
         p = {
             "in_proj_weight": jax.random.uniform(k1, (rows, E), minval=-bound, maxval=bound),
             "out_proj": {
                 "weight": jax.random.uniform(
-                    k2, (E, E), minval=-(1.0 / E**0.5), maxval=1.0 / E**0.5
+                    k2, (E, Q), minval=-(1.0 / Q**0.5), maxval=1.0 / Q**0.5
                 )
             },
         }
@@ -195,7 +209,7 @@ class MultiheadAttention(Module):
             bias = bias + jnp.where(kpm[:, None, None, :], neg, 0.0)
         return _dense_attention(
             qh, kh, vh, causal, 1.0 / (self.head_dim**0.5), Sk, bias=bias,
-            return_probs=return_probs,
+            return_probs=return_probs, window=self.window,
         )
 
     # ------------------------------------------------------------------ #
@@ -230,7 +244,7 @@ class MultiheadAttention(Module):
         -rolled decode loop that overruns silently overwrites the last
         slot).
         """
-        E = self.embed_dim
+        E = self.q_dim
         from .modules import _concrete_int
 
         i = _concrete_int(cache["index"])
@@ -253,9 +267,10 @@ class MultiheadAttention(Module):
         kc = jax.lax.dynamic_update_slice_in_dim(cache["k"], kh.astype(cache["k"].dtype), i, axis=2)
         vc = jax.lax.dynamic_update_slice_in_dim(cache["v"], vh.astype(cache["v"].dtype), i, axis=2)
         L = kc.shape[2]
-        y = self._attend_merge_project(
-            params, qh, kc, vc, dead_mask=jnp.arange(L) <= i  # future slots dead
-        )
+        seen = jnp.arange(L) <= i  # future slots dead
+        if self.window is not None:
+            seen = seen & (i - jnp.arange(L) < self.window)
+        y = self._attend_merge_project(params, qh, kc, vc, dead_mask=seen)
         return y, {"k": kc, "v": vc, "index": i + 1}
 
     def _project_kv(self, params, kv):
@@ -263,7 +278,7 @@ class MultiheadAttention(Module):
         :meth:`apply`, :meth:`precompute_kv` and :meth:`decode_step` share
         this layout.  Returns ``num_kv_heads`` heads (== num_heads unless
         grouped-query attention)."""
-        E, kvE = self.embed_dim, self.kv_dim
+        E, kvE = self.q_dim, self.kv_dim
         w = params["in_proj_weight"]
         b = params.get("in_proj_bias")
         k = kv @ w[E : E + kvE].T + (b[E : E + kvE] if b is not None else 0.0)
@@ -289,7 +304,7 @@ class MultiheadAttention(Module):
         out = jnp.einsum("bkgql,bkld->bkgqd", pg, vh).reshape(
             B, H, qh.shape[2], qh.shape[3]
         )
-        merged = out.transpose(0, 2, 1, 3).reshape(B, 1, self.embed_dim)
+        merged = out.transpose(0, 2, 1, 3).reshape(B, 1, self.q_dim)
         y = merged @ params["out_proj"]["weight"].T
         if self.bias:
             y = y + params["out_proj"]["bias"]
@@ -306,7 +321,7 @@ class MultiheadAttention(Module):
         (:meth:`precompute_kv`): x (B, 1, E) → (B, 1, E).  Numerically the
         corresponding row of a full cross :meth:`apply` against the same
         memory."""
-        E = self.embed_dim
+        E = self.q_dim
         w = params["in_proj_weight"]
         b = params.get("in_proj_bias")
         q = x @ w[:E].T + (b[:E] if b is not None else 0.0)
@@ -320,6 +335,8 @@ class MultiheadAttention(Module):
 
         probs = None
         gqa = self.num_kv_heads != self.num_heads
+        if self.window is not None and kv is not None:
+            raise ValueError("window is defined for self-attention only")
         if ring:
             # the ring rotates full-head K/V blocks — broadcast the groups
             # (training-time copy; the GQA memory win is the DECODE cache)
@@ -340,13 +357,13 @@ class MultiheadAttention(Module):
             # H/H_kv-fold repeat never reaches HBM
             from ..ops.flash_attention import flash_attention_gqa
 
-            out = flash_attention_gqa(qh, kh, vh, causal=causal)
+            out = flash_attention_gqa(qh, kh, vh, causal=causal, window=self.window)
         elif not gqa and qh.shape == kh.shape == vh.shape:
             # local self-attention: flash-fused Pallas kernel on TPU (the
             # (S, S) score matrix never reaches HBM), dense-jnp elsewhere
             from ..ops.flash_attention import flash_attention
 
-            out = flash_attention(qh, kh, vh, causal=causal)
+            out = flash_attention(qh, kh, vh, causal=causal, window=self.window)
         else:
             out = _global_attention(qh, *self._repeat_kv(kh, vh), causal,
                                     1.0 / (self.head_dim**0.5))
@@ -356,7 +373,7 @@ class MultiheadAttention(Module):
               key_padding_mask=None, attn_mask=None,
               need_weights: bool = False, average_attn_weights: bool = True,
               train: bool = False, key=None):
-        E = self.embed_dim
+        E = self.q_dim  # the query rows of the packed projection
         if need_weights and self.comm is not None and self.comm.size > 1 and kv is None:
             raise ValueError(
                 "need_weights materializes the (S, S) attention matrix — "
@@ -408,8 +425,9 @@ class MultiheadAttention(Module):
         elif self.qk_norm:
             raise ValueError("qk_norm is defined for self-attention only")
         # the scores-softmax-values part under a scope of its own, so that a
-        # trace of one fused training step can tell it from the projections
-        with jax.named_scope("ht.attention"):
+        # trace of one fused training step can tell it from the projections,
+        # and a windowed layer's from a global one's
+        with jax.named_scope("ht.attention" if self.window is None else "ht.attention.window"):
             out, probs = self._attend(qh, kh, vh, kv, causal, ring, masked, need_weights,
                                       key_padding_mask, attn_mask)
         B, H, S, d = out.shape

@@ -1144,14 +1144,18 @@ class _ShortConvOperator(nn.Module):
 
 class _PatternBlock(nn.Module):
     """``h = x + Op(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; an expert
-    FFN also returns what its routing counted (``MoE.apply_with_stats``)."""
+    FFN also returns what its routing counted (``MoE.apply_with_stats``), and
+    with ``route_on_input`` its router scores ``x`` itself, not ``RMSNorm(h)``."""
 
-    def __init__(self, embed_dim: int, operator: nn.Module, ffn: nn.Module, eps: float):
+    def __init__(self, embed_dim: int, operator: nn.Module, ffn: nn.Module, eps: float,
+                 route_on_input: bool = False):
         self.operator_norm = nn.RMSNorm(embed_dim, eps=eps)
         self.operator = operator
         self.ffn_norm = nn.RMSNorm(embed_dim, eps=eps)
         self.ffn = ffn
         self.routed = hasattr(ffn, "apply_with_stats")
+        # the router scores the block's input as it enters, ahead of the operator
+        self.route_on_input = route_on_input and self.routed
         # the operator's projections, beside the scope the operator gives its core
         self.scope = ("ht.shortconv.proj" if isinstance(operator, _ShortConvOperator)
                       else "ht.kda.proj" if isinstance(operator, KimiDeltaAttention) else "ht.attention.proj")
@@ -1173,7 +1177,8 @@ class _PatternBlock(nn.Module):
             h = x + self.operator.apply(params["operator"], z, causal=True)
         z = self.ffn_norm.apply(params["ffn_norm"], h)
         if self.routed:
-            out, stats = self.ffn.apply_with_stats(params["ffn"], z)
+            routed_on = {"router_input": x} if self.route_on_input else {}
+            out, stats = self.ffn.apply_with_stats(params["ffn"], z, **routed_on)
             return h + out, stats
         with jax.named_scope("ht.mlp"):
             return h + self.ffn.apply(params["ffn"], z), None
@@ -1185,7 +1190,12 @@ class PatternLM(nn.Module):
     ``layer_types[l]`` names layer ``l``'s sequence operator: ``"conv"`` (a
     gated short convolution of ``conv_taps`` positions), ``"full_attention"``
     (causal grouped-query attention with RMS-normalised query and key heads
-    and rotate-half rotary positions of base ``rope_base``), ``"kda"`` (Kimi
+    and rotate-half rotary positions of base ``rope_base``), ``"global_attention"``
+    and ``"sliding_attention"`` (the same attention over every earlier position
+    and over the nearest ``window`` alone; ``rope_kinds`` names the attention
+    kinds whose queries and keys are rotated, the others see no positions at
+    all; ``qk_norm=False`` leaves every attention kind's heads unnormalised,
+    ``head_dim`` gives them a width that is not ``embed_dim / num_heads``), ``"kda"`` (Kimi
     Delta Attention, ``kda_heads`` heads of ``kda_head_dim`` with a
     convolution of ``conv_taps`` positions, low-rank gates of ``kda_gate_rank``
     and chunks of ``kda_chunk`` tokens: :class:`~heat_tpu.nn.KimiDeltaAttention`)
@@ -1196,7 +1206,11 @@ class PatternLM(nn.Module):
     layer has ``num_experts`` SwiGLU experts of width ``expert_dim``,
     ``experts_per_token`` of them a token, chosen by sigmoid scores plus a
     selection bias (a buffer) and weighted by the renormalised scores, routed
-    without drops (``MoE(dispatch="sorted")``).  ``experts_held`` (a
+    without drops (``MoE(dispatch="sorted")``).  ``router_scoring="softmax"``
+    chooses by the logits alone and weights by a softmax over the chosen (no
+    selection bias), ``expert_activation="relu"`` makes the experts ReGLU, and
+    ``route_before_operator`` has each layer's router score the layer's input
+    as it enters, ahead of the norm and the sequence operator.  ``experts_held`` (a
     ``range``) says which experts' weights live on this rank: the router
     still scores all of them, and what the absent ones would add is left
     out.  ``shared_expert_dim`` adds a SwiGLU of that width that every token
@@ -1232,14 +1246,28 @@ class PatternLM(nn.Module):
                  tie_embedding: bool = True, shared_expert_dim: int = None,
                  expert_rows_bound: int = None, kda_heads: int = None, kda_head_dim: int = None,
                  kda_gate_rank: int = None, kda_chunk: int = 64, kv_rank: int = None,
-                 qk_nope_dim: int = None, qk_shared_dim: int = None, v_dim: int = None):
+                 qk_nope_dim: int = None, qk_shared_dim: int = None, v_dim: int = None,
+                 head_dim: int = None, qk_norm: bool = True, window: int = None,
+                 rope_kinds: Sequence[str] = ("full_attention", "sliding_attention"),
+                 router_scoring: str = "sigmoid", expert_activation: str = "silu",
+                 route_before_operator: bool = False):
         from .attention import LatentAttention, MultiheadAttention
         from .moe import MoE
 
-        unknown = sorted(set(layer_types) - {"conv", "full_attention", "kda", "mla"})
+        attention_kinds = ("full_attention", "global_attention", "sliding_attention")
+        unknown = sorted(set(layer_types) - {"conv", "kda", "mla", *attention_kinds})
         if unknown:
             raise ValueError(
-                f"layer_types may hold 'conv', 'full_attention', 'kda' and 'mla', got {unknown}")
+                f"layer_types may hold 'conv', 'kda' and 'mla' or an attention kind {attention_kinds}, got {unknown}")
+        if "sliding_attention" in layer_types and window is None:
+            raise ValueError("a 'sliding_attention' layer needs window=")
+
+        def attention(kind):
+            return lambda: MultiheadAttention(
+                embed_dim, num_heads, bias=False, rope=kind in rope_kinds, rope_base=rope_base,
+                rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=qk_norm, qk_norm_eps=norm_eps,
+                head_dim=head_dim, window=window if kind == "sliding_attention" else None)
+
         n_dense = len(layer_types) if num_dense_layers is None or not num_experts else num_dense_layers
         self.vocab_size, self.embed_dim = vocab_size, embed_dim
         self.layer_types = tuple(layer_types)
@@ -1248,9 +1276,7 @@ class PatternLM(nn.Module):
         self.head = None if tie_embedding else nn.Linear(embed_dim, vocab_size, bias=False)
         operators = {
             "conv": lambda: _ShortConvOperator(embed_dim, conv_taps),
-            "full_attention": lambda: MultiheadAttention(
-                embed_dim, num_heads, bias=False, rope=True, rope_base=rope_base,
-                rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=True, qk_norm_eps=norm_eps),
+            **{kind: attention(kind) for kind in attention_kinds},
             "kda": lambda: KimiDeltaAttention(
                 embed_dim, kda_heads, kda_head_dim, conv_taps=conv_taps, gate_rank=kda_gate_rank,
                 chunk=kda_chunk, eps=norm_eps),
@@ -1262,10 +1288,12 @@ class PatternLM(nn.Module):
         for i, kind in enumerate(self.layer_types):
             ffn = nn.SwiGLU(embed_dim, ffn_dim) if i < n_dense else MoE(
                 embed_dim, num_experts, hidden_dim=expert_dim, top_k=experts_per_token,
-                gated=True, scoring="sigmoid", expert_bias=True, norm_topk=norm_topk,
-                routed_scaling=routed_scaling, dispatch="sorted", experts_held=experts_held,
-                shared_dim=shared_expert_dim, rows_bound=expert_rows_bound)
-            self.blocks.append(_PatternBlock(embed_dim, operators[kind](), ffn, norm_eps))
+                gated=True, scoring=router_scoring, expert_bias=router_scoring == "sigmoid",
+                norm_topk=norm_topk, routed_scaling=routed_scaling, dispatch="sorted",
+                experts_held=experts_held, shared_dim=shared_expert_dim, rows_bound=expert_rows_bound,
+                activation=expert_activation)
+            self.blocks.append(_PatternBlock(embed_dim, operators[kind](), ffn, norm_eps,
+                                             route_on_input=route_before_operator))
         self.norm = nn.RMSNorm(embed_dim, eps=norm_eps)
         self._remat_fns = [{} for _ in self.blocks]
 

@@ -42,9 +42,12 @@ expert independently, selects on ``score + expert_bias`` (``expert_bias=
 True``: a buffer in the parameters that no optimizer updates), and weights
 by the unbiased scores, renormalized (``norm_topk=True``) and scaled by
 ``routed_scaling``.  ``gated=True`` makes every expert a gated-linear unit,
-``w2 (silu(w1 x) * w3 x)``, without biases.  Routing is deterministic: no
-jitter noise, so eval == train and results are reproducible across device
-counts.
+``w2 (act(w1 x) * w3 x)``, without biases, ``act`` being ``activation``:
+``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU).  The router scores the experts'
+own input unless ``apply_with_stats`` is handed another tensor for it
+(``router_input``: a layer that routes on its input, ahead of its
+attention).  Routing is deterministic: no jitter noise, so eval == train and
+results are reproducible across device counts.
 """
 
 from __future__ import annotations
@@ -199,6 +202,7 @@ class MoE(Module):
         experts_held=None,
         shared_dim: int | None = None,
         rows_bound: int | None = None,
+        activation: str = "silu",
     ):
         if top_k < 1 or top_k > num_experts:
             raise ValueError(f"top_k {top_k} must be in [1, num_experts={num_experts}]")
@@ -206,6 +210,8 @@ class MoE(Module):
             raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
         if dispatch not in ("capacity", "sorted"):
             raise ValueError(f"dispatch must be 'capacity' or 'sorted', got {dispatch!r}")
+        if activation not in ("silu", "relu") or (activation != "silu" and not gated):
+            raise ValueError(f"activation must be 'silu' or 'relu' and gated experts' own, got {activation!r}")
         held = range(num_experts) if experts_held is None else experts_held
         if not isinstance(held, range) or held.step != 1 or not 0 <= held.start < held.stop <= num_experts:
             raise ValueError(f"experts_held {experts_held!r} is not a range of the {num_experts} experts")
@@ -236,6 +242,7 @@ class MoE(Module):
         self.comm = comm
         self.batch_axis = batch_axis  # dp axis of a 2-D mesh (see _ep_program)
         self.gated = gated
+        self.activation = activation  # of the gated experts' w1 branch
         self.scoring = scoring
         self.expert_bias = expert_bias
         self.norm_topk = norm_topk
@@ -339,12 +346,13 @@ class MoE(Module):
             val = val / (val.sum(axis=-1, keepdims=True) + 1e-6)
         return val * self.routed_scaling, idx
 
-    def _sorted(self, params, x2d):
+    def _sorted(self, params, x2d, r2d=None):
         """``(y, stats)``: every token-slot whose expert is held goes through
-        that expert; nothing else is computed and nothing is dropped."""
+        that expert; nothing else is computed and nothing is dropped.  The
+        router reads ``r2d`` where it is given, else the experts' input."""
         n, k = x2d.shape[0], self.top_k
         with jax.named_scope("ht.moe.route"):
-            val, idx = self._route(params, x2d)
+            val, idx = self._route(params, x2d if r2d is None else r2d)
         if self.rows_bound is not None:
             return self._sorted_bounded(params, x2d, val, idx)
         with jax.named_scope("ht.moe.dispatch"):
@@ -381,7 +389,8 @@ class MoE(Module):
         dt, n_rows = xs.dtype, xs.shape[0]
         h = jax.lax.ragged_dot(xs, params["w1"].astype(dt), rows)
         if self.gated:
-            h = jax.nn.silu(h) * jax.lax.ragged_dot(xs, params["w3"].astype(dt), rows)
+            act = jax.nn.silu if self.activation == "silu" else jax.nn.relu
+            h = act(h) * jax.lax.ragged_dot(xs, params["w3"].astype(dt), rows)
         else:
             h = jax.nn.gelu(h + jnp.repeat(params["b1"].astype(dt), rows, axis=0,
                                            total_repeat_length=n_rows))
@@ -418,14 +427,19 @@ class MoE(Module):
         with jax.named_scope("ht.moe.shared"):
             return y + self.shared.apply(params["shared"], x2d)
 
-    def apply_with_stats(self, params, x):
+    def apply_with_stats(self, params, x, router_input=None):
         """``(y, {"rows": rows routed to each expert held, "dropped": token-
         slots of held experts that no expert computed})``; the capacity path
-        counts what its buffers took and what overflowed."""
+        counts what its buffers took and what overflowed.  ``router_input``
+        (of ``x``'s shape, the sorted path's) is what the router scores in
+        ``x``'s place; the experts still compute on ``x``."""
         x2d = x.reshape(-1, self.embed_dim)
         if self.dispatch == "sorted":
-            y, stats = self._sorted(params, x2d)
+            r2d = None if router_input is None else router_input.reshape(x2d.shape)
+            y, stats = self._sorted(params, x2d, r2d)
             return y.reshape(x.shape), stats
+        if router_input is not None:
+            raise ValueError("router_input needs dispatch='sorted'")
         if self.comm is not None:
             raise ValueError("apply_with_stats counts on one chip: comm must be None")
         y, stats = self._dense(params, x2d)

@@ -68,13 +68,16 @@ path_counts = {"pallas": 0, "dense": 0}
 
 
 def _dense_attention(q, k, v, causal: bool, scale: float, s_valid: int,
-                     bias=None, return_probs: bool = False):
+                     bias=None, return_probs: bool = False,
+                     window: Optional[int] = None):
     """THE dense softmax path — every non-flash attention route in the
     framework composes into this one function so masked-row semantics can
     never diverge.  ``s_valid`` masks trailing pad *keys* (positions >=
     s_valid never attend); ``bias`` is an optional additive score bias
     (broadcastable to (..., Sq, Sk)) carrying user masks — torch-style
-    bool masks should be pre-converted to 0/-inf.
+    bool masks should be pre-converted to 0/-inf.  ``window`` keeps of the
+    keys a query may see only the nearest ``window`` (query ``i`` sees key
+    ``j`` where ``i - j < window``; causal only).
 
     Fully-masked rows emit 0, and do so DIFFERENTIABLY: the all--inf row is
     sanitized to zeros *before* the softmax (an after-the-fact ``where``
@@ -88,6 +91,8 @@ def _dense_attention(q, k, v, causal: bool, scale: float, s_valid: int,
         mask = jnp.zeros((Sq, Sk), bool) | (jnp.arange(Sk)[None, :] < s_valid)
     if causal:
         cm = jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :]
+        if window is not None:
+            cm = cm & (jnp.arange(Sq)[:, None] - jnp.arange(Sk)[None, :] < window)
         mask = cm if mask is None else (mask & cm)
     if mask is not None:
         s = jnp.where(mask, s, -jnp.inf)
@@ -106,10 +111,11 @@ def _online_update(s, v_ref, m_scr, l_scr, acc_scr, *, guarded: bool):
     (bf16 rides the MXU's native input type); accumulation is f32.
 
     ``guarded`` (static) keeps a row that has met no live key yet (m = -inf)
-    free of NaN: the positions-carrying kernels can meet one.  In the
-    static-offset kernels every row sees key 0 in its first block, so m is
-    finite from then on, ``exp(-inf - m)`` is an exact 0 and the guards
-    would change no bit."""
+    free of NaN: the positions-carrying kernels can meet one, and so can a
+    static-offset sweep under a window (``_block_starves``).  Without a
+    window every row of a static-offset sweep sees key 0 in its first block,
+    so m is finite from then on, ``exp(-inf - m)`` is an exact 0 and the
+    guards would change no bit."""
     m_prev = m_scr[:, 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     if guarded:
@@ -148,50 +154,103 @@ def _finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
 
 
 def _block_kind(q_lo, k_lo, blk_q: int, blk_k: int, s_valid: int,
-                causal: bool):
+                causal: bool, window: Optional[int] = None):
     """``(live, interior)`` of the score block whose first query row is
     ``q_lo`` and whose first key is ``k_lo`` — decided by position alone, so
     the kernels (traced grid offsets) and the tests (ints) call this one
-    function.  *Dead* (not live): every key is pad (``>= s_valid``) or,
-    under ``causal``, in the future of every query row — both GEMMs are
-    skipped (the ~2x flop saving that makes causal flash worth it).
+    function.  *Dead* (not live): every key is pad (``>= s_valid``), under
+    ``causal`` in the future of every query row or, under a ``window``, at
+    least ``window`` positions in the past of every query row — both GEMMs
+    are skipped (the ~2x flop saving that makes causal flash worth it).
     *Interior*: no mask of the block can be false — every key is valid and,
-    under ``causal``, at or before every query row — so its body runs with
-    no iota, compare or select.  *Edge* (live, not interior): the diagonal
-    or the padding crosses it."""
+    under ``causal``, at or before every query row and, under a ``window``,
+    within it of every query row — so its body runs with no iota, compare
+    or select.  *Edge* (live, not interior): the diagonal, the window's
+    lower edge or the padding crosses it."""
     live = k_lo < s_valid
     interior = k_lo + blk_k <= s_valid
     if causal:
         live = live & (k_lo <= q_lo + blk_q - 1)
         interior = interior & (k_lo + blk_k - 1 <= q_lo)
+    if window is not None:
+        live = live & (q_lo - (k_lo + blk_k - 1) < window)
+        interior = interior & (q_lo + blk_q - 1 - k_lo < window)
     return live, interior
 
 
+def _block_starves(q_lo, k_lo, blk_q: int, blk_k: int, s_valid: int,
+                   window: int):
+    """Whether a row of the block can leave it without having met a key,
+    under a ``window``: the block's last row sees nothing at or before the
+    block's last key (or the last valid one).  A row's keys are contiguous
+    and a sweep meets them in order, so such a row met none in the blocks
+    before either, its running maximum is still ``-inf``, and the online
+    update needs its guard here and in no other block."""
+    return ((q_lo + blk_q - (k_lo + blk_k) >= window)
+            | (q_lo + blk_q - s_valid >= window))
+
+
+def _first_live_k(iq, blk_q: int, blk_k: int, window: int):
+    """Index of the first K/V block that a ``window`` lets Q block ``iq``
+    see: the one holding key ``q_lo - window + 1``."""
+    return jnp.maximum(iq * blk_q - window + 1, 0) // blk_k
+
+
+def _last_live_q(ik, blk_q: int, blk_k: int, nq: int, window: int):
+    """Index of the last Q block that sees K/V block ``ik`` under a
+    ``window``: the one holding row ``k_lo + blk_k - 1 + window - 1``."""
+    return jnp.minimum((ik * blk_k + blk_k + window - 2) // blk_q, nq - 1)
+
+
+def _window_steps(n_fixed: int, blk_fixed: int, blk_swept: int,
+                  window: int) -> int:
+    """Length of a windowed sweep: the most blocks of ``blk_swept`` positions
+    that the ``blk_fixed + window - 1`` positions which one of the
+    ``n_fixed`` fixed blocks can reach span (``window / blk + 1`` for square
+    blocks that divide the window)."""
+    return max((i * blk_fixed + blk_fixed - 1) // blk_swept
+               - max(i * blk_fixed - window + 1, 0) // blk_swept + 1
+               for i in range(n_fixed))
+
+
 def _block_census(Sp: int, s_valid: int, blk_q: int, blk_k: int,
-                  causal: bool) -> dict:
-    """How many of one head's ``Sp/blk_q x Sp/blk_k`` grid steps are
-    interior, edge and dead.  Shapes alone decide it, so this stands in for
-    a run-time counter of the mechanism."""
+                  causal: bool, window: Optional[int] = None) -> dict:
+    """How many of one head's forward grid steps are interior, edge and
+    dead: ``Sp/blk_q x Sp/blk_k`` of them, under a ``window`` the
+    ``_window_steps`` K/V blocks from each Q block's first live one.  Shapes
+    alone decide it, so this stands in for a run-time counter of the
+    mechanism."""
     census = {"interior": 0, "edge": 0, "dead": 0}
+    nk = Sp // blk_k
+    steps = nk if window is None else _window_steps(Sp // blk_q, blk_q, blk_k,
+                                                    window)
     for q_lo in range(0, Sp, blk_q):
-        for k_lo in range(0, Sp, blk_k):
-            live, interior = _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid,
-                                         causal)
+        first = 0 if window is None else max(q_lo - window + 1, 0) // blk_k
+        for ik in range(first, first + steps):
+            live, interior = _block_kind(q_lo, ik * blk_k, blk_q, blk_k,
+                                         s_valid, causal, window)
             census["interior" if interior else "edge" if live else "dead"] += 1
     return census
 
 
-def _on_live_blocks(step, kind, masked: bool):
+def _on_live_blocks(step, kind, masked: bool, starves=None):
     """Run ``step(with_mask)`` of a static-offset kernel on this grid step's
     block: without the mask arithmetic on an interior block, with it on an
     edge block, not at all on a dead one.  ``masked`` False (static: not
-    causal and no pad key) means no block has a mask."""
+    causal and no pad key) means no block has a mask.  ``starves`` (the
+    forward sweep under a window: ``_block_starves``) runs an edge block
+    that can starve a row as ``step(True, guarded=True)``."""
     live, interior = kind
     if not masked:
         step(False)
         return
     pl.when(interior)(lambda: step(False))
-    pl.when(live & jnp.logical_not(interior))(lambda: step(True))
+    edge = live & jnp.logical_not(interior)
+    if starves is None:
+        pl.when(edge)(lambda: step(True))
+        return
+    pl.when(edge & jnp.logical_not(starves))(lambda: step(True))
+    pl.when(edge & starves)(lambda: step(True, guarded=True))
 
 
 def _scale_folds(scale: float) -> bool:
@@ -217,7 +276,10 @@ def _score_operand(ref, scr, scale):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                   qs_scr, *, scale: float, causal: bool, s_valid: int,
-                  blk_q: int, blk_k: int, nk: int, masked: bool):
+                  blk_q: int, blk_k: int, nk: int, masked: bool,
+                  window: Optional[int] = None):
+    """Forward sweep of one Q block over its K/V blocks: all ``nk`` of them
+    or, under a ``window``, the ``nk`` from its first live one on."""
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     on_operand, on_scores = _split_scale(scale)
@@ -230,21 +292,31 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         _score_operand(q_ref, qs_scr, on_operand)
 
     q_lo = iq * blk_q
-    k_lo = ik * blk_k
+    if window is None:
+        k_lo = ik * blk_k
+    else:
+        k_lo = (_first_live_k(iq, blk_q, blk_k, window) + ik) * blk_k
 
-    def step(with_mask: bool):
+    def step(with_mask: bool, guarded: bool = False):
         # s: (blk_q, blk_k) f32 — in VMEM only
         s = _masked_scores(
             qs_scr[:], k_ref[0], scale=on_scores, causal=causal,
             masked=with_mask, s_valid=s_valid, q_lo=q_lo, k_lo=k_lo,
-            blk_q=blk_q, blk_k=blk_k,
+            blk_q=blk_q, blk_k=blk_k, window=window,
         )
-        # block 0 comes first and holds key 0, which no row masks: m is
-        # finite from the first update on, so no guard
-        _online_update(s, v_ref, m_scr, l_scr, acc_scr, guarded=False)
+        # without a window block 0 comes first and holds key 0, which no row
+        # masks: m is finite from the first update on, so no guard.  Under a
+        # window the sweep starts at the block its lower edge crosses, whose
+        # last rows see no key of it (row q_lo + r sees keys > q_lo + r -
+        # window): their m stays -inf and exp(-inf - -inf) is NaN, so the
+        # blocks that ``_block_starves`` names, and only those, are guarded
+        _online_update(s, v_ref, m_scr, l_scr, acc_scr, guarded=guarded)
 
     _on_live_blocks(
-        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal), masked)
+        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal, window),
+        masked,
+        None if window is None else _block_starves(q_lo, k_lo, blk_q, blk_k,
+                                                   s_valid, window))
 
     @pl.when(ik == nk - 1)
     def _():
@@ -252,7 +324,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _masked_scores(q, k, *, scale, causal, masked, s_valid,
-                   q_lo, k_lo, blk_q, blk_k):
+                   q_lo, k_lo, blk_q, blk_k, window=None):
     """THE score+mask computation — forward and backward share this one
     definition, so the masking convention can never silently diverge
     between the saved lse and the backward recompute.  ``scale`` None: an
@@ -268,14 +340,17 @@ def _masked_scores(q, k, *, scale, causal, masked, s_valid,
         if causal:
             q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
             mask = mask & (q_pos >= kv_pos)
+            if window is not None:
+                mask = mask & (q_pos - kv_pos < window)
         s = jnp.where(mask, s, -jnp.inf)
     return s
 
 
 def _recompute_p(q, k, lse_row, **kw):
     """Backward-side recompute: p_ij = exp(s_ij - lse_i).  The forward's
-    lse is finite on every row, so a masked score (-inf) recomputes to an
-    exact 0 with no guard."""
+    lse is finite on every row (-1e30 on a pad row that a window left
+    without a key), so a masked score (-inf) recomputes to an exact 0 with
+    no guard."""
     s = _masked_scores(q, k, **kw)
     return jnp.exp(s - lse_row[:, None])
 
@@ -429,7 +504,8 @@ def _flash_pos_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                          dq_ref, dq_scr, qs_scr,
-                         *, scale, causal, s_valid, blk_q, blk_k, nk, masked):
+                         *, scale, causal, s_valid, blk_q, blk_k, nk, masked,
+                         window=None):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     on_operand, on_scores = _split_scale(scale)
@@ -440,12 +516,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         _score_operand(q_ref, qs_scr, on_operand)
 
     q_lo, k_lo = iq * blk_q, ik * blk_k
+    if window is not None:  # the sweep starts at the Q block's first live block
+        k_lo = (_first_live_k(iq, blk_q, blk_k, window) + ik) * blk_k
 
     def step(with_mask: bool):
         p = _recompute_p(
             qs_scr[:], k_ref[0], lse_ref[0, 0], scale=on_scores,
             causal=causal, masked=with_mask, s_valid=s_valid, q_lo=q_lo,
-            k_lo=k_lo, blk_q=blk_q, blk_k=blk_k,
+            k_lo=k_lo, blk_q=blk_q, blk_k=blk_k, window=window,
         )
         dp = jax.lax.dot_general(  # dOᵢ · Vⱼᵀ  (blk_q, blk_k)
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
@@ -459,7 +537,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         )
 
     _on_live_blocks(
-        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal), masked)
+        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal, window),
+        masked)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -469,15 +548,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, ks_scr,
                           *, scale, causal, s_valid, blk_q, blk_k, nq, masked,
-                          nq_inner: int = 0):
+                          nq_inner: int = 0, window=None, n_q_blocks: int = 0):
     """dk/dv accumulation sweep.  ``nq`` is the TOTAL innermost sweep length
     (init at 0, write at nq-1); ``nq_inner`` (default: nq) is the number of
     Q blocks PER head — under GQA the sweep interleaves the g query heads of
     this K/V head's group, so the block offset is the sweep index modulo
-    nq_inner while the accumulator runs through all g·nq_inner steps."""
+    nq_inner while the accumulator runs through all g·nq_inner steps.  Under
+    a ``window`` a head's ``nq_inner`` steps start at the K/V block's first
+    live Q block and may run past the last of the ``n_q_blocks`` there are."""
     ik = pl.program_id(1)  # fixed K/V block
     raw = pl.program_id(2)  # sweeping Q blocks (x group heads under GQA)
     iq = raw % (nq_inner or nq)
+    if window is not None:
+        iq = iq + _first_live_q(ik, blk_q, blk_k, causal)
     on_operand, on_scores = _split_scale(scale)
 
     @pl.when(raw == 0)
@@ -492,7 +575,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         p = _recompute_p(
             q_ref[0], ks_scr[:], lse_ref[0, 0], scale=on_scores,
             causal=causal, masked=with_mask, s_valid=s_valid, q_lo=q_lo,
-            k_lo=k_lo, blk_q=blk_q, blk_k=blk_k,
+            k_lo=k_lo, blk_q=blk_q, blk_k=blk_k, window=window,
         )
         dv_scr[:] += jax.lax.dot_general(  # Pᵀ · dOᵢ  (blk_k, d)
             p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
@@ -508,8 +591,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             preferred_element_type=jnp.float32,
         )
 
-    _on_live_blocks(
-        step, _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal), masked)
+    kind = _block_kind(q_lo, k_lo, blk_q, blk_k, s_valid, causal, window)
+    if window is not None:
+        kind = tuple(flag & (iq < n_q_blocks) for flag in kind)
+    _on_live_blocks(step, kind, masked)
 
     @pl.when(raw == nq - 1)
     def _():
@@ -865,7 +950,8 @@ def flash_attention_block(q, k, v, q_pos, k_pos, *, causal: bool,
 
 
 def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Softmax attention over a local block, flash-fused on TPU.
 
     ``q, k``: identical shapes ``(..., S, d)`` (leading batch/head axes
@@ -874,7 +960,9 @@ def flash_attention(q, k, v, causal: bool = False,
     ``(..., S, d_v)`` in ``q``'s dtype.  The causal mask is top-left aligned
     (torch ``is_causal``).  Accumulation is f32 regardless of input dtype
     (bf16 inputs stay bf16 through the GEMM operands — the MXU's native
-    layout).
+    layout).  With ``window`` (causal only) query ``i`` sees the keys ``j``
+    with ``0 <= i - j < window``, and the kernels' sweeps visit only the
+    blocks that holds.
     """
     if k.shape != q.shape or v.shape[:-1] != q.shape[:-1]:
         raise ValueError(
@@ -886,11 +974,12 @@ def flash_attention(q, k, v, causal: bool = False,
     if scale is None:
         scale = 1.0 / (d**0.5)
     scale = float(scale)
+    window = _checked_window(window, causal, S)
 
     use_pallas, blk, platform = _pallas_gate(q, S, d, dv)
     if not use_pallas:
         path_counts["dense"] += 1
-        return _dense_attention(q, k, v, causal, scale, S)
+        return _dense_attention(q, k, v, causal, scale, S, window=window)
 
     lead = q.shape[:-2]
     B = 1
@@ -899,7 +988,8 @@ def flash_attention(q, k, v, causal: bool = False,
     out = _run_flash_padded(
         (q.reshape((B, S, d)), k.reshape((B, S, d)), v.reshape((B, S, dv))),
         S, blk,
-        lambda a, b, c: _flash(a, b, c, causal, scale, S, platform == "cpu"),
+        lambda a, b, c: _flash(a, b, c, causal, scale, S, platform == "cpu",
+                               *_window_args(window)),
     )
     return out.reshape(v.shape)
 
@@ -918,6 +1008,12 @@ def flash_attention(q, k, v, causal: bool = False,
 # The streamed side's block index is clamped to the sweep's nearest live
 # block (``_last_live_k``/``_first_live_q``): consecutive dead steps then
 # name the block already in VMEM and the pipeline copies nothing for them.
+#
+# Under a ``window`` a sweep has ``_window_steps`` steps, not one for every
+# block of the streamed side: it starts at the fixed block's first live
+# block (``_first_live_k``/``_first_live_q``) and the clamp holds it at the
+# last (``_last_live_k``/``_last_live_q``).  ``window=None`` builds the
+# sweeps over every block, as they were before there was a window.
 # --------------------------------------------------------------------- #
 
 
@@ -941,28 +1037,45 @@ def _first_live_q(ik, blk_q: int, blk_k: int, causal: bool):
     return (ik * blk_k) // blk_q if causal else 0
 
 
+def _streamed_k(blk_q: int, blk_k: int, s_valid: int, causal: bool,
+                window: Optional[int]):
+    """``(iq, step) -> K/V block`` of the sweeps that fix a Q block: the
+    step's own block, from the first live one on under a ``window``, held at
+    the last live one."""
+    last_k = functools.partial(_last_live_k, blk_q=blk_q, blk_k=blk_k,
+                               s_valid=s_valid, causal=causal)
+    if window is None:
+        return lambda iq, ik: jnp.minimum(ik, last_k(iq))
+    return lambda iq, ik: jnp.minimum(
+        _first_live_k(iq, blk_q, blk_k, window) + ik, last_k(iq))
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "scale", "s_valid", "hq", "hk", "interpret"),
+    static_argnames=("causal", "scale", "s_valid", "hq", "hk", "interpret",
+                     "window"),
 )
 def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
-                        hq: int, hk: int, interpret: bool):
+                        hq: int, hk: int, interpret: bool,
+                        window: Optional[int] = None):
     BHq, Sp, d = q.shape
     dv = v.shape[-1]
     blk_q, blk_k = _block_shape(Sp, max(d, dv), q.dtype.itemsize)
     nq, nk = Sp // blk_q, Sp // blk_k
+    if window is not None:  # the K/V blocks one Q block can reach
+        nk = _window_steps(nq, blk_q, blk_k, window)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, s_valid=s_valid,
         blk_q=blk_q, blk_k=blk_k, nk=nk,
-        masked=causal or (Sp != s_valid),
+        masked=causal or (Sp != s_valid), window=window,
     )
     kvrow = functools.partial(_gqa_kv_row, hq=hq, hk=hk)
-    last_k = functools.partial(_last_live_k, blk_q=blk_q, blk_k=blk_k,
-                               s_valid=s_valid, causal=causal)
+    kblk = _streamed_k(blk_q, blk_k, s_valid, causal, window)
+
     def kspec(width):
         return pl.BlockSpec(
             (1, blk_k, width),
-            lambda b, iq, ik: (kvrow(b), jnp.minimum(ik, last_k(iq)), 0))
+            lambda b, iq, ik: (kvrow(b), kblk(iq, ik), 0))
 
     return pl.pallas_call(
         kernel,
@@ -992,22 +1105,29 @@ def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "scale", "s_valid", "hq", "hk", "interpret"),
+    static_argnames=("causal", "scale", "s_valid", "hq", "hk", "interpret",
+                     "window"),
 )
 def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
-                        s_valid: int, hq: int, hk: int, interpret: bool):
+                        s_valid: int, hq: int, hk: int, interpret: bool,
+                        window: Optional[int] = None):
     BHq, Sp, d = q.shape
     BHk, dv = k.shape[0], v.shape[-1]
     g = hq // hk
     blk_q, blk_k = _block_shape(Sp, max(d, dv), q.dtype.itemsize)
     nq, nk = Sp // blk_q, Sp // blk_k
+    # the streamed side's steps of the two sweeps: every block, or what a
+    # window lets one fixed block reach
+    k_steps, q_steps = nk, nq
+    if window is not None:
+        k_steps = _window_steps(nq, blk_q, blk_k, window)
+        q_steps = _window_steps(nk, blk_k, blk_q, window)
     masked = causal or (Sp != s_valid)
     # D_i = Σ_d dOᵢ ⊙ Oᵢ — one cheap fused elementwise pass, fine in XLA;
     # (B, 1, Sp) like lse (see module docstring)
     dd = _row_dot(do, out)
     kvrow = functools.partial(_gqa_kv_row, hq=hq, hk=hk)
-    last_k = functools.partial(_last_live_k, blk_q=blk_q, blk_k=blk_k,
-                               s_valid=s_valid, causal=causal)
+    kblk = _streamed_k(blk_q, blk_k, s_valid, causal, window)
     first_q = functools.partial(_first_live_q, blk_q=blk_q, blk_k=blk_k,
                                 causal=causal)
 
@@ -1019,15 +1139,16 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     def kspec(width):
         return pl.BlockSpec(
             (1, blk_k, width),
-            lambda b, i, j: (kvrow(b), jnp.minimum(j, last_k(i)), 0))
+            lambda b, i, j: (kvrow(b), kblk(i, j), 0))
 
     rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, causal=causal, s_valid=s_valid,
-            blk_q=blk_q, blk_k=blk_k, nk=nk, masked=masked,
+            blk_q=blk_q, blk_k=blk_k, nk=k_steps, masked=masked,
+            window=window,
         ),
-        grid=(BHq, nq, nk),
+        grid=(BHq, nq, k_steps),
         in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), rowspec, rowspec],
         out_specs=qspec(d),
         out_shape=jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
@@ -1042,10 +1163,13 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     # accumulates its whole group — the innermost grid interleaves the g
     # query heads x nq blocks through ONE scratch
     def qrow(b, i):
-        return (b // hk) * hq + (b % hk) * g + i // nq
+        return (b // hk) * hq + (b % hk) * g + i // q_steps
 
     def qblk(j, i):
-        return jnp.maximum(i % nq, first_q(j))
+        if window is None:
+            return jnp.maximum(i % nq, first_q(j))
+        return jnp.minimum(first_q(j) + i % q_steps,
+                           _last_live_q(j, blk_q, blk_k, nq, window))
 
     def qspec2(width):
         return pl.BlockSpec((1, blk_q, width),
@@ -1059,10 +1183,10 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, causal=causal,
-            s_valid=s_valid, blk_q=blk_q, blk_k=blk_k, nq=g * nq,
-            nq_inner=nq, masked=masked,
+            s_valid=s_valid, blk_q=blk_q, blk_k=blk_k, nq=g * q_steps,
+            nq_inner=q_steps, masked=masked, window=window, n_q_blocks=nq,
         ),
-        grid=(BHk, nk, g * nq),
+        grid=(BHk, nk, g * q_steps),
         in_specs=[qspec2(d), kspec2(d), kspec2(dv), qspec2(dv), rowspec2, rowspec2],
         out_specs=[kspec2(d), kspec2(dv)],
         out_shape=[
@@ -1082,37 +1206,59 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
 # custom_vjp: jax.grad runs the Pallas backward kernels (dq sweep + dk/dv
 # sweep) instead of failing out of pallas_call's missing autodiff rule —
 # training keeps the flash memory profile
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_gqa(q, k, v, causal: bool, scale: float, s_valid: int,
-               hq: int, hk: int, interpret: bool):
+               hq: int, hk: int, interpret: bool,
+               window: Optional[int] = None):
     out, _ = _flash_gqa_fwd_impl(q, k, v, causal, scale, s_valid, hq, hk,
-                                 interpret)
+                                 interpret, window)
     return out
 
 
-def _flash_gqa_fwd_rule(q, k, v, causal, scale, s_valid, hq, hk, interpret):
+def _flash_gqa_fwd_rule(q, k, v, causal, scale, s_valid, hq, hk, interpret,
+                        window):
     out, lse = _flash_gqa_fwd_impl(q, k, v, causal, scale, s_valid, hq, hk,
-                                   interpret)
+                                   interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_gqa_bwd_rule(causal, scale, s_valid, hq, hk, interpret, res, do):
+def _flash_gqa_bwd_rule(causal, scale, s_valid, hq, hk, interpret, window,
+                        res, do):
     q, k, v, out, lse = res
     return _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal, scale, s_valid,
-                               hq, hk, interpret)
+                               hq, hk, interpret, window)
 
 
 _flash_gqa.defvjp(_flash_gqa_fwd_rule, _flash_gqa_bwd_rule)
 
 
 def _flash(q, k, v, causal: bool, scale: float, s_valid: int,
-           interpret: bool):
+           interpret: bool, window: Optional[int] = None):
     """Equal heads: every query row reads its own K/V row."""
-    return _flash_gqa(q, k, v, causal, scale, s_valid, 1, 1, interpret)
+    return _flash_gqa(q, k, v, causal, scale, s_valid, 1, 1, interpret,
+                      *_window_args(window))
+
+
+def _window_args(window):
+    """The window as the trailing argument of the kernels' entry, or none: a
+    call without a window is the call it was before there was one."""
+    return () if window is None else (window,)
+
+
+def _checked_window(window, causal: bool, S: int):
+    """``window`` as the kernels take it: ``None`` where it masks nothing (no
+    window, or one that holds the whole sequence)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(
+            f"window ({window}) must be at least 1 and needs causal=True")
+    return None if window >= S else int(window)
 
 
 def flash_attention_gqa(q, k, v, causal: bool = False,
-                        scale: Optional[float] = None):
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
     """Grouped-query attention, flash-fused on TPU without repeating K/V.
 
     ``q``: ``(..., H_q, S, d)``; ``k``: ``(..., H_kv, S, d)``; ``v``:
@@ -1121,8 +1267,8 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
     attends its group's shared K/V head straight from the kernel's index
     map — the ``H_q/H_kv``-fold K/V broadcast that ``jnp.repeat`` would
     write to HBM never materializes, forward or backward.  Returns
-    ``(..., H_q, S, d_v)`` in q's dtype; same causal/masked-row semantics as
-    :func:`flash_attention`.  Dispatch follows ``_pallas_gate`` exactly
+    ``(..., H_q, S, d_v)`` in q's dtype; same causal, ``window`` and
+    masked-row semantics as :func:`flash_attention`.  Dispatch follows ``_pallas_gate`` exactly
     like :func:`flash_attention` (TPU kernel; CPU interpreter at test
     scale; dense path over a repeated K/V everywhere else, incl. past the
     VMEM gate).
@@ -1144,7 +1290,9 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
         scale = 1.0 / (d**0.5)
     scale = float(scale)
     if hq == hk:
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)
+    window = _checked_window(window, causal, S)
 
     use_pallas, blk, platform = _pallas_gate(q, S, d, dv)
     if not use_pallas:
@@ -1152,7 +1300,7 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
         g = hq // hk
         return _dense_attention(
             q, jnp.repeat(k, g, axis=-3), jnp.repeat(v, g, axis=-3),
-            causal, scale, S,
+            causal, scale, S, window=window,
         )
 
     lead = q.shape[:-3]
@@ -1164,6 +1312,6 @@ def flash_attention_gqa(q, k, v, causal: bool = False,
          v.reshape((B * hk, S, dv))),
         S, blk,
         lambda a, b, c: _flash_gqa(a, b, c, causal, scale, S, hq, hk,
-                                   platform == "cpu"),
+                                   platform == "cpu", *_window_args(window)),
     )
     return out.reshape(q.shape[:-1] + (dv,))
