@@ -20,20 +20,29 @@ the square of the token count, so it is the path of small batches.
 ``tokens x top_k`` token-slots are sorted by expert, the experts run as
 grouped matrix products over the sorted rows (``jax.lax.ragged_dot``: each
 expert multiplies exactly the rows routed to it), and the results are
-brought back into token order and weighted.  Memory and work are linear in
+added into their tokens' rows, weighted.  Memory and work are linear in
 the tokens.  This path also takes ``experts_held``: the range of expert ids
 whose weights live here (one rank's share of an expert-parallel layer).
 The router still scores every expert and picks ``top_k`` of them; only the
 held experts have parameters, and what the others would add is left out of
 the result.  It runs on one chip without any exchange (``comm`` must be
 ``None``): the exchange of a multi-chip no-drop layer is not built yet.
-``rows_bound`` sizes this path's buffers: by default they hold a row for
-every token-slot (``tokens x top_k``), although a rank that holds 8 of 256
-experts computes a thirty-second of them; with ``rows_bound`` the gathered
-rows, the grouped products and the way back have that many rows, and a slot
-of a held expert past the bound is left out of the result and counted in
-``stats["dropped"]`` (a bound to size generously and to watch, not a
-capacity factor).  ``shared_dim`` adds a gated FFN of that width that every
+This path's buffers have a working size that the layer picks itself: twice
+the rows expected of the experts held (``2 x tokens x top_k x held /
+num_experts``, to the next 1,024), although a rank that holds 8 of 32 experts
+is sent a quarter of the token-slots.  The step counts the rows it routed
+before it gathers one, and its one body (gather the next rows in expert
+order, grouped products, weighted scatter-add into the tokens' rows) runs as
+many passes over those buffers as the count needs, in a loop on the device:
+one on a balanced step, more under skew, so no routing costs a row of a held
+expert.  ``rows_bound`` is the hard size, by default a row for every
+token-slot (``tokens x top_k``): a slot of a held expert past it is left out
+of the result and counted in ``stats["dropped"]`` (a bound to size generously
+and to watch, not a capacity factor).  ``stats["buffer_rows"]`` says how many
+rows of buffer the call moved (passes times the working size).  Where the
+working size is no less than the hard one (every expert held, a ``rows_bound``
+under it, toy shapes) there is one pass of the hard size and no loop.
+``shared_dim`` adds a gated FFN of that width that every
 token goes through, beside the routed experts (a shared expert).
 
 Routing is token-choice top-k.  ``scoring="softmax"`` renormalizes the
@@ -52,6 +61,7 @@ results are reproducible across device counts.
 
 from __future__ import annotations
 
+import functools
 import warnings
 
 import jax
@@ -104,23 +114,84 @@ def _topk_gates(gates, top_k: int):
     return val / (val.sum(axis=-1, keepdims=True) + 1e-9), idx
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is known: the gradient is
-    then the gather ``g[inverse]`` and not the scatter-add that autodiff
-    writes for a gather it cannot know to be one-to-one."""
-    return x[perm]
+# the working size of the sorted path's buffers is a multiple of this many rows
+_ROWS_MULTIPLE = 1024
 
 
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], inverse
+def _in_passes(part, passes, shape, operands, indices):
+    """Zeros of ``shape`` after ``out = part(c, out, *operands, *indices)`` for
+    ``c`` in ``range(passes)``, ``passes`` a device scalar: a ``while`` loop on
+    the device, which reverse mode cannot go through by itself.  ``part`` adds
+    into ``out`` and is linear in it, so the reverse is a loop of its own:
+    pass by pass it computes the part again and adds what that part gives
+    the operands (under a block's ``jax.checkpoint`` this is the one
+    recomputation the block makes anyway: the recomputed forward's result is
+    dead).  Nothing is kept between forward and backward but the inputs."""
+
+    def loop(passes, indices, operands):
+        return jax.lax.fori_loop(0, passes, lambda c, out: part(c, out, *operands, *indices),
+                                 jnp.zeros(shape, jnp.float32))
+
+    def forward(passes, indices, operands):
+        return loop(passes, indices, operands), (passes, indices, operands)
+
+    def backward(saved, g):
+        passes, indices, operands = saved
+
+        def add_pass(c, sums):
+            (given,) = jax.vjp(lambda o: part(c, jnp.zeros(shape, jnp.float32), *o, *indices), operands)[1](g)
+            with jax.named_scope("ht.moe.dispatch"):
+                return jax.tree.map(jnp.add, sums, given)
+
+        return None, None, jax.lax.fori_loop(0, passes, add_pass, jax.tree.map(jnp.zeros_like, operands))
+
+    run = jax.custom_vjp(loop)
+    run.defvjp(forward, backward)
+    return run(passes, indices, operands)
 
 
-def _permute_rows_bwd(inverse, g):
-    return g[inverse], None, None
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _sorted_rows(m: int, full: int, top_k: int, gated: bool, activation: str,
+                 c, y, weights, x2d, val, order, routed):
+    """Pass ``c`` of the sorted path's body over buffers of ``m`` rows: the
+    token-slots at places ``c m`` to ``(c + 1) m`` of the expert order
+    (``order``; ``val`` holds a weight a slot) are gathered, computed and
+    added into their tokens' rows of ``y``, weighted; places past the held
+    slots, or past ``full``, add nothing.  A jitted function of its sizes and
+    the experts' kind, not of a layer: a step traces the body several times a
+    layer (forward, the reverse mode's forward and backward), and layers of
+    one shape share one trace."""
+    with jax.named_scope("ht.moe.dispatch"):
+        first, last = c * m, jnp.minimum((c + 1) * m, full)
+        take = jax.lax.dynamic_slice_in_dim(order, first, m)
+        before = jnp.cumsum(routed) - routed  # where each expert's slots start in the order
+        # what this pass takes of each expert: its slots before ``last`` less those before ``first``
+        rows = jnp.clip(last - before, 0, routed) - jnp.clip(first - before, 0, routed)
+        in_group = jnp.arange(m) < jnp.sum(rows)
+        token = take // top_k
+        # a grouped product leaves the rows past its groups undefined
+        xs = jnp.where(in_group[:, None], x2d[token], 0)
+    with jax.named_scope("ht.moe.experts"):
+        ys = _experts_grouped(gated, activation, weights, xs, rows, in_group)
+    with jax.named_scope("ht.moe.combine"):
+        weight = jnp.where(in_group, val[take], 0).astype(jnp.float32)
+        return y.at[token].add(weight[:, None] * ys.astype(jnp.float32))
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+def _experts_grouped(gated: bool, activation: str, weights, xs, rows, in_group):
+    """The held experts over rows sorted by expert, ``rows[e]`` of them for
+    expert ``e``; rows past the groups come out 0."""
+    dt, n_rows = xs.dtype, xs.shape[0]
+    h = jax.lax.ragged_dot(xs, weights["w1"].astype(dt), rows)
+    if gated:
+        act = jax.nn.silu if activation == "silu" else jax.nn.relu
+        h = act(h) * jax.lax.ragged_dot(xs, weights["w3"].astype(dt), rows)
+    else:
+        h = jax.nn.gelu(h + jnp.repeat(weights["b1"].astype(dt), rows, axis=0, total_repeat_length=n_rows))
+    ys = jax.lax.ragged_dot(h, weights["w2"].astype(dt), rows)
+    if not gated:
+        ys = ys + jnp.repeat(weights["b2"].astype(dt), rows, axis=0, total_repeat_length=n_rows)
+    return jnp.where(in_group[:, None], ys, 0)
 
 
 def _routing(gates, top_k: int, capacity: int):
@@ -348,78 +419,54 @@ class MoE(Module):
 
     def _sorted(self, params, x2d, r2d=None):
         """``(y, stats)``: every token-slot whose expert is held goes through
-        that expert; nothing else is computed and nothing is dropped.  The
-        router reads ``r2d`` where it is given, else the experts' input."""
+        that expert, as far as the hard size reaches; nothing else is
+        computed.  The router reads ``r2d`` where it is given, else the
+        experts' input.  The buffers have the working size, and the body runs
+        as many passes over them as the rows this call routed need."""
         n, k = x2d.shape[0], self.top_k
         with jax.named_scope("ht.moe.route"):
             val, idx = self._route(params, x2d if r2d is None else r2d)
-        if self.rows_bound is not None:
-            return self._sorted_bounded(params, x2d, val, idx)
         with jax.named_scope("ht.moe.dispatch"):
-            here, group, rows = self._held_groups(idx)
-            order = jnp.argsort(group, stable=True)
-            back = jnp.argsort(order)
-            in_group = jnp.arange(n * k) < jnp.sum(rows)
-            xs = _permute_rows(jnp.repeat(x2d, k, axis=0), order, back)
-            # a grouped product leaves the rows past its groups undefined
-            xs = jnp.where(in_group[:, None], xs, 0)
-        with jax.named_scope("ht.moe.experts"):
-            ys = self._experts_grouped(params, xs, rows, in_group)
-        with jax.named_scope("ht.moe.combine"):
-            per_slot = _permute_rows(ys, back, order).reshape(n, k, -1)
-            y = jnp.einsum("nk,nkd->nd", val.astype(jnp.float32), per_slot.astype(jnp.float32))
-        # the buffer has a row for every token-slot, so this is 0 by construction
-        stats = {"rows": rows, "dropped": jnp.sum(here, dtype=jnp.int32) - jnp.sum(rows)}
+            group, routed = self._held_groups(idx)
+            order = jnp.argsort(group, stable=True)  # held slots first, by expert
+        working, full = self._buffer_rows(n * k)
+        most = -(-full // working)  # passes a call can need
+        # every pass slices ``working`` places of the order, the last one too
+        order = jnp.pad(order, (0, max(0, most * working - n * k)))
+        weights = {name: params[name] for name in ("w1", "w2", "w3", "b1", "b2") if name in params}
+        operands, indices = (weights, x2d, val.reshape(-1)), (order, routed)
+        part = functools.partial(_sorted_rows, working, full, k, self.gated, self.activation)
+        taken = jnp.minimum(jnp.sum(routed), full)
+        shape = (n, self.embed_dim)
+        if most == 1:
+            passes, y = 1, part(0, jnp.zeros(shape, jnp.float32), *operands, *indices)
+        else:
+            passes = (taken + working - 1) // working
+            y = _in_passes(part, passes, shape, operands, indices)
+        stats = {"rows": routed, "dropped": jnp.sum(routed) - taken,
+                 "buffer_rows": jnp.asarray(passes * working, jnp.int32)}
         return self._add_shared(params, x2d, y.astype(x2d.dtype)), stats
+
+    def _buffer_rows(self, slots: int):
+        """``(working, full)`` for a call of ``slots`` token-slots.  ``full``
+        is the hard size (``rows_bound``, or a row for every slot); ``working``,
+        the rows of the buffers, is twice the rows expected of the experts held,
+        rounded up to ``_ROWS_MULTIPLE``, and ``full`` where that is no less
+        (every expert held, a ``rows_bound`` already under it, toy shapes)."""
+        lo, hi = self.experts_held
+        full = min(self.rows_bound or slots, slots)
+        working = -(-2 * slots * (hi - lo) // (self.num_experts * _ROWS_MULTIPLE)) * _ROWS_MULTIPLE
+        return min(working, full), full
 
     def _held_groups(self, idx):
-        """Of every token-slot (slot ``i`` is token ``i // k``): whether its
-        expert is held, its group (the held expert's place, the experts not
-        held last), and the slots routed to each expert held."""
+        """Of every token-slot (slot ``i`` is token ``i // k``): its group
+        (the held expert's place, the experts not held last), and the slots
+        routed to each expert held."""
         lo, hi = self.experts_held
         flat = idx.reshape(-1)
-        here = (flat >= lo) & (flat < hi)
-        group = jnp.where(here, flat - lo, hi - lo)
+        group = jnp.where((flat >= lo) & (flat < hi), flat - lo, hi - lo)
         rows = jnp.sum(group[:, None] == jnp.arange(hi - lo)[None, :], axis=0, dtype=jnp.int32)
-        return here, group, rows
-
-    def _experts_grouped(self, params, xs, rows, in_group):
-        """The held experts over rows sorted by expert, ``rows[e]`` of them
-        for expert ``e``; rows past the groups come out 0."""
-        dt, n_rows = xs.dtype, xs.shape[0]
-        h = jax.lax.ragged_dot(xs, params["w1"].astype(dt), rows)
-        if self.gated:
-            act = jax.nn.silu if self.activation == "silu" else jax.nn.relu
-            h = act(h) * jax.lax.ragged_dot(xs, params["w3"].astype(dt), rows)
-        else:
-            h = jax.nn.gelu(h + jnp.repeat(params["b1"].astype(dt), rows, axis=0,
-                                           total_repeat_length=n_rows))
-        ys = jax.lax.ragged_dot(h, params["w2"].astype(dt), rows)
-        if not self.gated:
-            ys = ys + jnp.repeat(params["b2"].astype(dt), rows, axis=0, total_repeat_length=n_rows)
-        return jnp.where(in_group[:, None], ys, 0)
-
-    def _sorted_bounded(self, params, x2d, val, idx):
-        """The sorted path with buffers of ``rows_bound`` rows: the first
-        ``rows_bound`` token-slots in expert order are gathered, computed and
-        added back into their tokens' rows; the rest are counted as dropped."""
-        n, k, bound = x2d.shape[0], self.top_k, self.rows_bound
-        with jax.named_scope("ht.moe.dispatch"):
-            _, group, routed = self._held_groups(idx)
-            take = jnp.argsort(group, stable=True)[:bound]  # held slots first, by expert
-            before = jnp.cumsum(routed) - routed
-            rows = jnp.clip(bound - before, 0, routed)  # what the buffer takes of each expert
-            in_group = jnp.arange(take.shape[0]) < jnp.sum(rows)
-            token = take // k
-            xs = jnp.where(in_group[:, None], x2d[token], 0)
-        with jax.named_scope("ht.moe.experts"):
-            ys = self._experts_grouped(params, xs, rows, in_group)
-        with jax.named_scope("ht.moe.combine"):
-            weight = jnp.where(in_group, val.reshape(-1)[take], 0).astype(jnp.float32)
-            y = jnp.zeros((n, ys.shape[-1]), jnp.float32).at[token].add(
-                weight[:, None] * ys.astype(jnp.float32))
-        stats = {"rows": routed, "dropped": jnp.sum(routed) - jnp.sum(rows)}
-        return self._add_shared(params, x2d, y.astype(x2d.dtype)), stats
+        return group, rows
 
     def _add_shared(self, params, x2d, y):
         if self.shared is None:
