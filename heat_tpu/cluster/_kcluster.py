@@ -190,13 +190,15 @@ class _KCluster(ClusteringMixin, BaseEstimator):
 
                 def body(state):
                     centers, it, _ = state
-                    new = cls._em_step(jx, centers)
+                    with jax.named_scope("ht.kmeans.em"):
+                        new = cls._em_step(jx, centers)
                     return new, it + 1, jnp.max(jnp.abs(new - centers))
 
                 centers, n_iter, _ = jax.lax.while_loop(
                     cond, body, (centers0, jnp.asarray(0), jnp.asarray(jnp.inf, centers0.dtype))
                 )
-                labels, d2 = cls._assign(jx, centers)
+                with jax.named_scope("ht.kmeans.assign"):
+                    labels, d2 = cls._assign(jx, centers)
                 return centers, labels, jnp.sum(d2), n_iter
 
             cache[_KCluster._ASSIGN_BLOCK] = prog

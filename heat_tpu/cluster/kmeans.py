@@ -177,7 +177,8 @@ def _fit_sharded_program(comm, cls, assign_block):
 
         def body(state):
             centers, it, _ = state
-            new = em(centers)
+            with jax.named_scope("ht.kmeans.em"):
+                new = em(centers)
             return new, it + 1, jnp.max(jnp.abs(new - centers))
 
         centers, n_iter, _ = jax.lax.while_loop(
@@ -186,7 +187,8 @@ def _fit_sharded_program(comm, cls, assign_block):
         )
         # final local assignment on the converged centers — _assign
         # handles the small and blocked cases; pad rows are masked below
-        labels, d2min = cls._assign(phys_blk, centers)
+        with jax.named_scope("ht.kmeans.assign"):
+            labels, d2min = cls._assign(phys_blk, centers)
         w = (base + jnp.arange(c) < n).astype(d2min.dtype)
         inertia = jax.lax.psum(jnp.sum(d2min * w), axis)
         return centers, labels, inertia, n_iter

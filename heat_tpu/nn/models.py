@@ -1172,10 +1172,12 @@ class _PatternBlock(nn.Module):
     def apply(self, params, x, **kw):
         import jax
 
-        z = self.operator_norm.apply(params["operator_norm"], x)
+        with jax.named_scope("ht.lm.norm"):
+            z = self.operator_norm.apply(params["operator_norm"], x)
         with jax.named_scope(self.scope):
             h = x + self.operator.apply(params["operator"], z, causal=True)
-        z = self.ffn_norm.apply(params["ffn_norm"], h)
+        with jax.named_scope("ht.lm.norm"):
+            z = self.ffn_norm.apply(params["ffn_norm"], h)
         if self.routed:
             routed_on = {"router_input": x} if self.route_on_input else {}
             out, stats = self.ffn.apply_with_stats(params["ffn"], z, **routed_on)
@@ -1354,16 +1356,20 @@ class PatternLM(nn.Module):
     def apply(self, params, tokens, *, train: bool = False, key=None):
         import jax
 
-        embedding = self._cast(params["embed"])["weight"]  # also the output head, if tied
-        head = embedding if self.head is None else self._cast(params["head"])["weight"]
+        with jax.named_scope("ht.lm.cast"):
+            embedding = self._cast(params["embed"])["weight"]  # also the output head, if tied
+            head = embedding if self.head is None else self._cast(params["head"])["weight"]
         with jax.named_scope("ht.lm.embed"):
             h = embedding[tokens]
         stats = []
         for block, cache, p in zip(self.blocks, self._remat_fns, params["blocks"]):
             def run(p, h, block=block):
-                return block.apply(self._cast(p), h)
+                with jax.named_scope("ht.lm.cast"):
+                    p = self._cast(p)
+                return block.apply(p, h)
 
-            h, s = _remat_jit(cache, train, run)(p, h)
+            with jax.named_scope("ht.lm.block"):
+                h, s = _remat_jit(cache, train, run)(p, h)
             if s is not None:
                 stats.append(s)
         with jax.named_scope("ht.lm.head_loss"):
