@@ -4,8 +4,8 @@ One process drives the three main paths through their ordinary entry points
 over the default mesh of every attached chip: the ``ht.*`` array path at
 BASELINE widths, the two trainers (``DataParallel`` MLP, ``DASO`` ResNet-50),
 and the model layer with the Pallas kernels engaged (``TransformerLM``, the
-flash-attention family against the dense reference, ``chunk_kda``'s kernels
-against its XLA form); on more than one chip
+flash-attention family against the dense reference, ``chunk_kda``'s and
+``conv_silu_heads``' kernels against their XLA forms); on more than one chip
 also the ring, expert-parallel, pipeline and two-tier DASO paths.  Every
 phase checks its result (shape, finiteness, agreement with a reference,
 placement on every chip) and the first fault raises: there is no ``try``
@@ -46,7 +46,7 @@ from heat_tpu.ops.flash_attention import (
     _dense_attention, flash_attention, flash_attention_block,
     flash_attention_gqa, path_counts,
 )
-from heat_tpu.ops import kda
+from heat_tpu.ops import kda, short_conv
 from heat_tpu.parallel.ring_attention import (
     _block_impl, path_counts as ring_counts, ring_attention,
 )
@@ -65,6 +65,7 @@ FULL = dict(
     lm_batch=8, lm_seq=1024, lm_prompt=64, lm_new=64,
     attn=(4, 8, 4096, 64), attn_kv_heads=2, attn_long=(2, 8, 32768, 64),
     kda=(32, 8192, 128),  # one sequence of the cell kimi_linear_48b_a3b_train_2x8k
+    kda_conv=(2, 8192, 32, 128),  # a layer of that cell: sequences a chip, tokens, heads, their width
     ring=(2, 8, 4096, 64),  # S is per chip
     moe=dict(embed=1024, hidden=4096, experts_per_chip=8, tokens_per_chip=512),
     pipe=dict(embed=512, heads=8, seq=1024, batch_per_chip=2),
@@ -479,6 +480,41 @@ def model_kda(shape, chunk: int = 64) -> None:
           dtype="bfloat16", kda_rel_err=f"{err:.2e}", kda_grad_rel_err=f"{grad_err:.2e}")
 
 
+def model_kda_conv(shape, taps: int = 4) -> None:
+    """conv_silu_heads (Kimi Delta Attention's convolution, SiLU and L2 norms)
+    by its Pallas kernels, forward and backward, bf16, against its dense
+    executor on the same device(s); the sequences are what the chips share."""
+    t0 = time.perf_counter()
+    comm = ht.communication.get_comm()
+    per_chip, S, H, d = shape
+    keys = jax.random.split(jax.random.key(12), 5)
+    qkv = comm.shard(jax.random.normal(keys[0], (per_chip * comm.size, S, 3 * H * d), jnp.bfloat16), 0)
+    conv = jax.random.uniform(keys[1], (3 * H * d, taps), minval=-0.5, maxval=0.5)
+    ws = [comm.shard(jax.random.normal(k, (per_chip * comm.size, H, S, d), jnp.bfloat16), 0) for k in keys[2:]]
+    before = dict(short_conv.path_counts)
+    out = short_conv.conv_silu_heads(qkv, conv, H)
+    assert short_conv.path_counts == {**before, "pallas": before["pallas"] + 1}, (
+        f"conv_silu_heads: path_counts went {before} -> {short_conv.path_counts}; the "
+        f"Pallas path must rise and the dense path must not")
+    tile = short_conv._pallas_gate(qkv, H, 3)
+
+    def both(tile):
+        def loss(qkv, conv, *ws):
+            heads = short_conv._conv_silu_heads(qkv, conv, H, (True, True, False), (d**-0.5, 1, 1), 1e-6, tile)
+            return sum(jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)) for o, w in zip(heads, ws)), heads
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(qkv, conv, *ws)
+
+    ((_, heads), grads), ((_, ref), ref_grads) = both(tile), both(None)
+    _spans_all((out, grads[0]), "conv_silu_heads")
+    err = max(max(_rel_err(a, b), _rel_err(c, b)) for a, b, c in zip(out, ref, heads))
+    grad_err = max(_rel_err(a, b) for a, b in zip(grads, ref_grads))
+    assert all(map(_finite, out)), "conv_silu_heads: not finite"
+    assert err < BF16_TOL and grad_err < BF16_TOL, (err, grad_err)
+    _done("model.kda_conv", t0, shape="x".join(map(str, qkv.shape)), heads=H, tile="x".join(map(str, tile)),
+          dtype="bfloat16", conv_rel_err=f"{err:.2e}", conv_grad_rel_err=f"{grad_err:.2e}")
+
+
 # ---------------------------------------------------------------------- #
 # more than one chip
 # ---------------------------------------------------------------------- #
@@ -570,6 +606,7 @@ def run(s: dict) -> None:
         lambda: model_lm(s["lm"], s["lm_batch"], s["lm_seq"], s["lm_prompt"], s["lm_new"]),
         lambda: model_flash(s["attn"], s["attn_kv_heads"], s["attn_long"]),
         lambda: model_kda(s["kda"]),
+        lambda: model_kda_conv(s["kda_conv"]),
     ]
     n = len(jax.devices())
     if n > 1:

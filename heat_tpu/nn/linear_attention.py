@@ -28,10 +28,12 @@ class KimiDeltaAttention(Module):
       weight vector shared by the heads.
 
     No bias but ``dt_bias``.  Scopes: ``ht.kda.proj`` (the projections of the
-    hidden state), ``ht.kda.conv`` (convolution, SiLU, L2 norms),
-    ``ht.kda.gate`` (decay, beta, output norm and gate), ``ht.kda`` (the
-    kernel).  Convolution, norms and gates are float32 whatever ``x``'s dtype;
-    matrices are brought to ``x``'s dtype where they are used.
+    hidden state), ``ht.kda.conv`` (convolution, SiLU, L2 norms: one operator,
+    :func:`heat_tpu.ops.short_conv.conv_silu_heads`, a fused pass a direction
+    where its kernels run), ``ht.kda.gate`` (decay, beta, output norm and
+    gate), ``ht.kda`` (the kernel).  Convolution, norms and gates are float32
+    whatever ``x``'s dtype; matrices are brought to ``x``'s dtype where they
+    are used.
     """
 
     def __init__(self, embed_dim: int, num_heads: int, head_dim: int, *, conv_taps: int = 4,
@@ -65,15 +67,10 @@ class KimiDeltaAttention(Module):
 
     def _qkv(self, qkv, taps):
         """``(q, k, v)`` as heads in ``qkv``'s dtype: convolution, SiLU, L2 norms."""
-        from ..ops.short_conv import _conv
+        from ..ops.short_conv import conv_silu_heads
 
-        b, s, _ = qkv.shape
-        mixed = jax.nn.silu(_conv(qkv.astype(jnp.float32), taps.astype(jnp.float32)))
-        q, k, v = (t.reshape(b, s, self.num_heads, self.head_dim).transpose(0, 2, 1, 3)
-                   for t in jnp.split(mixed, 3, axis=-1))
-        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * self.head_dim ** -0.5
-        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-        return tuple(t.astype(qkv.dtype) for t in (q, k, v))
+        return conv_silu_heads(qkv, taps, self.num_heads, normalise=(True, True, False),
+                               scale=(self.head_dim ** -0.5, 1, 1), eps=1e-6)
 
     def _decay(self, low, f_b, dt_bias, a_log):
         """The log-decay ``(B, H, S, d)`` in float32 from the low-rank projection's first half."""
@@ -94,12 +91,13 @@ class KimiDeltaAttention(Module):
 
         w = {n: params[n]["weight"].astype(x.dtype)
              for n in ("in_proj", "f_a", "f_b", "g_a", "g_b", "b_proj", "out_proj")}
-        # the element-wise parts are rematerialised: their float32 intermediates are
-        # several times the size of what goes in and comes out
+        # the decay and the output gate are rematerialised: their float32 intermediates
+        # are several times the size of what goes in and comes out; ``conv_silu_heads``
+        # keeps only what goes in by its own rule
         with jax.named_scope("ht.kda.proj"):
             qkv = x @ w["in_proj"].T
         with jax.named_scope("ht.kda.conv"):
-            q, k, v = jax.checkpoint(self._qkv)(qkv, params["conv"]["weight"])
+            q, k, v = self._qkv(qkv, params["conv"]["weight"])
         with jax.named_scope("ht.kda.gate"):
             g = jax.checkpoint(self._decay)(x @ w["f_a"].T, w["f_b"], params["dt_bias"], params["A_log"])
             beta = jax.nn.sigmoid((x @ w["b_proj"].T).astype(jnp.float32)).transpose(0, 2, 1)

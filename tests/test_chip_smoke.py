@@ -2,7 +2,7 @@
 8-device CPU mesh (kernels in interpret mode), its refusal to run without a
 TPU, and the compile-cache placement rule.
 
-The whole script at toy sizes (``chip_smoke.run``: sixteen phases) is a
+The whole script at toy sizes (``chip_smoke.run``: seventeen phases) is a
 minute of XLA:CPU compiles, more than the quick lane can spare (ROADMAP D9),
 so it carries the ``slow`` marker; the quick lane keeps the refusal, the
 cache rule and the agreement of the toy and full size tables.  Run the slow
@@ -31,7 +31,7 @@ TOY = dict(
     lm=dict(vocab_size=64, embed_dim=32, num_heads=4, depth=2, max_len=64),
     lm_batch=8, lm_seq=32, lm_prompt=4, lm_new=4,
     attn=(8, 4, 128, 16), attn_kv_heads=2, attn_long=(2, 8, 256, 16),
-    kda=(8, 128, 128),
+    kda=(8, 128, 128), kda_conv=(1, 64, 2, 128),
     ring=(2, 2, 16, 8),
     moe=dict(embed=16, hidden=32, experts_per_chip=2, tokens_per_chip=8),
     pipe=dict(embed=16, heads=2, seq=8, batch_per_chip=1),
@@ -54,7 +54,7 @@ def test_every_phase_toy(capsys):
     assert [l.split()[1] for l in lines] == [
         "array.matmul", "array.resplit", "array.qr", "array.kmeans", "array.ragged",
         "array.fft", "train.mlp_dataparallel", "train.daso", "model.transformer_lm",
-        "model.flash_attention", "model.kda", "multi.dryrun_tiers",
+        "model.flash_attention", "model.kda", "model.kda_conv", "multi.dryrun_tiers",
         "multi.ring_attention", "multi.moe_expert_parallel", "multi.pipeline",
         "multi.daso_two_tier",
     ]

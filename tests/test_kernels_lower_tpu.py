@@ -95,6 +95,29 @@ def test_kda_kernels_lower_at_the_training_cell_shape(monkeypatch, dtype):
     assert exported.mlir_module().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_convolution_kernels_lower_at_the_training_cell_shape(monkeypatch, dtype):
+    """``kimi_linear_48b_a3b_train_2x8k``: a layer's ``qkv`` (2, 8192, 3 x 32
+    x 128) through ``conv_silu_heads``' forward and backward kernels, a
+    section each, as its ``custom_vjp`` calls them."""
+    sc = importlib.import_module("heat_tpu.ops.short_conv")
+    monkeypatch.setattr(sc, "platform_of", lambda q: "tpu")  # the kernels, not their interpreter
+    monkeypatch.setattr(sc, "_kernel_mesh", lambda q: None)
+    qkv = jax.ShapeDtypeStruct((2, 8192, 3 * 32 * 128), dtype)
+    tile = sc._pallas_gate(qkv, 32, 3)
+    assert tile == (512, 4)
+
+    def f(qkv, taps):
+        def loss(*a):
+            heads = sc._conv_silu_heads(*a, 32, (True, True, False), (128**-0.5, 1, 1), 1e-6, tile)
+            return sum(jnp.sum(t.astype(jnp.float32)) for t in heads)
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(qkv, taps)
+
+    exported = jax.export.export(jax.jit(f), platforms=["tpu"])(qkv, jax.ShapeDtypeStruct((3 * 32 * 128, 4), jnp.float32))
+    assert exported.mlir_module().count("tpu_custom_call") >= 6
+
+
 def _gqa_case():
     import numpy as np
 
@@ -189,6 +212,35 @@ def test_kda_kernels_per_shard_match_the_xla_form(monkeypatch):
     with jax.default_matmul_precision("highest"):
         ((_, got), d_got), ((_, want), d_want) = both(2), both(0)
     for a, b in zip((got, *d_got), (want, *d_want)):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=0)
+
+
+def test_kda_convolution_kernels_per_shard_match_the_dense_executor(monkeypatch):
+    """``conv_silu_heads``' kernels (the interpreter here) a shard of the
+    sequences a device of the CPU mesh, as across chips: the heads and both
+    gradients against the dense executor."""
+    import numpy as np
+
+    from heat_tpu.core.devices import get_default_mesh
+
+    sc = importlib.import_module("heat_tpu.ops.short_conv")
+    mesh = get_default_mesh()
+    monkeypatch.setattr(sc, "_kernel_mesh", lambda q: mesh)
+    keys = jax.random.split(jax.random.key(0), 2)
+    qkv = jax.random.normal(keys[0], (mesh.size, 48, 3 * 2 * 128))
+    taps = jax.random.uniform(keys[1], (3 * 2 * 128, 4), minval=-0.5, maxval=0.5)
+    tile = sc._pallas_gate(qkv, 2, 3)
+    assert tile == (48, 2) and sc._pallas_gate(qkv[1:], 2, 3) is None  # a sequence fewer does not divide
+
+    def both(tile):
+        def loss(*a):
+            heads = sc._conv_silu_heads(*a, 2, (True, True, False), (128**-0.5, 1, 1), 1e-6, tile)
+            return sum(jnp.sum(jnp.sin(t)) for t in heads), heads
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(qkv, taps)
+
+    ((_, got), d_got), ((_, want), d_want) = both(tile), both(None)
+    for a, b in zip((*got, *d_got), (*want, *d_want)):
         np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=0)
 
 

@@ -271,6 +271,28 @@ def test_kimi_delta_attention_matches_the_reference(setup):
         assert float(jnp.abs(d_want[0]["A_log"]).max()) > 0 and float(jnp.abs(d_want[0]["dt_bias"]).max()) > 0
 
 
+def test_kimi_delta_attention_with_heads_of_a_lane_tile_matches_the_reference():
+    """Heads of 128: the convolution, SiLU and norms by ``conv_silu_heads``'
+    kernels and the chunks' parts by ``chunk_kda``'s (both under the
+    interpreter here), the reference the same token recurrence."""
+    conv = sys.modules["heat_tpu.ops.short_conv"]
+    cfg = {**CFG, "linear_attn_config": {"head_dim": 128, "num_heads": 2, "short_conv_kernel_size": 4}}
+    with jax.default_matmul_precision("highest"):
+        z = jax.random.normal(jax.random.key(2), (2, 40, 64))
+        p = ref.init_params(jax.random.key(0), cfg, init_std=0.2)["blocks"][1]["operator"]
+        layer = ht.nn.KimiDeltaAttention(64, 2, 128, conv_taps=4, gate_rank=cfg["kda_gate_rank"], chunk=16,
+                                         eps=cfg["rms_norm_eps"])
+        assert jax.tree.map(jnp.shape, layer.init(jax.random.key(0))) == jax.tree.map(jnp.shape, p)
+        before = dict(conv.path_counts), dict(kda.path_counts)
+        got, d_got = _both(layer.apply, p, z)
+        assert conv.path_counts == {**before[0], "pallas": before[0]["pallas"] + 1}
+        assert kda.path_counts == {**before[1], "pallas": before[1]["pallas"] + 1}
+        want, d_want = _both(lambda p, z: ref.kda(p, z, cfg), p, z)
+        close(got, want)
+        jax.tree.map(close, d_got, d_want)
+        assert float(jnp.abs(d_want[0]["conv"]["weight"]).max()) > 0
+
+
 def test_latent_attention_matches_the_reference(setup):
     model, params, _ = setup
     with jax.default_matmul_precision("highest"):
