@@ -66,6 +66,8 @@ FULL = dict(
     attn=(4, 8, 4096, 64), attn_kv_heads=2, attn_long=(2, 8, 32768, 64),
     kda=(32, 8192, 128),  # one sequence of the cell kimi_linear_48b_a3b_train_2x8k
     kda_conv=(2, 8192, 32, 128),  # a layer of that cell: sequences a chip, tokens, heads, their width
+    # a windowed layer of the cell trinity_mini_26b_a3b_train_1x32k, a sequence a chip
+    gated_window=dict(embed=2048, heads=32, kv_heads=4, head_dim=128, seq=32768, window=2048, prefix=512),
     ring=(2, 8, 4096, 64),  # S is per chip
     moe=dict(embed=1024, hidden=4096, experts_per_chip=8, tokens_per_chip=512),
     pipe=dict(embed=512, heads=8, seq=1024, batch_per_chip=2),
@@ -438,6 +440,33 @@ def model_flash(shape, kv_heads: int, long_shape) -> None:
           **{f"{n}_rel_err": f"{e:.2e}" for n, e in errs.items()})
 
 
+def model_gated_window(embed: int, heads: int, kv_heads: int, head_dim: int, seq: int, window: int,
+                       prefix: int) -> None:
+    """One gated windowed attention layer (normalised, rotated heads of their
+    own width), forward, bf16, a sequence a chip: the kernels must take it, and
+    its first ``prefix`` rows are the dense masked path's rows of that prefix."""
+    from heat_tpu.nn.attention import MultiheadAttention
+
+    t0 = time.perf_counter()
+    comm = ht.communication.get_comm()
+    op = MultiheadAttention(embed, heads, bias=False, rope=True, rope_pairing="half", num_kv_heads=kv_heads,
+                            qk_norm=True, head_dim=head_dim, window=window, gate=True)
+    params = jax.tree.map(lambda a: comm.shard(a.astype(jnp.bfloat16), None), op.init(jax.random.key(0)))
+    x = comm.shard(jax.random.normal(jax.random.key(1), (comm.size, seq, embed), jnp.bfloat16), 0)
+    before = _kernel_counts()
+    y = jax.jit(lambda p, x: op.apply(p, x, causal=True))(params, x)
+    _kernels_engaged(before, "gated windowed attention")
+    assert y.shape == x.shape and _finite(y)
+    _spans_all(y, "gated windowed attention")
+    # causal: a prefix's rows do not depend on what follows; a float mask takes the dense path
+    ref = jax.jit(lambda p, x: op.apply(p, x, causal=True, attn_mask=jnp.zeros((prefix, prefix))))(
+        params, x[:, :prefix])
+    err = _rel_err(y[:, :prefix], ref)
+    assert err < BF16_TOL, f"gated windowed attention vs dense: {err}"
+    _done("model.gated_window_attention", t0, seq=seq, window=window, heads=f"{heads}/{kv_heads}x{head_dim}",
+          embed=embed, dtype="bfloat16", prefix_rel_err=f"{err:.2e}")
+
+
 def model_kda(shape, chunk: int = 64) -> None:
     """chunk_kda with the Pallas kernels as its chunk-local part, forward and
     backward, bf16 with a float32 decay, against the XLA form of that part on
@@ -605,6 +634,7 @@ def run(s: dict) -> None:
                            s["daso_batch_per_chip"]),
         lambda: model_lm(s["lm"], s["lm_batch"], s["lm_seq"], s["lm_prompt"], s["lm_new"]),
         lambda: model_flash(s["attn"], s["attn_kv_heads"], s["attn_long"]),
+        lambda: model_gated_window(**s["gated_window"]),
         lambda: model_kda(s["kda"]),
         lambda: model_kda_conv(s["kda_conv"]),
     ]

@@ -16,7 +16,9 @@ key part shared by all heads, values narrower than keys.  With ``num_kv_heads < 
 shrinks to (E + 2·num_kv_heads·head_dim, E) rows — torch state dicts then
 no longer round-trip, by construction; nor do they with ``head_dim=`` a head
 width of its own (``num_heads·head_dim`` query rows, whatever ``E`` is).
-``window=`` keeps causal self-attention to the nearest ``window`` keys.
+``window=`` keeps causal self-attention to the nearest ``window`` keys;
+``gate=True`` multiplies the merged heads by ``sigmoid(x W_g)`` ahead of the
+output projection.
 """
 
 from __future__ import annotations
@@ -71,6 +73,11 @@ class MultiheadAttention(Module):
     back to ``embed_dim``); ``window`` lets a query of causal self-attention
     see only the nearest ``window`` keys (itself among them), in the flash
     kernels, the dense path and ``decode_step`` alike, not on the ring.
+    ``gate=True`` adds a fifth projection ``gate_proj`` (``num_heads *
+    head_dim`` x ``embed_dim``, no bias) of the queries' input: the merged
+    heads are multiplied by its sigmoid, entry by entry, before ``out_proj``,
+    on every path (``apply``, ``decode_step``, ``cross_step``), under the
+    scope ``ht.attention.gate``.
 
     ``apply(params, x, kv=None, causal=False, key_padding_mask=None,
     attn_mask=None)`` performs self-attention on ``x`` (B, S, E), or
@@ -101,6 +108,7 @@ class MultiheadAttention(Module):
         qk_norm_eps: float = 1e-5,
         head_dim: int = None,
         window: int = None,
+        gate: bool = False,
     ):
         if head_dim is None:
             if embed_dim % num_heads:
@@ -125,6 +133,7 @@ class MultiheadAttention(Module):
         self.num_kv_heads = num_kv_heads  # < num_heads = grouped-query attention
         self.kv_dim = num_kv_heads * self.head_dim
         self.window = window
+        self.gate = gate  # sigmoid(x W_g) on the merged heads, ahead of out_proj
         self.bias = bias
         self.comm = comm
         self.rope = rope  # rotary positions on SELF-attention q/k (not cross)
@@ -157,7 +166,22 @@ class MultiheadAttention(Module):
         if self.qk_norm:
             p["q_norm"] = {"weight": jnp.ones((self.head_dim,))}
             p["k_norm"] = {"weight": jnp.ones((self.head_dim,))}
+        if self.gate:
+            p["gate_proj"] = {"weight": jax.random.uniform(
+                jax.random.fold_in(key, 2), (Q, E), minval=-(1.0 / E**0.5), maxval=1.0 / E**0.5)}
         return p
+
+    def _gate_project(self, params, merged, x):
+        """``(merged * sigmoid(x W_g)) W_o^T (+ b)``: the tail every path
+        shares; without ``gate`` the output projection alone."""
+        if self.gate:
+            with jax.named_scope("ht.attention.gate"):
+                g = x @ params["gate_proj"]["weight"].T
+                merged = merged * jax.nn.sigmoid(g.astype(jnp.float32)).astype(merged.dtype)
+        y = merged @ params["out_proj"]["weight"].T
+        if self.bias:
+            y = y + params["out_proj"]["bias"]
+        return y
 
     def _position(self, params, qh, kh, positions):
         """What happens to the query and key heads between the projection and
@@ -270,7 +294,7 @@ class MultiheadAttention(Module):
         seen = jnp.arange(L) <= i  # future slots dead
         if self.window is not None:
             seen = seen & (i - jnp.arange(L) < self.window)
-        y = self._attend_merge_project(params, qh, kc, vc, dead_mask=seen)
+        y = self._attend_merge_project(params, qh, kc, vc, x, dead_mask=seen)
         return y, {"k": kc, "v": vc, "index": i + 1}
 
     def _project_kv(self, params, kv):
@@ -286,10 +310,11 @@ class MultiheadAttention(Module):
         n = self.num_kv_heads
         return self._heads(k, n), self._heads(v, n)
 
-    def _attend_merge_project(self, params, qh, kh, vh, dead_mask=None):
+    def _attend_merge_project(self, params, qh, kh, vh, x, dead_mask=None):
         """THE one-query decode tail: scaled scores (optionally masking
         ``dead_mask`` key slots), softmax, value contraction, head merge,
-        output projection.  Shared by :meth:`decode_step` (masks unwritten
+        the gate on the query's input ``x`` where the module has one, output
+        projection.  Shared by :meth:`decode_step` (masks unwritten
         cache slots) and :meth:`cross_step` (no mask) so the decode
         numerics can never drift between the two."""
         B, H = qh.shape[0], qh.shape[1]
@@ -305,10 +330,7 @@ class MultiheadAttention(Module):
             B, H, qh.shape[2], qh.shape[3]
         )
         merged = out.transpose(0, 2, 1, 3).reshape(B, 1, self.q_dim)
-        y = merged @ params["out_proj"]["weight"].T
-        if self.bias:
-            y = y + params["out_proj"]["bias"]
-        return y
+        return self._gate_project(params, merged, x)
 
     def precompute_kv(self, params, kv):
         """Project an encoder memory ONCE into per-head K/V for
@@ -325,7 +347,7 @@ class MultiheadAttention(Module):
         w = params["in_proj_weight"]
         b = params.get("in_proj_bias")
         q = x @ w[:E].T + (b[:E] if b is not None else 0.0)
-        return self._attend_merge_project(params, self._heads(q), kh, vh)
+        return self._attend_merge_project(params, self._heads(q), kh, vh, x)
 
     def _attend(self, qh, kh, vh, kv, causal, ring, masked, need_weights,
                 key_padding_mask, attn_mask):
@@ -432,9 +454,7 @@ class MultiheadAttention(Module):
                                       key_padding_mask, attn_mask)
         B, H, S, d = out.shape
         merged = out.transpose(0, 2, 1, 3).reshape(B, S, E)
-        y = merged @ params["out_proj"]["weight"].T
-        if self.bias:
-            y = y + params["out_proj"]["bias"]
+        y = self._gate_project(params, merged, x)
         if need_weights:
             # torch contract: (B, S_q, S_k) averaged over heads by default,
             # (B, H, S_q, S_k) with average_attn_weights=False

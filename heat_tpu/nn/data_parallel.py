@@ -144,7 +144,7 @@ class DataParallel:
     # -- fused train step ----------------------------------------------- #
     def make_train_step(self, loss_fn: Callable, with_rng: bool = False,
                         donate: bool = True, overlap_sync=None,
-                        grad_bucket_bytes=None, sync_domains=None, stats=None):
+                        grad_bucket_bytes=None, sync_domains=None, stats=None, forward=None):
         """Build a jitted (params, opt_state, x, y[, key]) →
         (params, opt_state, loss) step.  The batch arrives sharded; the mean
         loss over the GLOBAL batch makes XLA emit the gradient psum (the
@@ -188,6 +188,12 @@ class DataParallel:
         optimizer's new state, computed on the device in the same program
         (norms by parameter group of the gradient, of the step taken and of
         Adam's moments, say).  The fused path only.
+
+        ``forward`` (a method of the module with ``apply``'s arguments) is
+        called in ``apply``'s place: a model that computes its loss with its
+        head (``PatternLM.next_token_loss``, which never holds the logits)
+        returns from it what ``loss_fn`` then takes as its first argument.
+        The fused path only.
         """
         if self.optimizer is None:
             raise RuntimeError("make_train_step requires an attached optimizer")
@@ -197,20 +203,20 @@ class DataParallel:
             overlap_sync = getattr(self.optimizer, "overlap_sync", False)
         if grad_bucket_bytes is None:
             grad_bucket_bytes = getattr(self.optimizer, "grad_bucket_bytes", None)
-        if overlap_sync and stats is not None:
-            raise ValueError("stats= is a hook of the one-program step: not with overlap_sync")
+        if overlap_sync and (stats is not None or forward is not None):
+            raise ValueError("stats= and forward= are hooks of the one-program step: not with overlap_sync")
         if overlap_sync:
             return self._make_overlapped_step(
                 loss_fn, with_rng, donate, grad_bucket_bytes, sync_domains
             )
 
         _jit = functools.partial(jax.jit, donate_argnums=(0, 1) if donate else ())
-        apply = self.module.apply
+        apply = self.module.apply if forward is None else forward
         opt = self.optimizer
 
         from .modules import _module_accepts_train
 
-        accepts_train = _module_accepts_train(self.module)
+        accepts_train = forward is not None or _module_accepts_train(self.module)
 
         if accepts_train:
 

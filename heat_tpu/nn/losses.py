@@ -26,6 +26,7 @@ __all__ = [
     "MultiLabelMarginLoss", "MultiLabelSoftMarginLoss", "MultiMarginLoss", "NLLLoss",
     "PoissonNLLLoss", "SmoothL1Loss", "SoftMarginLoss", "TripletMarginLoss",
     "TripletMarginWithDistanceLoss", "next_token_cross_entropy",
+    "next_token_cross_entropy_by_rows",
 ]
 
 
@@ -397,7 +398,11 @@ def next_token_cross_entropy(logits, tokens):
     The log-sum-exp is float32, one sequence at a time and rematerialised
     under ``grad``, so ``B x S x V`` logits kept in bfloat16 are never held in
     float32 all at once: at 32,768 positions of a 16,384-word vocabulary that
-    is 0.5 GB a sequence in place of 2 GB."""
+    is 0.5 GB a sequence in place of 2 GB.  With one sequence that saves
+    nothing (the one float32 block is the whole batch's), and the logits and
+    their cotangent are held whole whatever the batch:
+    :func:`next_token_cross_entropy_by_rows` takes the head's product into the
+    blocks, so that no ``(B, S, V)`` array exists."""
 
     @jax.checkpoint
     def sequence(args):
@@ -409,3 +414,41 @@ def next_token_cross_entropy(logits, tokens):
     with jax.named_scope("ht.lm.head_loss"):
         total = jnp.sum(jax.lax.map(sequence, (logits, tokens)))
         return total / (tokens.shape[0] * (tokens.shape[1] - 1))
+
+
+def next_token_cross_entropy_by_rows(states, head, tokens, *, norm=None, block_rows: int = 8192):
+    """:func:`next_token_cross_entropy` of ``logits = norm(states) head^T``
+    without the logits: the final norm, the head's product, the float32
+    log-sum-exp and the picked logit are computed ``block_rows`` rows of the
+    flattened ``(B x S, D)`` states at a time, each block rematerialised under
+    ``grad`` (the backward pass makes a block's logits again), so the largest
+    array is one block's ``(block_rows, V)`` and neither the ``(B, S, V)``
+    logits nor their cotangent exist.  ``states`` is ``(B, S, D)``, ``head``
+    ``(V, D)`` in the dtype of the product's operands, ``tokens`` ``(B, S)``
+    integers; ``norm`` (rows in, rows out: the model's final norm) is applied
+    to a block's rows, ``None`` where ``states`` are normalised already.  The
+    last block is padded with rows that count for nothing where ``B x S`` is
+    no multiple of ``block_rows``; one block of ``B x S`` rows where that is
+    smaller."""
+    n, length, d = states.shape
+    rows = n * length
+    # a sequence's last position predicts nothing: its row counts 0, under any target
+    targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1).reshape(rows)
+    counts = jnp.broadcast_to(jnp.arange(length) < length - 1, (n, length)).reshape(rows)
+    block = min(block_rows, rows)
+    pad = -rows % block
+    blocks = lambda a: jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)).reshape(  # noqa: E731
+        (-1, block) + a.shape[1:])
+
+    @jax.checkpoint
+    def one(args):
+        h, target, count = args
+        if norm is not None:
+            h = norm(h)
+        lg = (h @ head.T).astype(jnp.float32)
+        picked = jnp.take_along_axis(lg, target[:, None], axis=-1)[:, 0]
+        return jnp.sum(jnp.where(count, jax.nn.logsumexp(lg, axis=-1) - picked, 0.0))
+
+    with jax.named_scope("ht.lm.head_loss"):
+        total = jnp.sum(jax.lax.map(one, (blocks(states.reshape(rows, d)), blocks(targets), blocks(counts))))
+        return total / (n * (length - 1))

@@ -1145,14 +1145,20 @@ class _ShortConvOperator(nn.Module):
 class _PatternBlock(nn.Module):
     """``h = x + Op(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; an expert
     FFN also returns what its routing counted (``MoE.apply_with_stats``), and
-    with ``route_on_input`` its router scores ``x`` itself, not ``RMSNorm(h)``."""
+    with ``route_on_input`` its router scores ``x`` itself, not ``RMSNorm(h)``.
+    With ``output_norms`` each sublayer's output goes through an RMSNorm of
+    its own before the residual sum: ``h = x + RMSNorm(Op(RMSNorm(x)))``,
+    ``y = h + RMSNorm(FFN(RMSNorm(h)))``."""
 
     def __init__(self, embed_dim: int, operator: nn.Module, ffn: nn.Module, eps: float,
-                 route_on_input: bool = False):
+                 route_on_input: bool = False, output_norms: bool = False):
         self.operator_norm = nn.RMSNorm(embed_dim, eps=eps)
         self.operator = operator
         self.ffn_norm = nn.RMSNorm(embed_dim, eps=eps)
         self.ffn = ffn
+        # a norm on each sublayer's output, ahead of the residual sum
+        self.operator_out_norm = nn.RMSNorm(embed_dim, eps=eps) if output_norms else None
+        self.ffn_out_norm = nn.RMSNorm(embed_dim, eps=eps) if output_norms else None
         self.routed = hasattr(ffn, "apply_with_stats")
         # the router scores the block's input as it enters, ahead of the operator
         self.route_on_input = route_on_input and self.routed
@@ -1164,26 +1170,47 @@ class _PatternBlock(nn.Module):
         import jax
 
         k1, k2 = jax.random.split(key)
-        return {
+        out = {
             "operator_norm": self.operator_norm.init(None), "operator": self.operator.init(k1),
             "ffn_norm": self.ffn_norm.init(None), "ffn": self.ffn.init(k2),
         }
+        if self.operator_out_norm is not None:
+            out["operator_out_norm"] = self.operator_out_norm.init(None)
+            out["ffn_out_norm"] = self.ffn_out_norm.init(None)
+        return out
+
+    def _normed_sum(self, params, name: str, x, out):
+        """``x + RMSNorm(out)`` by the sublayer's output norm ``name``."""
+        import jax
+
+        with jax.named_scope("ht.lm.norm"):
+            return x + getattr(self, name).apply(params[name], out)
 
     def apply(self, params, x, **kw):
         import jax
 
+        # without output norms a residual sum stays under its sublayer's scope, where it
+        # was; with them it goes with the norm, outside, so that no operation has two names
+        plain = self.operator_out_norm is None
         with jax.named_scope("ht.lm.norm"):
             z = self.operator_norm.apply(params["operator_norm"], x)
         with jax.named_scope(self.scope):
-            h = x + self.operator.apply(params["operator"], z, causal=True)
+            h = self.operator.apply(params["operator"], z, causal=True)
+            if plain:
+                h = x + h
+        if not plain:
+            h = self._normed_sum(params, "operator_out_norm", x, h)
         with jax.named_scope("ht.lm.norm"):
             z = self.ffn_norm.apply(params["ffn_norm"], h)
         if self.routed:
             routed_on = {"router_input": x} if self.route_on_input else {}
             out, stats = self.ffn.apply_with_stats(params["ffn"], z, **routed_on)
-            return h + out, stats
+            return (h + out if plain else self._normed_sum(params, "ffn_out_norm", h, out)), stats
         with jax.named_scope("ht.mlp"):
-            return h + self.ffn.apply(params["ffn"], z), None
+            out = self.ffn.apply(params["ffn"], z)
+            if plain:
+                return h + out, None
+        return self._normed_sum(params, "ffn_out_norm", h, out), None
 
 
 class PatternLM(nn.Module):
@@ -1207,8 +1234,10 @@ class PatternLM(nn.Module):
     feed-forward of width ``ffn_dim``; with ``num_experts`` set, every later
     layer has ``num_experts`` SwiGLU experts of width ``expert_dim``,
     ``experts_per_token`` of them a token, chosen by sigmoid scores plus a
-    selection bias (a buffer) and weighted by the renormalised scores, routed
-    without drops (``MoE(dispatch="sorted")``).  ``router_scoring="softmax"``
+    selection bias (a buffer) and weighted by the renormalised scores (the
+    chosen scores over their sum ``+ 1e-6``, ``MoE._route``'s constant: a
+    published model's own may differ, ``1e-20`` in one, by less than float32
+    resolves), routed without drops (``MoE(dispatch="sorted")``).  ``router_scoring="softmax"``
     chooses by the logits alone and weights by a softmax over the chosen (no
     selection bias), ``expert_activation="relu"`` makes the experts ReGLU, and
     ``route_before_operator`` has each layer's router score the layer's input
@@ -1220,6 +1249,12 @@ class PatternLM(nn.Module):
     expert layers' buffers (``MoE(shared_dim=, rows_bound=)``).  RMSNorm
     everywhere, no bias anywhere; the token embedding is also the output
     head unless ``tie_embedding=False`` gives the head a matrix of its own.
+    ``attention_gate`` gives every attention kind's layers a gate on their
+    merged heads (``MultiheadAttention(gate=True)``), ``output_norms`` every
+    block an RMSNorm on each sublayer's output ahead of the residual sum as
+    well as the one on its input, and ``embedding_scale`` multiplies the
+    embedded tokens as they enter the first layer (``sqrt(embed_dim)`` in
+    models that scale it so).
 
     Parameters are float32, drawn ``N(0, init_std)`` (norm weights 1, the
     selection bias ``N(0, bias_std)`` and then fixed, a ``"kda"`` layer's
@@ -1233,6 +1268,10 @@ class PatternLM(nn.Module):
     (B, S, vocab) in dtype, stats)``; ``stats`` holds, per expert layer, the
     rows routed to each expert held and the rows dropped (always 0 on this
     path), for the ``stats=`` hook of ``DataParallel.make_train_step``.
+    ``next_token_loss(params, tokens)`` returns ``(the mean next-token
+    cross-entropy, stats)`` without ever holding the logits: the final norm,
+    the head's product and the loss a block of rows at a time
+    (``make_train_step(..., forward=model.next_token_loss)``).
     ``decay_mask(params)`` is the usual weight-decay mask (matrices, an
     untied head among them, yes; norms, selection bias, embedding, ``A_log``
     and ``dt_bias`` no).
@@ -1252,7 +1291,8 @@ class PatternLM(nn.Module):
                  head_dim: int = None, qk_norm: bool = True, window: int = None,
                  rope_kinds: Sequence[str] = ("full_attention", "sliding_attention"),
                  router_scoring: str = "sigmoid", expert_activation: str = "silu",
-                 route_before_operator: bool = False):
+                 route_before_operator: bool = False, attention_gate: bool = False,
+                 output_norms: bool = False, embedding_scale: float = None):
         from .attention import LatentAttention, MultiheadAttention
         from .moe import MoE
 
@@ -1268,12 +1308,14 @@ class PatternLM(nn.Module):
             return lambda: MultiheadAttention(
                 embed_dim, num_heads, bias=False, rope=kind in rope_kinds, rope_base=rope_base,
                 rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=qk_norm, qk_norm_eps=norm_eps,
-                head_dim=head_dim, window=window if kind == "sliding_attention" else None)
+                head_dim=head_dim, window=window if kind == "sliding_attention" else None,
+                gate=attention_gate)
 
         n_dense = len(layer_types) if num_dense_layers is None or not num_experts else num_dense_layers
         self.vocab_size, self.embed_dim = vocab_size, embed_dim
         self.layer_types = tuple(layer_types)
         self.init_std, self.bias_std, self.dtype = init_std, bias_std, dtype
+        self.embedding_scale = embedding_scale
         self.embed = nn.Embedding(vocab_size, embed_dim)
         self.head = None if tie_embedding else nn.Linear(embed_dim, vocab_size, bias=False)
         operators = {
@@ -1295,7 +1337,8 @@ class PatternLM(nn.Module):
                 experts_held=experts_held, shared_dim=shared_expert_dim, rows_bound=expert_rows_bound,
                 activation=expert_activation)
             self.blocks.append(_PatternBlock(embed_dim, operators[kind](), ffn, norm_eps,
-                                             route_on_input=route_before_operator))
+                                             route_on_input=route_before_operator,
+                                             output_norms=output_norms))
         self.norm = nn.RMSNorm(embed_dim, eps=norm_eps)
         self._remat_fns = [{} for _ in self.blocks]
 
@@ -1353,7 +1396,9 @@ class PatternLM(nn.Module):
 
         return jax.tree_util.tree_map_with_path(cast, tree)
 
-    def apply(self, params, tokens, *, train: bool = False, key=None):
+    def _states(self, params, tokens, train: bool):
+        """``(the last block's output (B, S, D), the output head's matrix in
+        the activations' dtype, stats)``: everything ahead of the final norm."""
         import jax
 
         with jax.named_scope("ht.lm.cast"):
@@ -1361,6 +1406,8 @@ class PatternLM(nn.Module):
             head = embedding if self.head is None else self._cast(params["head"])["weight"]
         with jax.named_scope("ht.lm.embed"):
             h = embedding[tokens]
+            if self.embedding_scale is not None:
+                h = h * self.embedding_scale
         stats = []
         for block, cache, p in zip(self.blocks, self._remat_fns, params["blocks"]):
             def run(p, h, block=block):
@@ -1372,7 +1419,28 @@ class PatternLM(nn.Module):
                 h, s = _remat_jit(cache, train, run)(p, h)
             if s is not None:
                 stats.append(s)
+        return h, head, stats
+
+    def apply(self, params, tokens, *, train: bool = False, key=None):
+        import jax
+
+        h, head, stats = self._states(params, tokens, train)
         with jax.named_scope("ht.lm.head_loss"):
             h = self.norm.apply(params["norm"], h)
             logits = h @ head.T
         return logits, stats
+
+    def next_token_loss(self, params, tokens, *, train: bool = False, key=None, block_rows: int = 8192):
+        """``(mean next-token cross-entropy of tokens (B, S), stats)``: what
+        :func:`~heat_tpu.nn.losses.next_token_cross_entropy` makes of
+        ``apply``'s logits, by
+        :func:`~heat_tpu.nn.losses.next_token_cross_entropy_by_rows`: the final
+        norm, the head's product and the loss ``block_rows`` rows at a time, no
+        ``(B, S, vocab)`` array.  ``DataParallel.make_train_step(...,
+        forward=model.next_token_loss)`` trains on it."""
+        from .losses import next_token_cross_entropy_by_rows
+
+        h, head, stats = self._states(params, tokens, train)
+        loss = next_token_cross_entropy_by_rows(
+            h, head, tokens, norm=lambda rows: self.norm.apply(params["norm"], rows), block_rows=block_rows)
+        return loss, stats

@@ -32,6 +32,7 @@ TOY = dict(
     lm_batch=8, lm_seq=32, lm_prompt=4, lm_new=4,
     attn=(8, 4, 128, 16), attn_kv_heads=2, attn_long=(2, 8, 256, 16),
     kda=(8, 128, 128), kda_conv=(1, 64, 2, 128),
+    gated_window=dict(embed=32, heads=4, kv_heads=2, head_dim=16, seq=128, window=32, prefix=48),
     ring=(2, 2, 16, 8),
     moe=dict(embed=16, hidden=32, experts_per_chip=2, tokens_per_chip=8),
     pipe=dict(embed=16, heads=2, seq=8, batch_per_chip=1),
@@ -54,7 +55,7 @@ def test_every_phase_toy(capsys):
     assert [l.split()[1] for l in lines] == [
         "array.matmul", "array.resplit", "array.qr", "array.kmeans", "array.ragged",
         "array.fft", "train.mlp_dataparallel", "train.daso", "model.transformer_lm",
-        "model.flash_attention", "model.kda", "model.kda_conv", "multi.dryrun_tiers",
+        "model.flash_attention", "model.gated_window_attention", "model.kda", "model.kda_conv", "multi.dryrun_tiers",
         "multi.ring_attention", "multi.moe_expert_parallel", "multi.pipeline",
         "multi.daso_two_tier",
     ]
