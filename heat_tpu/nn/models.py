@@ -154,12 +154,18 @@ def _remat_jit(cache: dict, train: bool, block_fn):
     sequence-length checkpointing work.  The jit around jax.checkpoint is
     REQUIRED (checkpoint's closed_call cannot evaluate eagerly inside the
     ring path's shard_map) and cached per train flag so repeat applies
-    reuse one traced wrapper."""
+    reuse one traced wrapper.  The one thing it keeps is what the flash
+    kernels name (``ops.flash_attention.KEPT``: their output and
+    log-sum-exp), so the backward reads those and never runs an attention
+    forward again; a block without a flash call keeps nothing."""
     fn = cache.get(train)
     if fn is None:
         import jax
 
-        fn = cache[train] = jax.jit(jax.checkpoint(block_fn))
+        from ..ops.flash_attention import KEPT
+
+        policy = jax.checkpoint_policies.save_only_these_names(*KEPT)
+        fn = cache[train] = jax.jit(jax.checkpoint(block_fn, policy=policy))
     return fn
 
 

@@ -39,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -63,8 +64,14 @@ def _round_up(n: int, m: int) -> int:
 
 # engagement counter, same contract as ring_attention.path_counts: tests and
 # chip_smoke.py assert which implementation a call took (counted per call,
-# at trace time under an outer jit)
-path_counts = {"pallas": 0, "dense": 0}
+# at trace time under an outer jit); "kept" counts the differentiated calls
+# whose residuals carry the names in ``KEPT``
+path_counts = {"pallas": 0, "dense": 0, "kept": 0}
+
+# the names of the flash forward's residuals: a checkpoint whose policy saves
+# them (``nn/models._remat_jit``) keeps ``out`` and ``lse`` to the backward,
+# and the recomputation no longer runs the forward kernel
+KEPT = ("ht.flash.out", "ht.flash.lse")
 
 
 def _dense_attention(q, k, v, causal: bool, scale: float, s_valid: int,
@@ -1219,6 +1226,8 @@ def _flash_gqa_fwd_rule(q, k, v, causal, scale, s_valid, hq, hk, interpret,
                         window):
     out, lse = _flash_gqa_fwd_impl(q, k, v, causal, scale, s_valid, hq, hk,
                                    interpret, window)
+    out, lse = checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[1])
+    path_counts["kept"] += 1
     return out, (q, k, v, out, lse)
 
 

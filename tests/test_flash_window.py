@@ -198,7 +198,10 @@ def test_without_a_window_the_kernels_lower_as_before(blocks128, name):
     """``window=None`` is a static branch that builds the kernels as they were
     at 57d3c79, instruction for instruction: the fixtures are the jaxprs of
     value and gradients that commit traced (source lines and addresses
-    stripped), and the two cells that run these kernels must not move."""
+    stripped), and the two cells that run these kernels must not move.  From
+    PR 39 on they also hold the forward's two residuals' names
+    (``KEPT``: a ``name`` equation each, which lowers to nothing); without
+    those the jaxprs are 57d3c79's to the character."""
     fa = blocks128
     call, shapes, kw = JAXPR_CASES[name]
     with gzip.open(os.path.join(FIXTURES, f"{name}.jaxpr.txt.gz"), "rt") as f:
