@@ -11,7 +11,7 @@ mesh and scores accumulate flash-style while K/V rotate on the ICI ring,
 so context length scales with the chip count (any length: the ring pads
 and masks ragged sequences).  ``LatentAttention`` is multi-head latent
 attention as trained: keys and values expanded from one low-rank latent, a
-key part shared by all heads, values narrower than keys.  With ``num_kv_heads < num_heads``
+key part shared by all heads (rotated or not), values narrower than keys.  With ``num_kv_heads < num_heads``
 (grouped-query attention, beyond torch's module) the packed projection
 shrinks to (E + 2·num_kv_heads·head_dim, E) rows — torch state dicts then
 no longer round-trip, by construction; nor do they with ``head_dim=`` a head
@@ -22,6 +22,8 @@ output projection.
 """
 
 from __future__ import annotations
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -464,20 +466,41 @@ class MultiheadAttention(Module):
         return y
 
 
+def _pairs_to_halves(w, width: int, groups: int):
+    """``w`` (``groups`` blocks of rows, stacked) with the last ``width`` rows
+    of each block reordered from consecutive pairs ``(2i, 2i+1)`` to the two
+    halves ``(i, width/2 + i)``."""
+    blocks = w.reshape(groups, -1, w.shape[-1])
+    pairs = blocks[:, -width:].reshape(groups, width // 2, 2, -1)
+    halves = pairs.swapaxes(1, 2).reshape(groups, width, -1)
+    return jnp.concatenate([blocks[:, :-width], halves], axis=1).reshape(w.shape)
+
+
 class LatentAttention(Module):
-    """Causal multi-head latent attention (MLA) without positional encoding,
-    in its training form: the latent is expanded to full keys and values,
-    not absorbed into the query.
+    """Causal multi-head latent attention (MLA), in its training form: the
+    latent is expanded to full keys and values, not absorbed into the query.
 
     ``q = x W_q`` as ``num_heads`` heads of ``qk_nope_dim + qk_shared_dim``;
     ``[c, k_shared] = x W_kva`` (``kv_rank`` and ``qk_shared_dim`` wide);
     ``[k_nope, v] = RMSNorm(c) W_kvb`` as heads of ``qk_nope_dim`` and
     ``v_dim``; a head's key is ``[k_nope, k_shared]``, ``k_shared`` the same
     for every head (published configurations call its width
-    ``qk_rope_head_dim``: with rotary positions it is the part that carries
-    them; here nothing is rotated).  Softmax of ``q k^T / sqrt(key width)``
-    over the earlier positions, the ``num_heads x v_dim`` values through
-    ``W_o``.  No bias anywhere.  Keys are wider than values, which
+    ``qk_rope_head_dim``).  Without ``rope`` nothing carries positions.  With
+    ``rope=True`` the last ``qk_shared_dim`` channels of every query head and
+    ``k_shared`` are rotated by consecutive channel pairs ``(2i, 2i+1)``, as
+    :func:`apply_rope`'s ``pairing="interleaved"`` rotates them (base
+    ``rope_base``, positions ``0 .. S-1``), ``k_shared`` once, before it is
+    broadcast to the heads.  The scores are computed as published
+    implementations compute them: the rows of ``W_q`` and ``W_kva`` that make
+    those channels are reordered so that a pair's two channels come out half
+    the width apart (a transpose of small blocks of the weights; a strided
+    slice of the activations' last axis would lower to a gather), and the
+    halves are rotated (``pairing="half"``); the same permutation of the
+    query's and the key's channels leaves every score as it is.  The reorder,
+    the split, the rotation and the assembly of the query and key heads run
+    under the scope ``ht.attention.rope``.  Softmax of ``q k^T / sqrt(key
+    width)`` over the earlier positions, the ``num_heads x v_dim`` values
+    through ``W_o``.  No bias anywhere.  Keys are wider than values, which
     ``ops.flash_attention`` takes as they are.
 
     The scores-softmax-values part runs under the scope ``ht.attention``,
@@ -486,9 +509,13 @@ class LatentAttention(Module):
     """
 
     def __init__(self, embed_dim: int, num_heads: int, *, kv_rank: int, qk_nope_dim: int,
-                 qk_shared_dim: int, v_dim: int, eps: float = 1e-5):
+                 qk_shared_dim: int, v_dim: int, eps: float = 1e-5, rope: bool = False,
+                 rope_base: float = 10000.0):
+        if rope and qk_shared_dim % 2:
+            raise ValueError("rope requires an even qk_shared_dim")
         self.embed_dim, self.num_heads, self.kv_rank = embed_dim, num_heads, kv_rank
         self.qk_nope_dim, self.qk_shared_dim, self.v_dim, self.eps = qk_nope_dim, qk_shared_dim, v_dim, eps
+        self.rope, self.rope_base = rope, rope_base
 
     def init(self, key):
         e, h, r = self.embed_dim, self.num_heads, self.kv_rank
@@ -507,13 +534,23 @@ class LatentAttention(Module):
         b, s, _ = x.shape
         h, nope, shared = self.num_heads, self.qk_nope_dim, self.qk_shared_dim
         w = {n: params[n]["weight"].astype(x.dtype) for n in ("q_proj", "kv_a_proj", "kv_b_proj", "out_proj")}
+        if self.rope:
+            with jax.named_scope("ht.attention.rope"):
+                w["q_proj"] = _pairs_to_halves(w["q_proj"], shared, h)
+                w["kv_a_proj"] = _pairs_to_halves(w["kv_a_proj"], shared, 1)
         heads = lambda t: t.reshape(b, s, h, -1).transpose(0, 2, 1, 3)  # noqa: E731
         q = heads(x @ w["q_proj"].T)
         c, k_shared = jnp.split(x @ w["kv_a_proj"].T, [self.kv_rank], axis=-1)
         c = rms_normalize(c, params["kv_a_norm"]["weight"], self.eps)
         kv = heads(c @ w["kv_b_proj"].T)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_shared[:, None], (b, h, s, shared))], axis=-1)
+        with jax.named_scope("ht.attention.rope") if self.rope else contextlib.nullcontext():
+            if self.rope:
+                positions = jnp.arange(s)
+                q = jnp.concatenate(
+                    [q[..., :nope], apply_rope(q[..., nope:], positions, self.rope_base, "half")], axis=-1)
+                k_shared = apply_rope(k_shared, positions, self.rope_base, "half")
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_shared[:, None], (b, h, s, shared))], axis=-1)
         with jax.named_scope("ht.attention"):
             out = flash_attention(q, k, kv[..., nope:], causal=causal, scale=(nope + shared) ** -0.5)
         return out.transpose(0, 2, 1, 3).reshape(b, s, h * self.v_dim) @ w["out_proj"].T

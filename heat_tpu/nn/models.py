@@ -1234,9 +1234,13 @@ class PatternLM(nn.Module):
     Delta Attention, ``kda_heads`` heads of ``kda_head_dim`` with a
     convolution of ``conv_taps`` positions, low-rank gates of ``kda_gate_rank``
     and chunks of ``kda_chunk`` tokens: :class:`~heat_tpu.nn.KimiDeltaAttention`)
-    or ``"mla"`` (latent attention without positions, ``num_heads`` heads of
-    ``qk_nope_dim + qk_shared_dim`` on a latent of ``kv_rank`` with values of
-    ``v_dim``: :class:`~heat_tpu.nn.LatentAttention`).  The first ``num_dense_layers`` layers have a SwiGLU
+    or ``"mla"`` (latent attention, ``num_heads`` heads of ``qk_nope_dim +
+    qk_shared_dim`` on a latent of ``kv_rank`` normalised with
+    ``kv_norm_eps``, by default ``norm_eps``, with values of ``v_dim``:
+    :class:`~heat_tpu.nn.LatentAttention`; without positions unless
+    ``rope_kinds`` names ``"mla"``, and then its shared key part and the
+    matching part of every query head are rotated with base ``rope_base``).
+    The first ``num_dense_layers`` layers have a SwiGLU
     feed-forward of width ``ffn_dim``; with ``num_experts`` set, every later
     layer has ``num_experts`` SwiGLU experts of width ``expert_dim``,
     ``experts_per_token`` of them a token, chosen by sigmoid scores plus a
@@ -1298,7 +1302,7 @@ class PatternLM(nn.Module):
                  rope_kinds: Sequence[str] = ("full_attention", "sliding_attention"),
                  router_scoring: str = "sigmoid", expert_activation: str = "silu",
                  route_before_operator: bool = False, attention_gate: bool = False,
-                 output_norms: bool = False, embedding_scale: float = None):
+                 output_norms: bool = False, embedding_scale: float = None, kv_norm_eps: float = None):
         from .attention import LatentAttention, MultiheadAttention
         from .moe import MoE
 
@@ -1332,7 +1336,8 @@ class PatternLM(nn.Module):
                 chunk=kda_chunk, eps=norm_eps),
             "mla": lambda: LatentAttention(
                 embed_dim, num_heads, kv_rank=kv_rank, qk_nope_dim=qk_nope_dim,
-                qk_shared_dim=qk_shared_dim, v_dim=v_dim, eps=norm_eps),
+                qk_shared_dim=qk_shared_dim, v_dim=v_dim, eps=norm_eps if kv_norm_eps is None else kv_norm_eps,
+                rope="mla" in rope_kinds, rope_base=rope_base),
         }
         self.blocks = []
         for i, kind in enumerate(self.layer_types):

@@ -45,6 +45,12 @@ PATTERNS = {
                           router_scoring="softmax", expert_activation="relu", route_before_operator=True,
                           tie_embedding=False, **{**EXPERTS, "bias_std": 0.0}),
         {"ht.attention.proj", "ht.attention", "ht.attention.window"}),
+    "rotated_latent_attention_shared_experts": (
+        lambda: PatternLM(96, 64, ["mla", "mla"], num_heads=2, ffn_dim=96, num_dense_layers=1,
+                          experts_per_token=2, expert_dim=32, tie_embedding=False, shared_expert_dim=64,
+                          expert_rows_bound=64, kv_rank=24, qk_nope_dim=16, qk_shared_dim=8, v_dim=16,
+                          rope_kinds=("mla",), rope_base=5e4, kv_norm_eps=1e-6, **EXPERTS),
+        {"ht.attention.proj", "ht.attention.rope", "ht.attention", "ht.mlp", "ht.moe.shared"}),
 }
 EVERY_PATTERN = {"ht.lm.cast", "ht.lm.embed", "ht.lm.block", "ht.lm.norm", "ht.lm.head_loss",
                  "ht.moe.route", "ht.moe.dispatch", "ht.moe.experts", "ht.moe.combine", "ht.optim.update"}
