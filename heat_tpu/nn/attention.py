@@ -23,6 +23,7 @@ output projection.
 
 from __future__ import annotations
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,14 @@ import jax.numpy as jnp
 from .modules import Linear, Module, rms_normalize
 
 __all__ = ["MultiheadAttention", "LatentAttention", "apply_rope"]
+
+
+def rope_angles(positions, d: int, base: float = 10000.0):
+    """The rotary angles (..., S, d/2), float32: ``position * base^(-2i/d)``
+    for the channel pairs ``i`` of heads ``d`` wide (``apply_rope``'s, and
+    ``ops.position_heads``' tables)."""
+    freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (d/2,)
+    return jnp.asarray(positions, jnp.float32)[..., None] * freqs
 
 
 def apply_rope(x, positions, base: float = 10000.0, pairing: str = "interleaved"):
@@ -50,8 +59,7 @@ def apply_rope(x, positions, base: float = 10000.0, pairing: str = "interleaved"
         raise ValueError(f"rope requires an even head dim, got {d}")
     if pairing not in ("interleaved", "half"):
         raise ValueError(f"pairing must be 'interleaved' or 'half', got {pairing!r}")
-    freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (d/2,)
-    ang = jnp.asarray(positions, jnp.float32)[..., None] * freqs  # (..., S, d/2)
+    ang = rope_angles(positions, d, base)  # (..., S, d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
     if pairing == "half":
@@ -79,7 +87,11 @@ class MultiheadAttention(Module):
     head_dim`` x ``embed_dim``, no bias) of the queries' input: the merged
     heads are multiplied by its sigmoid, entry by entry, before ``out_proj``,
     on every path (``apply``, ``decode_step``, ``cross_step``), under the
-    scope ``ht.attention.gate``.
+    scope ``ht.attention.gate``.  Self-attention off the ring takes its
+    heads from the packed projection through ``ops.position_heads`` (the QK
+    norm and a rotation by halves fused with the split and the transpose,
+    where the heads are whole lane tiles); every other path composes them
+    (``_self_heads``).
 
     ``apply(params, x, kv=None, causal=False, key_padding_mask=None,
     attn_mask=None)`` performs self-attention on ``x`` (B, S, E), or
@@ -197,6 +209,18 @@ class MultiheadAttention(Module):
             qh = apply_rope(qh, positions, self.rope_base, self.rope_pairing)
             kh = apply_rope(kh, positions, self.rope_base, self.rope_pairing)
         return qh, kh
+
+    def _self_heads(self, params, proj):
+        """Self-attention's query, key and value heads (B, H, S, d) from the
+        packed projection, the query and key heads positioned (``_position``;
+        rotary positions on self-attention only: cross-attention has no
+        shared position scale between q and the encoder memory)."""
+        q, k, v = jnp.split(proj, [self.q_dim, self.q_dim + self.kv_dim], axis=-1)
+        qh = self._heads(q)  # (B, H, S, d)
+        kh = self._heads(k, self.num_kv_heads)
+        vh = self._heads(v, self.num_kv_heads)
+        qh, kh = self._position(params, qh, kh, jnp.arange(qh.shape[-2]))
+        return qh, kh, vh
 
     def _heads(self, t, n_heads: int = None):
         B, S, _ = t.shape
@@ -434,20 +458,23 @@ class MultiheadAttention(Module):
         b = params.get("in_proj_bias")
         if kv is None:
             proj = x @ w.T + (b if b is not None else 0.0)
-            q, k, v = jnp.split(proj, [E, E + self.kv_dim], axis=-1)
-            qh = self._heads(q)  # (B, H, S, d)
-            kh = self._heads(k, self.num_kv_heads)
-            vh = self._heads(v, self.num_kv_heads)
+            if ring:  # proj sharded along the sequence; the positions are global
+                qh, kh, vh = self._self_heads(params, proj)
+            else:
+                # one pass a direction where the kernels take the shapes
+                from ..ops.position_heads import position_heads
+
+                qh, kh, vh = position_heads(
+                    proj, self.num_heads, self.num_kv_heads, functools.partial(self._self_heads, params),
+                    norms=(params["q_norm"]["weight"], params["k_norm"]["weight"]) if self.qk_norm else (),
+                    eps=self.qk_norm_eps, rope_base=self.rope_base if self.rope else None,
+                    rope_pairing=self.rope_pairing)
         else:
             q = x @ w[:E].T + (b[:E] if b is not None else 0.0)
             qh = self._heads(q)
             kh, vh = self._project_kv(params, kv)
-        if kv is None:
-            # rotary positions on self-attention only (cross-attention has
-            # no shared position scale between q and the encoder memory)
-            qh, kh = self._position(params, qh, kh, jnp.arange(qh.shape[-2]))
-        elif self.qk_norm:
-            raise ValueError("qk_norm is defined for self-attention only")
+            if self.qk_norm:
+                raise ValueError("qk_norm is defined for self-attention only")
         # the scores-softmax-values part under a scope of its own, so that a
         # trace of one fused training step can tell it from the projections,
         # and a windowed layer's from a global one's

@@ -118,6 +118,35 @@ def test_kda_convolution_kernels_lower_at_the_training_cell_shape(monkeypatch, d
     assert exported.mlir_module().count("tpu_custom_call") >= 6
 
 
+@pytest.mark.parametrize("cell, heads, length, norm, rope", [
+    ("trinity_mini_26b_a3b_train_1x32k", 32, 32768, True, True),
+    ("trinity_mini_26b_a3b_train_1x32k/global", 32, 32768, True, False),
+    ("smallthinker_21b_a3b_train_1x16k", 28, 16384, False, True),
+    ("smallthinker_21b_a3b_train_1x16k/global", 28, 16384, False, False)])
+def test_position_heads_kernels_lower_at_the_training_cell_shape(monkeypatch, cell, heads, length, norm, rope):
+    """A layer's packed projection (1, S, (H + 2 x 4) x 128) bfloat16 through
+    ``position_heads``' forward and backward kernels, as its ``custom_vjp``
+    calls them: Trinity's 32 query heads with QK norm, rotated on its
+    windowed layers, and SmallThinker's 28 without the norm."""
+    ph = importlib.import_module("heat_tpu.ops.position_heads")
+    monkeypatch.setattr(ph, "platform_of", lambda q: "tpu")  # the kernels, not their interpreter
+    monkeypatch.setattr(ph, "_kernel_mesh", lambda q: None)
+    proj = jax.ShapeDtypeStruct((1, length, (heads + 8) * 128), jnp.bfloat16)
+    tile = ph._pallas_gate(proj, heads, 4, True)
+    assert tile == 512
+    norms = (jax.ShapeDtypeStruct((128,), jnp.bfloat16),) * 2 if norm else ()
+
+    def f(proj, *norms):
+        def loss(proj, norms):
+            out = ph._position_heads(proj, norms, heads, 4, 1e-5, 10000.0 if rope else None, tile, False)
+            return sum(jnp.sum(t.astype(jnp.float32)) for t in out)
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(proj, norms)
+
+    exported = jax.export.export(jax.jit(f), platforms=["tpu"])(proj, *norms)
+    assert exported.mlir_module().count("tpu_custom_call") >= 2
+
+
 def _gqa_case():
     import numpy as np
 
@@ -244,6 +273,39 @@ def test_kda_convolution_kernels_per_shard_match_the_dense_executor(monkeypatch)
         np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=0)
 
 
+def test_position_heads_kernels_per_shard_match_the_composition(monkeypatch):
+    """``position_heads``' kernels (the interpreter here) a shard of the
+    sequences a device of the CPU mesh, as across chips: the heads, ``d proj``
+    and both norms' weights' cotangents against the composition."""
+    import numpy as np
+
+    from heat_tpu.core.devices import get_default_mesh
+    from heat_tpu.nn.attention import MultiheadAttention
+
+    ph = importlib.import_module("heat_tpu.ops.position_heads")
+    mesh = get_default_mesh()
+    monkeypatch.setattr(ph, "_kernel_mesh", lambda q: mesh)
+    op = MultiheadAttention(256, 4, bias=False, rope=True, rope_pairing="half", num_kv_heads=2, head_dim=128,
+                            qk_norm=True)
+    params = op.init(jax.random.key(0))
+    proj = jax.random.normal(jax.random.key(1), (mesh.size, 48, 8 * 128))
+    norms = (params["q_norm"]["weight"], params["k_norm"]["weight"])
+    assert ph._pallas_gate(proj, 4, 2, True) == 48 and ph._pallas_gate(proj[1:], 4, 2, True) is None
+
+    def both(fused):
+        def loss(proj, norms):
+            p = {**params, "q_norm": {"weight": norms[0]}, "k_norm": {"weight": norms[1]}}
+            dense = lambda t: op._self_heads(p, t)  # noqa: E731
+            heads = ph.position_heads(proj, 4, 2, dense, norms=norms, rope_base=op.rope_base) if fused else dense(proj)
+            return sum(jnp.sum(jnp.sin(t)) for t in heads), heads
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(proj, norms)
+
+    ((_, got), d_got), ((_, want), d_want) = both(True), both(False)
+    for a, b in zip(jax.tree.leaves((got, d_got)), jax.tree.leaves((want, d_want))):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=0)
+
+
 class TestNoQuietFallback:
     """On the platform the gate selected the kernel for, a kernel failure is
     the caller's failure: no ``except`` turns it into the dense/jnp result."""
@@ -262,3 +324,12 @@ class TestNoQuietFallback:
         with pytest.raises(RuntimeError, match="kernel refused"):
             fa.flash_attention_gqa(q, q[:, :1], q[:, :1], causal=True)
         assert fa.path_counts["dense"] == dense
+
+    def test_position_heads_raises(self, monkeypatch):
+        ph = importlib.import_module("heat_tpu.ops.position_heads")
+        monkeypatch.setattr(ph, "_heads_call", self._boom)
+        proj = jnp.ones((1, 32, 4 * 128), jnp.float32)
+        dense = ph.path_counts["dense"]
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            ph.position_heads(proj, 2, 1, lambda p: (p, p, p), rope_base=10000.0)
+        assert ph.path_counts["dense"] == dense
