@@ -65,8 +65,21 @@ def _round_up(n: int, m: int) -> int:
 # engagement counter, same contract as ring_attention.path_counts: tests and
 # chip_smoke.py assert which implementation a call took (counted per call,
 # at trace time under an outer jit); "kept" counts the differentiated calls
-# whose residuals carry the names in ``KEPT``
-path_counts = {"pallas": 0, "dense": 0, "kept": 0}
+# whose residuals carry the names in ``KEPT``; "bwd_fused" and
+# "bwd_two_sweeps" which backward a static-offset call's gradient took
+# (``_fused_bwd_fits``)
+path_counts = {"pallas": 0, "dense": 0, "kept": 0, "bwd_fused": 0,
+               "bwd_two_sweeps": 0}
+
+# VMEM of the fused backward (``_flash_bwd_fused_kernel``), which holds a
+# head's dQ, dK and dV whole in float32: the limit its ``CompilerParams``
+# give Mosaic, and what ``_fused_bwd_bytes`` may take of it.  A v5e has 128
+# MiB; compiled for one, Trinity-Mini's layer (48 MiB of the three) fits in
+# 64 and not in 56
+_FUSED_VMEM = 96 * 2**20
+_FUSED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=_FUSED_VMEM)
 
 # the names of the flash forward's residuals: a checkpoint whose policy saves
 # them (``nn/models._remat_jit``) keeps ``out`` and ``lse`` to the backward,
@@ -609,6 +622,83 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
+                            dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                            ks_scr, *, scale, causal, s_valid, blk, g, nk,
+                            q_steps, masked, window=None, n_q_blocks: int = 0):
+    """Both backward sweeps as one: for each of a K/V head's ``g`` query
+    heads, K/V block ``ik`` fixed and the Q blocks streamed as in
+    ``_flash_bwd_dkv_kernel``, each live block's ``P`` and ``dS`` made once
+    for all three products.  ``dq_scr`` holds the query head's whole dQ,
+    ``dk_scr`` and ``dv_scr`` the K/V head's whole dK and dV, in float32:
+    dK/dV take their terms in the dk/dv sweep's order (query head, then Q
+    block) and go out after the group's last head; a Q block's dQ rows go
+    out at the step that adds their last term, into the output block the
+    index map names there."""
+    h = pl.program_id(1)  # the query head of the group
+    ik = pl.program_id(2)  # fixed K/V block
+    i = pl.program_id(3)  # sweeping Q blocks
+    iq = i if window is None else _first_live_q(ik, blk, blk, causal) + i
+    on_operand, on_scores = _split_scale(scale)
+
+    @pl.when((h == 0) & (ik == 0) & (i == 0))
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when((ik == 0) & (i == 0))
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(i == 0)
+    def _():
+        _score_operand(k_ref, ks_scr, on_operand)
+
+    q_lo, k_lo = iq * blk, ik * blk
+    rows = pl.ds(pl.multiple_of(q_lo, blk), blk)
+    keys = pl.ds(pl.multiple_of(k_lo, blk), blk)
+
+    def step(with_mask: bool):
+        p = _recompute_p(
+            q_ref[0], ks_scr[:], lse_ref[0, 0], scale=on_scores,
+            causal=causal, masked=with_mask, s_valid=s_valid, q_lo=q_lo,
+            k_lo=k_lo, blk_q=blk, blk_k=blk, window=window,
+        )
+        dv_scr[keys, :] += jax.lax.dot_general(  # Pᵀ · dOᵢ  (blk, dv)
+            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(  # dOᵢ · Vⱼᵀ  (blk, blk)
+            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - dd_ref[0, 0][:, None])  # unscaled: the scale goes on the sums
+        dk_scr[keys, :] += jax.lax.dot_general(  # dSᵀ · Qᵢ  (blk, d)
+            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_scr[rows, :] += jax.lax.dot_general(  # dS · Kⱼ  (blk, d)
+            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    kind = _block_kind(q_lo, k_lo, blk, blk, s_valid, causal, window)
+    if window is not None:
+        kind = tuple(flag & (iq < n_q_blocks) for flag in kind)
+    _on_live_blocks(step, kind, masked)
+
+    # Q block iq's last term: under causal the diagonal's (a row sees no key
+    # after its own, a window or not), else the last K/V block's
+    @pl.when(iq == ik if causal else ik == nk - 1)
+    def _():
+        dq_ref[0] = (dq_scr[rows, :] * scale).astype(dq_ref.dtype)
+
+    @pl.when((h == g - 1) & (i == q_steps - 1))
+    def _():
+        dk_ref[0] = (dk_scr[keys, :] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[keys, :].astype(dv_ref.dtype)
+
+
 def _row_dot(a, b):
     """Σ_d aᵢ ⊙ bᵢ in f32 as a ``(B, 1, S)`` row carrier."""
     return jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32),
@@ -716,6 +806,26 @@ def _pallas_gate(q, S: int, d: int, dv: Optional[int] = None):
         vmem = 4 * (3 * blk_q * d + 2 * blk_k * d + blk_q * blk_k + 2 * blk_q)
         use_pallas = vmem <= 12 * 2**20
     return use_pallas, max(blk_q, blk_k), platform
+
+
+def _fused_bwd_bytes(Sp: int, d: int, dv: int, blk: int,
+                     itemsize: int) -> int:
+    """VMEM of the fused backward at a padded length ``Sp`` and blocks of
+    ``blk``: a head's dQ, dK and dV (float32) and the staged K operand, the
+    blocks in and out double-buffered, and four ``(blk, blk)`` float32
+    score temporaries."""
+    resident = 4 * Sp * (2 * d + dv) + itemsize * blk * d
+    blocks = 2 * itemsize * blk * (5 * d + 3 * dv)
+    rows = 2 * 2 * 4 * 8 * blk  # lse and D, a row padded to 8 sublanes
+    return resident + blocks + rows + 4 * 4 * blk * blk
+
+
+def _fused_bwd_fits(Sp: int, d: int, dv: int, itemsize: int) -> bool:
+    """THE backward's path: the fused kernel where ``_fused_bwd_bytes`` fits
+    ``_FUSED_VMEM``, else the two sweeps (at d = 128 past S = 49,152).
+    Shapes alone decide it."""
+    blk, _ = _block_shape(Sp, max(d, dv), itemsize)
+    return _fused_bwd_bytes(Sp, d, dv, blk, itemsize) <= _FUSED_VMEM
 
 
 def _blocks_rect(Sq: int, Sk: int):
@@ -1009,8 +1119,11 @@ def flash_attention(q, k, v, causal: bool = False,
 # (b // hq)·hk + (b % hq) // g, so the g-fold K/V repeat that ``jnp.repeat``
 # would materialize in HBM never exists.  The dk/dv sweep runs the g query
 # heads of a K/V head's group through one accumulator (grid
-# (B·hk, nk, g·nq), block offset = sweep index mod nq).  Equal heads are the
-# case hq == hk (``_flash``): the row maps are then the identity.
+# (B·hk, nk, g·nq), block offset = sweep index mod nq).  The fused backward,
+# which takes the two sweeps' place wherever its VMEM budget holds, keeps a
+# K/V head's dK/dV in VMEM through its g query heads (grid (B·hk, g, nk,
+# nq)).  Equal heads are the case hq == hk (``_flash``): the row maps are
+# then the identity.
 #
 # The streamed side's block index is clamped to the sweep's nearest live
 # block (``_last_live_k``/``_first_live_q``): consecutive dead steps then
@@ -1027,6 +1140,11 @@ def flash_attention(q, k, v, causal: bool = False,
 def _gqa_kv_row(b, hq: int, hk: int):
     g = hq // hk
     return (b // hq) * hk + (b % hq) // g
+
+
+def _gqa_q_row(b, h, hq: int, hk: int):
+    """Query row of the ``h``-th head in K/V row ``b``'s group."""
+    return (b // hk) * hq + (b % hk) * (hq // hk) + h
 
 
 def _last_live_k(iq, blk_q: int, blk_k: int, s_valid: int, causal: bool):
@@ -1113,11 +1231,13 @@ def _flash_gqa_fwd_impl(q, k, v, causal: bool, scale: float, s_valid: int,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "s_valid", "hq", "hk", "interpret",
-                     "window"),
+                     "window", "fused"),
 )
 def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
                         s_valid: int, hq: int, hk: int, interpret: bool,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, fused: bool = False):
+    """``(dq, dk, dv)``: the fused kernel (``_flash_bwd_fused_kernel``) where
+    ``fused``, else the dq sweep and the dk/dv sweep."""
     BHq, Sp, d = q.shape
     BHk, dv = k.shape[0], v.shape[-1]
     g = hq // hk
@@ -1137,6 +1257,18 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     kblk = _streamed_k(blk_q, blk_k, s_valid, causal, window)
     first_q = functools.partial(_first_live_q, blk_q=blk_q, blk_k=blk_k,
                                 causal=causal)
+
+    def qblk(j, i):
+        if window is None:
+            return jnp.maximum(i % nq, first_q(j))
+        return jnp.minimum(first_q(j) + i % q_steps,
+                           _last_live_q(j, blk_q, blk_k, nq, window))
+
+    if fused:
+        return _bwd_fused_call(
+            q, k, v, do, lse, dd, qblk, causal=causal, scale=scale,
+            s_valid=s_valid, hq=hq, hk=hk, blk=blk_q, nk=nk, q_steps=q_steps,
+            masked=masked, window=window, interpret=interpret)
 
     # dq sweep: Q block fixed per middle grid index, K/V blocks stream
     # q and k are ``d`` wide, v and the output's cotangent ``dv``
@@ -1172,12 +1304,6 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     def qrow(b, i):
         return (b // hk) * hq + (b % hk) * g + i // q_steps
 
-    def qblk(j, i):
-        if window is None:
-            return jnp.maximum(i % nq, first_q(j))
-        return jnp.minimum(first_q(j) + i % q_steps,
-                           _last_live_q(j, blk_q, blk_k, nq, window))
-
     def qspec2(width):
         return pl.BlockSpec((1, blk_q, width),
                             lambda b, j, i: (qrow(b, i), qblk(j, i), 0))
@@ -1210,9 +1336,68 @@ def _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal: bool, scale: float,
     return dq, dk, dv
 
 
-# custom_vjp: jax.grad runs the Pallas backward kernels (dq sweep + dk/dv
-# sweep) instead of failing out of pallas_call's missing autodiff rule —
-# training keeps the flash memory profile
+def _bwd_fused_call(q, k, v, do, lse, dd, qblk, *, causal, scale, s_valid,
+                    hq, hk, blk, nk, q_steps, masked, window, interpret):
+    """The fused backward's ``pallas_call``: grid ``(B·H_kv, g, nk,
+    q_steps)``, the Q side's blocks as the dk/dv sweep names them
+    (``qblk``)."""
+    BHq, Sp, d = q.shape
+    BHk, dv = k.shape[0], v.shape[-1]
+    g = hq // hk
+    qrow = functools.partial(_gqa_q_row, hq=hq, hk=hk)
+
+    def qspec(width):
+        return pl.BlockSpec((1, blk, width),
+                            lambda b, h, j, i: (qrow(b, h), qblk(j, i), 0))
+
+    def kspec(width):
+        return pl.BlockSpec((1, blk, width), lambda b, h, j, i: (b, j, 0))
+
+    # dK/dV go out after the group's last head: block j then, block 0
+    # (not yet written) before
+    def dkspec(width):
+        return pl.BlockSpec((1, blk, width),
+                            lambda b, h, j, i: (b, jnp.where(h == g - 1, j, 0), 0))
+
+    # the Q block whose dQ this K/V block completes: the diagonal under
+    # causal, else each in turn in the last K/V block's sweep
+    def dq_blk(j, i):
+        return j if causal else jnp.where(j == nk - 1, i, 0)
+
+    rowspec = pl.BlockSpec((1, 1, blk),
+                           lambda b, h, j, i: (qrow(b, h), 0, qblk(j, i)))
+    return pl.pallas_call(
+        functools.partial(
+            _flash_bwd_fused_kernel, scale=scale, causal=causal,
+            s_valid=s_valid, blk=blk, g=g, nk=nk, q_steps=q_steps,
+            masked=masked, window=window, n_q_blocks=Sp // blk,
+        ),
+        grid=(BHk, g, nk, q_steps),
+        in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), rowspec, rowspec],
+        out_specs=[
+            pl.BlockSpec((1, blk, d),
+                         lambda b, h, j, i: (qrow(b, h), dq_blk(j, i), 0)),
+            dkspec(d), dkspec(dv),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BHq, Sp, d), q.dtype),
+            jax.ShapeDtypeStruct((BHk, Sp, d), k.dtype),
+            jax.ShapeDtypeStruct((BHk, Sp, dv), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((Sp, d), jnp.float32),  # the query head's dQ
+            pltpu.VMEM((Sp, d), jnp.float32),  # the K/V head's dK
+            pltpu.VMEM((Sp, dv), jnp.float32),  # and dV
+            pltpu.VMEM((blk, d), k.dtype),  # the score product's K operand
+        ],
+        compiler_params=_FUSED_PARAMS,
+        interpret=interpret,
+    )(q, k, v, do, lse, dd)
+
+
+# custom_vjp: jax.grad runs the Pallas backward kernels (the fused sweep, or
+# the dq sweep + the dk/dv sweep) instead of failing out of pallas_call's
+# missing autodiff rule — training keeps the flash memory profile
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_gqa(q, k, v, causal: bool, scale: float, s_valid: int,
                hq: int, hk: int, interpret: bool,
@@ -1234,8 +1419,11 @@ def _flash_gqa_fwd_rule(q, k, v, causal, scale, s_valid, hq, hk, interpret,
 def _flash_gqa_bwd_rule(causal, scale, s_valid, hq, hk, interpret, window,
                         res, do):
     q, k, v, out, lse = res
+    _, Sp, d = q.shape
+    fused = _fused_bwd_fits(Sp, d, v.shape[-1], q.dtype.itemsize)
+    path_counts["bwd_fused" if fused else "bwd_two_sweeps"] += 1
     return _flash_gqa_bwd_impl(q, k, v, out, lse, do, causal, scale, s_valid,
-                               hq, hk, interpret, window)
+                               hq, hk, interpret, window, fused=fused)
 
 
 _flash_gqa.defvjp(_flash_gqa_fwd_rule, _flash_gqa_bwd_rule)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from test_ops_kernels import _fa, _value_and_grads, blocks128  # noqa: F401  (the fixture: 128 x 128 blocks)
+from test_ops_kernels import BWD_PATHS, _fa, _value_and_grads, blocks128, take_path  # noqa: F401  (the fixture: 128 x 128 blocks)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURES = os.path.join(HERE, "fixtures_flash_window")
@@ -41,11 +41,13 @@ class TestWindowedKernels:
     # 128-wide blocks: a window smaller than a block, of one block exactly (the
     # sweep's first block then starves its last rows), between one and two,
     # of two, and larger than the sequence (no window at all)
+    @pytest.mark.parametrize("path", BWD_PATHS)
     @pytest.mark.parametrize("window", [1, 50, 128, 200, 256, 1000])
     @pytest.mark.parametrize("S", [512, 400])  # 400: padding crosses the last block
     @pytest.mark.parametrize("hq,hk", [(2, 2), (7, 1)], ids=["group1", "group7"])
-    def test_matches_dense_forward_and_backward(self, blocks128, hq, hk, S, window):
+    def test_matches_dense_forward_and_backward(self, blocks128, monkeypatch, hq, hk, S, window, path):
         fa = blocks128
+        take_path(fa, monkeypatch, path)
         q, k, v, w = _case(S, hq, hk, seed=S + window)
         before = dict(fa.path_counts)
         call = lambda q, k, v: fa.flash_attention_gqa(q, k, v, causal=True, window=window)  # noqa: E731
@@ -56,15 +58,44 @@ class TestWindowedKernels:
             assert bool(jnp.all(jnp.isfinite(a)))
             np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
-    def test_keys_and_values_of_different_widths_in_bfloat16(self, blocks128):
+    @pytest.mark.parametrize("path", BWD_PATHS)
+    @pytest.mark.parametrize("d,dv", [(24, 16), (48, 32)])
+    def test_keys_and_values_of_different_widths_in_bfloat16(self, blocks128, monkeypatch, d, dv, path):
         fa = blocks128
-        q, k, v, w = _case(384, 2, 2, d=24, dv=16, dtype=jnp.bfloat16, seed=3)
+        take_path(fa, monkeypatch, path)
+        q, k, v, w = _case(384, 2, 2, d=d, dv=dv, dtype=jnp.bfloat16, seed=3)
         call = lambda q, k, v: fa.flash_attention(q, k, v, causal=True, window=130)  # noqa: E731
         f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
         got = _value_and_grads(call, w, q, k, v)
         want = _value_and_grads(_dense(fa, 130, 384), w, f32(q), f32(k), f32(v))
         for a, b in zip(got, want):
             np.testing.assert_allclose(f32(a), b, atol=0.06, rtol=0.06)
+
+    @pytest.mark.parametrize("path", BWD_PATHS)
+    @pytest.mark.parametrize("hq,hk", [(2, 2), (4, 1)], ids=["group1", "group4"])
+    def test_a_window_of_four_blocks(self, blocks128, monkeypatch, hq, hk, path):
+        """SmallThinker's ratio of window to block (4,096 to 1,024) at
+        128-row blocks: a K/V block's sweep reaches five Q blocks, the last
+        four of them past its own.  A sequence of 1,024 is past the
+        interpreter's gate, so the kernels are called as the entry calls
+        them."""
+        fa = blocks128
+        take_path(fa, monkeypatch, path)
+        S, window = 1024, 512
+        assert fa._window_steps(S // 128, 128, 128, window) == 5
+        q, k, v, w = _case(S, hq, hk, seed=21)
+        flat = lambda t: t.reshape((-1,) + t.shape[2:])  # noqa: E731
+
+        def call(q, k, v):
+            out = fa._flash_gqa(flat(q), flat(k), flat(v), True, 16 ** -0.5, S, hq, hk, True, window)
+            return out.reshape(q.shape)
+
+        before = dict(fa.path_counts)
+        got = _value_and_grads(call, w, q, k, v)
+        assert fa.path_counts[f"bwd_{path}"] == before[f"bwd_{path}"] + 1
+        want = _value_and_grads(_dense(fa, window, S), w, q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
     def test_the_last_row_of_the_first_live_block_has_no_key(self, blocks128, monkeypatch):
         """Window 128 on 128-wide blocks: a Q block's sweep starts at the K/V
@@ -198,10 +229,12 @@ def test_without_a_window_the_kernels_lower_as_before(blocks128, name):
     """``window=None`` is a static branch that builds the kernels as they were
     at 57d3c79, instruction for instruction: the fixtures are the jaxprs of
     value and gradients that commit traced (source lines and addresses
-    stripped), and the two cells that run these kernels must not move.  From
-    PR 39 on they also hold the forward's two residuals' names
-    (``KEPT``: a ``name`` equation each, which lowers to nothing); without
-    those the jaxprs are 57d3c79's to the character."""
+    stripped), and the two cells that run these kernels must not move.  They
+    also hold the forward's two residuals' names (``KEPT``: a ``name``
+    equation each, which lowers to nothing) and the fused backward, one
+    kernel where the two sweeps were; with those names and with the two
+    sweeps (``_fused_bwd_fits`` False) the jaxprs were 57d3c79's to the
+    character when the fixtures were recorded again."""
     fa = blocks128
     call, shapes, kw = JAXPR_CASES[name]
     with gzip.open(os.path.join(FIXTURES, f"{name}.jaxpr.txt.gz"), "rt") as f:
@@ -211,25 +244,29 @@ def test_without_a_window_the_kernels_lower_as_before(blocks128, name):
     assert _jaxpr_text(fa, getattr(fa, call), shapes, window=8, **kw) != before
 
 
-def _lowers_for_the_tpu(call, *avals):
+def _lowers_for_the_tpu(call, *avals, kernels):
     """Pallas -> Mosaic lowering from the CPU host (``jax.export``), where block
-    shapes and index maps are validated: forward and both backward sweeps."""
+    shapes and index maps are validated: the forward and the backward's
+    kernels (one fused sweep, or the two)."""
     def value_and_grads(q, k, v):
         return jax.value_and_grad(lambda a, b, c: jnp.sum(call(a, b, c).astype(jnp.float32)), (0, 1, 2))(q, k, v)
 
     exported = jax.export.export(jax.jit(value_and_grads), platforms=["tpu"])(*avals)
-    assert exported.mlir_module().count("tpu_custom_call") >= 3
+    assert exported.mlir_module().count("tpu_custom_call") == kernels
 
 
+@pytest.mark.parametrize("path", BWD_PATHS)
 @pytest.mark.parametrize("S,window,dtype", [
     (16384, 4096, jnp.bfloat16),   # smallthinker_21b_a3b_train_1x16k: 28 query heads over 4, heads of 128
     (16384 - 24, 4096, jnp.bfloat16),  # the same with pad keys in the last block
     (2048, 700, jnp.float32),      # a window that no block divides
 ])
-def test_windowed_kernels_lower_for_the_tpu(S, window, dtype):
+def test_windowed_kernels_lower_for_the_tpu(monkeypatch, S, window, dtype, path):
     fa = _fa()
+    take_path(fa, monkeypatch, path)
     hq, hk, d = 28, 4, 128
     padded = -(-S // 1024) * 1024
     q = jax.ShapeDtypeStruct((hq, padded, d), dtype)
     kv = jax.ShapeDtypeStruct((hk, padded, d), dtype)
-    _lowers_for_the_tpu(lambda a, b, c: fa._flash_gqa(a, b, c, True, d ** -0.5, S, hq, hk, False, window), q, kv, kv)
+    _lowers_for_the_tpu(lambda a, b, c: fa._flash_gqa(a, b, c, True, d ** -0.5, S, hq, hk, False, window), q, kv, kv,
+                        kernels=2 if path == "fused" else 3)

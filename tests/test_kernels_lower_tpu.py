@@ -70,6 +70,27 @@ def test_gqa_lowers_at_the_training_cell_shape():
             q, kv, kv)
 
 
+@pytest.mark.parametrize("B,hq,hk,S,d,dv,window", [
+    (1, 32, 4, 32768, 128, 128, None),  # trinity_mini_26b_a3b_train_1x32k, the global layer
+    (1, 32, 4, 32768, 128, 128, 2048),  # and its windowed layers
+    (4, 16, 16, 8192, 192, 128, None),  # moonlight_16b_a3b_train_4x8k: latent attention, 192-wide keys
+], ids=["trinity_global", "trinity_window", "moonlight"])
+def test_fused_backward_lowers_at_the_training_cell_shapes(B, hq, hk, S, d, dv, window):
+    """The gradient's one fused kernel at the cells' shapes, bfloat16 and
+    causal: the forward and the backward are two Mosaic calls, and a head's
+    whole dQ, dK and dV are held in VMEM (48 MiB of float32 at Trinity's)."""
+    assert fa._fused_bwd_fits(S, d, dv, 2)
+    q = jax.ShapeDtypeStruct((B * hq, S, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((B * hk, S, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((B * hk, S, dv), jnp.bfloat16)
+    w = () if window is None else (window,)
+    before = fa.path_counts["bwd_fused"]
+    exported = jax.export.export(jax.jit(_vg(
+        lambda a, b, c: fa._flash_gqa(a, b, c, True, d**-0.5, S, hq, hk, False, *w))), platforms=["tpu"])(q, k, v)
+    assert exported.mlir_module().count("tpu_custom_call") == 2
+    assert fa.path_counts["bwd_fused"] == before + 1
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_kda_kernels_lower_at_the_training_cell_shape(monkeypatch, dtype):
     """``kimi_linear_48b_a3b_train_2x8k``: 2 sequences x 32 heads of 128,

@@ -316,13 +316,27 @@ def _value_and_grads(f, w, q, k, v):
     return (out, *grads)
 
 
+# the static-offset backward's two paths: one fused sweep wherever its VMEM
+# budget holds (every shape of these tests), or the dq and dk/dv sweeps
+BWD_PATHS = ["fused", "two_sweeps"]
+
+
+def take_path(fa, monkeypatch, path):
+    """Send the backward down ``path``: the two sweeps by a budget that no
+    shape fits."""
+    if path == "two_sweeps":
+        monkeypatch.setattr(fa, "_fused_bwd_fits", lambda *a: False)
+
+
 class TestFlashManyBlocks:
+    @pytest.mark.parametrize("path", BWD_PATHS)
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("S", [512, 400])  # 400: padding crosses the last column
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.parametrize("kind", ["mha", "gqa"])
-    def test_matches_dense(self, blocks128, kind, causal, S, dtype):
+    def test_matches_dense(self, blocks128, monkeypatch, kind, causal, S, dtype, path):
         fa = blocks128
+        take_path(fa, monkeypatch, path)
         d = 16
         q, k, v, w, call, dense = _attention_case(fa, kind, S, d, dtype, seed=S + causal)
         assert fa._block_census(512, S, 128, 128, causal) == {
@@ -332,18 +346,21 @@ class TestFlashManyBlocks:
             (False, 400): {"interior": 12, "edge": 4, "dead": 0},
         }[causal, S]
         assert fa._scale_folds(d**-0.5)
-        before = fa.path_counts["pallas"]
+        before = dict(fa.path_counts)
         got = _value_and_grads(lambda *o: call(*o, causal=causal), w, q, k, v)
-        assert fa.path_counts["pallas"] == before + 2  # the kernels, forward and backward
+        assert fa.path_counts["pallas"] == before["pallas"] + 2  # the kernels, forward and backward
+        assert fa.path_counts[f"bwd_{path}"] == before[f"bwd_{path}"] + 1
         want = _value_and_grads(lambda *o: dense(*o, causal, d**-0.5), w, q, k, v)
         tol = dict(rtol=1e-4, atol=1e-5) if dtype == "float32" else dict(rtol=5e-2, atol=5e-2)
         for g, r, what in zip(got, want, ("out", "dq", "dk", "dv")):
             assert g.dtype == q.dtype
             np.testing.assert_allclose(np.float32(g), np.float32(r), err_msg=what, **tol)
 
+    @pytest.mark.parametrize("path", BWD_PATHS)
     @pytest.mark.parametrize("how", ["d48", "explicit"])
-    def test_a_scale_that_is_no_power_of_two_stays_on_the_scores(self, blocks128, how):
+    def test_a_scale_that_is_no_power_of_two_stays_on_the_scores(self, blocks128, monkeypatch, how, path):
         fa = blocks128
+        take_path(fa, monkeypatch, path)
         d = 48 if how == "d48" else 16
         scale = d**-0.5 if how == "d48" else 0.3
         assert not fa._scale_folds(scale)
@@ -361,14 +378,17 @@ class TestFlashManyBlocks:
         assert fa._scale_folds(scale)
         assert not fa._scale_folds(scale * 1.5) and not fa._scale_folds(scale / 3)
 
+    @pytest.mark.parametrize("path", BWD_PATHS)
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("causal,s_valid", [(True, 512), (True, 400), (False, 400)])
-    def test_every_bit_as_before(self, blocks128, causal, s_valid, dtype):
+    def test_every_bit_as_before(self, blocks128, causal, s_valid, dtype, path):
         """Folding a power of two into an operand, scaling an accumulator
         instead of every ``ds``, and dropping guards that guard nothing are
         all exact: at equal block sizes the kernels give the bits that the
         arithmetic before PR 31 gave (written out below in ``jax.numpy``,
-        scale on the scores, mask and guards on every live block)."""
+        scale on the scores, mask and guards on every live block).  One
+        sweep adds each gradient's terms in the two sweeps' order, so it
+        gives their bits too."""
         import jax
         import jax.numpy as jnp
 
@@ -378,7 +398,7 @@ class TestFlashManyBlocks:
         q, k, v, do = (jnp.asarray(rng.normal(size=(1, Sp, d)), dtype) for _ in range(4))
         out, lse = fa._flash_gqa_fwd_impl(q, k, v, causal, scale, s_valid, 1, 1, True)
         dq, dk, dv = fa._flash_gqa_bwd_impl(q, k, v, out, lse, do, causal, scale, s_valid,
-                                            1, 1, True)
+                                            1, 1, True, fused=path == "fused")
         # jitted like the interpreted kernel: op by op, XLA's CPU backend
         # rounds a few of these expressions differently
         want = jax.jit(_as_before, static_argnums=(4, 5, 6, 7))(
@@ -386,6 +406,65 @@ class TestFlashManyBlocks:
         for g, r, what in zip((out[0], lse[0, 0], dq[0], dk[0], dv[0]), want,
                               ("out", "lse", "dq", "dk", "dv")):
             np.testing.assert_array_equal(np.float32(g), np.float32(r), err_msg=what)
+
+
+class TestBackwardPath:
+    """Which backward a static-offset call's gradient takes: the fused sweep
+    where its resident dQ, accumulators, blocks and scores fit the VMEM its
+    ``CompilerParams`` name, else the two sweeps; shapes alone decide."""
+
+    # each model cell's attention layers: S, d, dv
+    CELLS = {
+        "trinity_mini_26b_a3b_train_1x32k": (32768, 128, 128),
+        "smallthinker_21b_a3b_train_1x16k": (16384, 128, 128),
+        "moonlight_16b_a3b_train_4x8k": (8192, 192, 128),
+        "kimi_linear_48b_a3b_train_2x8k": (8192, 192, 128),
+        "lfm2_8b_a1b_train_4x8k": (8192, 64, 64),
+    }
+
+    @pytest.mark.parametrize("cell", list(CELLS))
+    def test_the_cells_shapes_fuse(self, cell):
+        fa = _fa()
+        S, d, dv = self.CELLS[cell]
+        assert fa._block_shape(S, max(d, dv), 2) == (1024, 1024)
+        assert fa._fused_bwd_fits(S, d, dv, 2)
+        # Trinity's layers the largest: a head's dQ, dK and dV are 48 MiB of the 68.4
+        assert fa._fused_bwd_bytes(S, d, dv, 1024, 2) <= 68.4 * 2**20
+
+    @pytest.mark.parametrize("S,d,itemsize,fits", [
+        (49152, 128, 2, True),     # 72 MiB of dQ, dK and dV: compiled for a v5e at the limit
+        (65536, 128, 2, False),    # 96 MiB of them
+        (131072, 128, 2, False),
+        (32768, 256, 2, False),    # 96 MiB, at 1024-row blocks
+        (65536, 128, 4, False),    # float32
+    ])
+    def test_past_the_budget_the_two_sweeps(self, S, d, itemsize, fits):
+        fa = _fa()
+        assert fa._fused_bwd_fits(S, d, d, itemsize) == fits
+        blk = fa._block_shape(S, d, itemsize)[0]
+        assert (fa._fused_bwd_bytes(S, d, d, blk, itemsize) <= fa._FUSED_VMEM) == fits
+
+    @pytest.mark.parametrize("path", BWD_PATHS)
+    @pytest.mark.parametrize("kind", ["mha", "gqa"])
+    def test_path_counts_count_each(self, blocks128, monkeypatch, kind, path):
+        import jax
+        import jax.numpy as jnp
+
+        fa = blocks128
+        take_path(fa, monkeypatch, path)
+        q, k, v, w, call, _ = _attention_case(fa, kind, 384, 16, "float32", seed=7)
+        before = dict(fa.path_counts)
+        text = str(jax.make_jaxpr(jax.grad(lambda *o: jnp.sum(call(*o, causal=True) * w),
+                                           argnums=(0, 1, 2)))(q, k, v))
+        assert {n: fa.path_counts[n] - before[n] for n in before} == {
+            "pallas": 1, "dense": 0, "kept": 1,
+            "bwd_fused": int(path == "fused"), "bwd_two_sweeps": int(path == "two_sweeps")}
+        # the forward kernel and one backward kernel, or two
+        assert text.count("pallas_call[") == (2 if path == "fused" else 3)
+        # a call without gradients counts no backward
+        call(q, k, v, causal=True)
+        assert fa.path_counts["bwd_fused"] + fa.path_counts["bwd_two_sweeps"] == \
+            before["bwd_fused"] + before["bwd_two_sweeps"] + 1
 
 
 def _as_before(q, k, v, do, causal, scale, s_valid, blk):

@@ -177,7 +177,9 @@ def test_without_a_gate_attention_lowers_as_before(name):
     """``gate=False`` is a static branch that builds the programs of e393382,
     instruction for instruction: the fixtures are the jaxprs that commit traced
     (source lines and addresses stripped), with the flash forward's residuals
-    named from PR 39 on (a ``name`` equation each, which lowers to nothing)."""
+    named (a ``name`` equation each, which lowers to nothing) and the flash
+    backward one fused kernel (with the two sweeps, ``_fused_bwd_fits``
+    False, they were e393382's with the names when recorded again)."""
     with gzip.open(os.path.join(FIXTURES, f"attention_{name}.jaxpr.txt.gz"), "rt") as f:
         before = f.read()
     assert ungated_jaxprs(name) == before
