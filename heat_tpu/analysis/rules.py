@@ -895,7 +895,7 @@ class TraceIdentityRule(Rule):
         "parallel/scheduler.py",  # mints per-job ids at submission
     )
     TRACE_KEYS = {"trace_id", "span_id", "parent_id"}
-    RECORDING_CALLS = {"span", "record_event", "record_dispatch", "traced"}
+    RECORDING_CALLS = {"span", "record_event", "traced"}
 
     def check(self, ctx: LintContext) -> Iterable[Finding]:
         if module_matches(ctx.path, self.SANCTIONED_MODULES):
@@ -1073,8 +1073,9 @@ class UnledgeredDeviceBufferRule(Rule):
       a plain *device* is not a mesh buffer and is not flagged.
 
     An enclosing function that itself registers the buffer with the
-    ledger (``memledger.register(...)`` / ``_MEMLEDGER.register(...)``)
-    is a sanctioned registrar — the optimizer's parameter placement does
+    ledger (``memledger.register(...)``, ``_hooks.memledger.register(...)``
+    or a local ``ml = _hooks.memledger`` and ``ml.register(...)``) is a
+    sanctioned registrar — the optimizer's parameter placement does
     exactly this — and is exempt."""
 
     code = "HT111"
@@ -1092,7 +1093,7 @@ class UnledgeredDeviceBufferRule(Rule):
         "utils/memledger.py",
     )
     SHARDING_MARKERS = {"sharding", "NamedSharding", "PositionalSharding"}
-    LEDGER_NAMES = {"memledger", "_memledger", "_MEMLEDGER", "_ml"}
+    LEDGER_NAMES = {"memledger", "_memledger", "ml", "_ml"}
 
     def _mentions_sharding(self, node: ast.AST) -> bool:
         for sub in ast.walk(node):

@@ -23,13 +23,18 @@ profiler's own answer — no flag here), each helper runs entry to return
 under ``ht.dispatch.<kind>`` (stat ``op``) and each cached program's call
 under ``ht.dispatch.launch``; with nothing recording an eager op pays two
 ``is_enabled()`` calls and one frame.  See design.md "Telemetry & metrics".
+
+Observers: every cached program is called in one place, ``_launch``, and
+every result passes ``_checked``.  The flight recorder, the memory ledger
+and the sanitizer reach them through their slots in ``_hooks``; disarmed,
+each costs one attribute load.  What the whole layer costs on the chip is
+``dispatch_py_ms_per_job`` in the benchmark's eager cell.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import time
 import warnings
 from typing import Callable, Optional, Tuple, Union
 
@@ -37,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import _cache, sanitation, types
+from . import _cache, _hooks, sanitation, types
 from .dndarray import DNDarray
 from .stride_tricks import broadcast_shape, sanitize_axis
 
@@ -48,58 +53,47 @@ __all__ = ["_local_op", "_binary_op", "_reduce_op", "_cum_op", "_program_op"]
 # program — numpy's in-place contract realized as XLA buffer aliasing
 _DONATE_T1 = contextvars.ContextVar("heat_tpu_donate_t1", default=False)
 
-# telemetry hot-path hook: ``utils.telemetry.enable()`` sets this to the
-# telemetry module and ``disable()`` clears it, so the disabled check on
-# every dispatch tail is ONE module-global load — no import, no call, no
-# flag indirection (the telemetry-off overhead contract, ISSUE 3)
-_TELEMETRY = None
-
-# runtime sanitizer hot-path hook (HEAT_TPU_CHECKS=1): ``core.sanitation.
-# enable_checks()`` sets this to the metadata-only validator and
-# ``disable_checks()`` clears it — same one-global-load disabled cost as
-# the telemetry hook.  When armed, every dispatch tail re-validates the
-# invariants the zero-copy fast paths assume (``DNDarray._from_parts``
-# skips ``__init__``'s enforcement).
-_CHECKS = None
-
-# flight-recorder hot-path hook (``utils.flightrec.enable()`` pokes the
-# module in, ``disable()`` clears it): armed, every cached dispatch appends
-# a minimal op record to the crash-durable ring — the "last healthy local
-# operation" context around the seq-stamped collectives.  Disabled cost:
-# the same one-module-global load as the two hooks above (the flightrec
-# overhead contract, gated by ``benchmarks/dispatch.py --flightrec-gate``).
-_FLIGHTREC = None
-
-# device-memory-ledger hot-path hook (``utils.memledger.enable()`` pokes
-# the module in, ``disable()`` clears it): armed, donated operands are
-# consumed and a RESOURCE_EXHAUSTED out of a dispatched program renders
-# the ledger dump into the flight ring before re-raising; the dispatch
-# OUTPUT registration itself rides ``DNDarray._from_parts`` (one lean
-# ``register_dispatch`` call — see the threshold coalescing note there).
-# Disabled cost: one module-global load (gated by
-# ``benchmarks/dispatch.py --memledger-gate``).
-_MEMLEDGER = None
-
 
 def _op_name(op) -> str:
     return getattr(op, "__name__", str(op))
 
 
-def _run_prog(tel, name: str, op, prog, args, cache_hit: bool):
-    """Run a cached dispatch executable when anything listens: under
-    ``ht.dispatch.launch`` while a profile records (``_cache.launch``), and
-    with a ``dispatch.<kind>`` leaf record in telemetry's ring, carrying the
-    op name and cache hit/miss, when telemetry is armed.  ``tel`` is the
-    caller's captured module reference or ``None`` — re-reading the
-    ``_TELEMETRY`` global here would race a concurrent ``disable()`` into an
-    AttributeError mid-op (record_dispatch itself re-checks the enabled
-    flag)."""
-    if tel is None:
-        return _cache.launch(prog, *args)
-    t0 = time.perf_counter()
-    out = _cache.launch(prog, *args)
-    tel.record_dispatch(name, t0, time.perf_counter(), _op_name(op), cache_hit)
-    return out
+def _checked(ret: DNDarray, where: str) -> DNDarray:
+    """``ret``, validated by the sanitizer (``HEAT_TPU_CHECKS=1``) when armed:
+    the zero-copy paths skip ``DNDarray.__init__``'s enforcement, so the
+    sanitizer re-checks here what they assume."""
+    chk = _hooks.checks
+    return ret if chk is None else chk(ret, where)
+
+
+def _launch(where: str, op, entry, args: tuple, device, comm, donated=None) -> DNDarray:
+    """Call a cached dispatch program and wrap its result: the one place the
+    five helpers launch one.  ``entry`` is ``cached_program``'s
+    ``(program, shape, heat dtype, split)``; ``where`` names the helper to
+    the observers (``dispatch.<kind>``).  While a profile records, the call
+    runs under ``ht.dispatch.launch``.  An armed memory ledger sees an
+    exception before it re-raises, and ``donated``, the in-place dunders'
+    left operand, once the program has really consumed it; an armed flight
+    recorder gets the op's name."""
+    prog, rshape, rdtype, rsplit = entry
+    try:
+        res = _cache.launch(prog, *args) if _cache.recording() else prog(*args)
+    except Exception as e:
+        ml = _hooks.memledger
+        if ml is not None:
+            ml.note_oom(e, where, None)
+        raise
+    fr = _hooks.flightrec
+    if fr is not None:
+        fr.record_dispatch(_op_name(op))
+    if donated is not None:
+        ml = _hooks.memledger
+        # is_deleted() is the runtime's own truth: the plan may have
+        # narrowed donation off (shape- or dtype-changing results), and a
+        # live buffer is never dropped from the ledger early
+        if ml is not None and donated.is_deleted():
+            ml.consume(donated)
+    return _checked(DNDarray._from_parts(res, rshape, rdtype, rsplit, device, comm), where)
 
 
 @contextlib.contextmanager
@@ -222,7 +216,7 @@ def _local(op, x, out, kwargs):
                 x.comm,
                 x.balanced,
             )
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.local.pad")
+            return _checked(ret, "dispatch.local.pad")
     comm = x.comm
     j = x._jarray
     if (
@@ -232,35 +226,19 @@ def _local(op, x, out, kwargs):
         and _cacheable(j)
         and _hashable(kw := tuple(sorted(kwargs.items())))
     ):
-        tel = _TELEMETRY
-        m0 = _cache._STATS["misses"] if tel is not None else 0
         entry = _cache.cached_program(
             comm,
             ("local", op, _sig(j), x.split, kw),
             lambda: _build_local(comm, op, j, x.split, kwargs),
         )
         if entry is not _SLOW:
-            prog, rshape, rdtype, rsplit = entry
-            try:
-                res = (
-                    prog(j)
-                    if tel is None and not _cache.recording()
-                    else _run_prog(tel, "dispatch.local", op, prog, (j,), _cache._STATS["misses"] == m0)
-                )
-            except Exception as e:
-                if _MEMLEDGER is not None:
-                    _MEMLEDGER.note_oom(e, "dispatch.local", None)
-                raise
-            if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(_op_name(op))
-            ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, x.device, comm)
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.local")
+            return _launch("dispatch.local", op, entry, (j,), x.device, comm)
     result = op(j, **kwargs)
     result = comm.shard(result, x.split if x.split is not None and x.split < result.ndim else None)
     if out is not None:
         sanitation.sanitize_out(out, result.shape, x.split, x.device)
         out._jarray = result.astype(out.dtype.jax_dtype())
-        return out if _CHECKS is None else _CHECKS(out, "dispatch.local.out")
+        return _checked(out, "dispatch.local.out")
     ret = DNDarray(
         result,
         tuple(result.shape),
@@ -270,7 +248,7 @@ def _local(op, x, out, kwargs):
         x.comm,
         x.balanced,
     )
-    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.local.general")
+    return _checked(ret, "dispatch.local.general")
 
 
 def _compile_tail(comm, compute, want_split, *js):
@@ -331,7 +309,7 @@ def _binary(op, t1, t2, out, where, fn_kwargs):
     # alignment and the result metadata, and holds the compiled executable.
     # Ineligible signatures (pads, mismatched splits, tracers) are
     # negative-cached as _SLOW and take the general path below.
-    if out is None and where is None and not fn_kwargs and not _FORCE_SLOW and _stable_op(op):
+    if out is None and where is None and not fn_kwargs and _stable_op(op):
         d1 = isinstance(t1, DNDarray)
         proto = t1 if d1 else t2 if isinstance(t2, DNDarray) else None
         if proto is not None:
@@ -346,45 +324,18 @@ def _binary(op, t1, t2, out, where, fn_kwargs):
                         isinstance(t2, DNDarray) and t1._parray is t2._parray
                     )  # one buffer may not be donated and read in one call
                 )
-                tel = _TELEMETRY
-                m0 = _cache._STATS["misses"] if tel is not None else 0
                 entry = _cache.cached_program(
                     comm,
                     ("binary", op, k1, k2, donate),
                     lambda: _plan_binary(op, t1, t2, donate, comm),
                 )
                 if entry is not _SLOW:
-                    prog, rshape, rdtype, rsplit = entry
-                    args = (
-                        t1._jarray if d1 else t1,
-                        t2._jarray if isinstance(t2, DNDarray) else t2,
+                    a1 = t1._jarray if d1 else t1
+                    a2 = t2._jarray if isinstance(t2, DNDarray) else t2
+                    return _launch(
+                        "dispatch.binary", op, entry, (a1, a2), proto.device, comm,
+                        donated=a1 if donate else None,
                     )
-                    try:
-                        res = (
-                            prog(*args)
-                            if tel is None and not _cache.recording()
-                            else _run_prog(
-                                tel, "dispatch.binary", op, prog, args,
-                                _cache._STATS["misses"] == m0,
-                            )
-                        )
-                    except Exception as e:
-                        if _MEMLEDGER is not None:
-                            _MEMLEDGER.note_oom(e, "dispatch.binary", None)
-                        raise
-                    if _FLIGHTREC is not None:
-                        _FLIGHTREC.record_dispatch(_op_name(op))
-                    if donate and _MEMLEDGER is not None and args[0].is_deleted():
-                        # the donated left operand's buffer is gone — but
-                        # only when the program REALLY consumed it: the plan
-                        # may have narrowed donation off (dtype/shape-changing
-                        # results), and is_deleted() is the runtime's own
-                        # truth, so a live buffer is never dropped early
-                        _MEMLEDGER.consume(args[0])
-                    ret = DNDarray._from_parts(
-                        res, rshape, rdtype, rsplit, proto.device, comm
-                    )
-                    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.binary")
 
     fn_kwargs = fn_kwargs or {}
     if not isinstance(t1, DNDarray) and not isinstance(t2, DNDarray):
@@ -455,7 +406,7 @@ def _binary(op, t1, t2, out, where, fn_kwargs):
                 comm,
                 True,
             )
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.binary.pad")
+            return _checked(ret, "dispatch.binary.pad")
 
     j1 = a1._jarray if isinstance(a1, DNDarray) else a1
     j2 = a2._jarray if isinstance(a2, DNDarray) else a2
@@ -471,7 +422,7 @@ def _binary(op, t1, t2, out, where, fn_kwargs):
             result = comm.shard(result, res_split)
         sanitation.sanitize_out(out, result.shape, res_split, device)
         out._jarray = result.astype(out.dtype.jax_dtype())
-        return out if _CHECKS is None else _CHECKS(out, "dispatch.binary.out")
+        return _checked(out, "dispatch.binary.out")
     if where is not None:
         w = where._jarray if isinstance(where, DNDarray) else jnp.asarray(where)
         result = comm.shard(jnp.where(w, result, jnp.zeros_like(result)), res_split)
@@ -484,17 +435,12 @@ def _binary(op, t1, t2, out, where, fn_kwargs):
         comm,
         True,
     )
-    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.binary.general")
+    return _checked(ret, "dispatch.binary.general")
 
 
 # negative-cache sentinel: this signature must take the general path
 # (lookups that find it count under cache_stats()["slow"], not as hits)
 _SLOW = _cache.SLOW
-
-# benchmarking hook (benchmarks/dispatch.py): True forces every _binary_op
-# through the general path — the seed's dispatch, preserved verbatim below —
-# so the cached-vs-seed comparison is measured in one process
-_FORCE_SLOW = False
 
 
 def _plan_desc(t, comm):
@@ -626,7 +572,7 @@ def _reduce(op, x, axis, keepdims, out, dtype, kwargs):
                     phys, tuple(phys.shape), types.canonical_heat_type(phys.dtype),
                     None, x.device, x.comm, True,
                 )
-                return ret if _CHECKS is None else _CHECKS(ret, "dispatch.reduce.pad")
+                return _checked(ret, "dispatch.reduce.pad")
             # split axis survives (still padded in phys): logical gshape shrinks
             gshape = list(phys.shape)
             gshape[new_split] -= x._pad
@@ -634,7 +580,7 @@ def _reduce(op, x, axis, keepdims, out, dtype, kwargs):
                 phys, tuple(gshape), types.canonical_heat_type(phys.dtype),
                 new_split, x.device, x.comm, True,
             )
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.reduce.pad-split")
+            return _checked(ret, "dispatch.reduce.pad-split")
 
     j = x._jarray
     axkey = axis if axis is None or isinstance(axis, int) else tuple(axis)
@@ -646,29 +592,13 @@ def _reduce(op, x, axis, keepdims, out, dtype, kwargs):
         and _hashable(kw := tuple(sorted(kwargs.items())))
     ):
         dkey = None if dtype is None else types.canonical_heat_type(dtype)
-        tel = _TELEMETRY
-        m0 = _cache._STATS["misses"] if tel is not None else 0
         entry = _cache.cached_program(
             x.comm,
             ("reduce", op, _sig(j), axkey, keepdims, dkey, new_split, kw),
             lambda: _build_reduce(x.comm, op, j, axis, keepdims, dkey, new_split, kwargs),
         )
         if entry is not _SLOW:
-            prog, rshape, rdtype, rsplit = entry
-            try:
-                res = (
-                    prog(j)
-                    if tel is None and not _cache.recording()
-                    else _run_prog(tel, "dispatch.reduce", op, prog, (j,), _cache._STATS["misses"] == m0)
-                )
-            except Exception as e:
-                if _MEMLEDGER is not None:
-                    _MEMLEDGER.note_oom(e, "dispatch.reduce", None)
-                raise
-            if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(_op_name(op))
-            ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, x.device, x.comm)
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.reduce")
+            return _launch("dispatch.reduce", op, entry, (j,), x.device, x.comm)
     result = op(j, axis=axis, keepdims=keepdims, **kwargs)
     if dtype is not None:
         result = result.astype(types.canonical_heat_type(dtype).jax_dtype())
@@ -678,7 +608,7 @@ def _reduce(op, x, axis, keepdims, out, dtype, kwargs):
     if out is not None:
         sanitation.sanitize_out(out, result.shape, new_split, x.device)
         out._jarray = result.astype(out.dtype.jax_dtype())
-        return out if _CHECKS is None else _CHECKS(out, "dispatch.reduce.out")
+        return _checked(out, "dispatch.reduce.out")
     ret = DNDarray(
         result,
         tuple(result.shape),
@@ -688,7 +618,7 @@ def _reduce(op, x, axis, keepdims, out, dtype, kwargs):
         x.comm,
         True,
     )
-    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.reduce.general")
+    return _checked(ret, "dispatch.reduce.general")
 
 
 def _build_reduce(comm, op, j, axis, keepdims, dtype, new_split, kwargs):
@@ -731,34 +661,18 @@ def _cum(op, x, axis, dtype, out):
                 phys, x.shape, types.canonical_heat_type(phys.dtype),
                 x.split, x.device, x.comm, True,
             )
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.cum.pad")
+            return _checked(ret, "dispatch.cum.pad")
     j = x._jarray
     split = None if axis is None else x.split
     if out is None and not x._pad and _stable_op(op) and _cacheable(j):
         dkey = None if dtype is None else types.canonical_heat_type(dtype)
-        tel = _TELEMETRY
-        m0 = _cache._STATS["misses"] if tel is not None else 0
         entry = _cache.cached_program(
             x.comm,
             ("cum", op, _sig(j), axis, dkey, split),
             lambda: _build_cum(x.comm, op, j, axis, dkey, split),
         )
         if entry is not _SLOW:
-            prog, rshape, rdtype, rsplit = entry
-            try:
-                res = (
-                    prog(j)
-                    if tel is None and not _cache.recording()
-                    else _run_prog(tel, "dispatch.cum", op, prog, (j,), _cache._STATS["misses"] == m0)
-                )
-            except Exception as e:
-                if _MEMLEDGER is not None:
-                    _MEMLEDGER.note_oom(e, "dispatch.cum", None)
-                raise
-            if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(_op_name(op))
-            ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, x.device, x.comm)
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.cum")
+            return _launch("dispatch.cum", op, entry, (j,), x.device, x.comm)
     if axis is None:
         # numpy semantics: flatten
         flat = j.reshape(-1)
@@ -771,7 +685,7 @@ def _cum(op, x, axis, dtype, out):
     if out is not None:
         sanitation.sanitize_out(out, result.shape, split, x.device)
         out._jarray = result.astype(out.dtype.jax_dtype())
-        return out if _CHECKS is None else _CHECKS(out, "dispatch.cum.out")
+        return _checked(out, "dispatch.cum.out")
     ret = DNDarray(
         result,
         tuple(result.shape),
@@ -781,7 +695,7 @@ def _cum(op, x, axis, dtype, out):
         x.comm,
         True,
     )
-    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.cum.general")
+    return _checked(ret, "dispatch.cum.general")
 
 
 def _build_cum(comm, op, j, axis, dtype, split):
@@ -817,29 +731,13 @@ def _program(compute, operands, split, static):
     args = tuple(t._jarray if isinstance(t, DNDarray) else t for t in operands)
     descs = tuple(_plan_desc(t, comm) for t in operands)
     if None not in descs:
-        tel = _TELEMETRY
-        m0 = _cache._STATS["misses"] if tel is not None else 0
         entry = _cache.cached_program(
             comm,
             ("program", compute, descs, split, static),
             lambda: _build_program(comm, compute, operands, args, split, static),
         )
         if entry is not _SLOW:
-            prog, rshape, rdtype, rsplit = entry
-            try:
-                res = (
-                    prog(*args)
-                    if tel is None and not _cache.recording()
-                    else _run_prog(tel, "dispatch.program", compute, prog, args, _cache._STATS["misses"] == m0)
-                )
-            except Exception as e:
-                if _MEMLEDGER is not None:
-                    _MEMLEDGER.note_oom(e, "dispatch.program", None)
-                raise
-            if _FLIGHTREC is not None:
-                _FLIGHTREC.record_dispatch(_op_name(compute))
-            ret = DNDarray._from_parts(res, rshape, rdtype, rsplit, proto.device, comm)
-            return ret if _CHECKS is None else _CHECKS(ret, "dispatch.program")
+            return _launch("dispatch.program", compute, entry, args, proto.device, comm)
     result = compute(*args, *static)
     if split is not None and split >= result.ndim:
         split = None
@@ -853,38 +751,10 @@ def _program(compute, operands, split, static):
         comm,
         True,
     )
-    return ret if _CHECKS is None else _CHECKS(ret, "dispatch.program.general")
+    return _checked(ret, "dispatch.program.general")
 
 
 def _build_program(comm, compute, operands, args, split, static):
     if any(isinstance(t, DNDarray) and t._pad for t in operands):
         return _SLOW  # padded operands: compute sees the logical arrays
     return _compile_tail(comm, lambda *a: compute(*a, *static), split, *args)
-
-
-# telemetry may have been armed before this module finished importing
-# (HEAT_TPU_TELEMETRY=1 enables at utils import time, and import order
-# depends on the entry point) — pick the flag up here instead of missing it
-import sys as _sys  # noqa: E402
-
-_t = _sys.modules.get("heat_tpu.utils.telemetry")
-if _t is not None and _t._ENABLED:
-    _TELEMETRY = _t
-# same race for the flight recorder (HEAT_TPU_FLIGHTREC_DIR arms at
-# utils.flightrec import time): re-read the flag now that the body is done
-_fr = _sys.modules.get("heat_tpu.utils.flightrec")
-if _fr is not None and _fr.enabled():
-    _FLIGHTREC = _fr
-# same race for the memory ledger (HEAT_TPU_MEMLEDGER=1 arms at
-# utils.memledger import time)
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and _ml.enabled():
-    _MEMLEDGER = _ml
-del _sys, _t, _fr, _ml
-
-# same race for the sanitizer: HEAT_TPU_CHECKS=1 arms at core.sanitation
-# import time, which runs DURING this module's import (sanitation is imported
-# above) — its poke hit the half-initialized module and the `_CHECKS = None`
-# line then clobbered it, so re-read the flag now that the body is done
-if sanitation.checks_enabled():
-    _CHECKS = sanitation.validate_dispatch

@@ -56,17 +56,10 @@ import os
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
+from . import _hooks
 from ._cache import launch
 from .redistribution import parse_budget
 
-# device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
-# in): the executors register every in-flight bucket average (category
-# ``transient``), fire the ``mem.alloc`` fault site ahead of each bucket's
-# staging, and consume each bucket the moment its blend/update dispatched —
-# so ``mem.live_bytes`` observes the budget + one-bucket pipeline contract
-# FROM INSIDE.  Disabled cost: one module-global load per sync.  Module
-# bottom re-arms.
-_MEMLEDGER = None
 
 __all__ = [
     "GradBucketPlan",
@@ -248,7 +241,7 @@ def _await_bucket(arrs, what: str = "comm.allreduce") -> None:
 
 
 def _ledger_dispatch(bucket_bytes: int, avg_leaves) -> None:
-    ml = _MEMLEDGER
+    ml = _hooks.memledger
     if ml is None:
         return
     # the mem.alloc fault site, per bucket: chaos CI injects deterministic
@@ -263,7 +256,7 @@ def _ledger_dispatch(bucket_bytes: int, avg_leaves) -> None:
 
 
 def _ledger_consume(avg_leaves) -> None:
-    ml = _MEMLEDGER
+    ml = _hooks.memledger
     if ml is None:
         return
     for a in avg_leaves:
@@ -612,12 +605,3 @@ def bucketed_grad_allreduce(
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-# the memory ledger may have been env-armed (HEAT_TPU_MEMLEDGER=1) while
-# this module was still importing — re-read the flag now (defensive
-# module-bottom re-arm, the established hot-path-hook pattern)
-import sys as _sys  # noqa: E402
-
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and getattr(_ml, "enabled", lambda: False)():
-    _MEMLEDGER = _ml
-del _sys, _ml

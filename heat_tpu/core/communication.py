@@ -40,7 +40,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from . import devices
+from . import _hooks, devices
 from ._cache import cached_program, launch
 
 __all__ = [
@@ -61,31 +61,13 @@ _TELEMETRY_MOD = None
 # dispatch hot path
 _HEALTH_MOD = None
 
-# runtime sanitizer hook (HEAT_TPU_CHECKS=1): ``core.sanitation.
-# enable_checks()`` points this at ``sanitation.check_placement`` so every
-# eager resplit verifies the produced array actually carries the canonical
-# sharding of its target split (metadata-only: sharding objects, no value
-# reads).  Disabled cost: one module-global load per resplit.  This module
-# currently loads before sanitation (sanitation → dndarray → here), so the
-# env-arming poke lands after this line runs — but that ordering is
-# transitive and fragile, so the module bottom re-arms defensively like
-# ``_operations`` does.
-_RESPLIT_CHECK = None
-
-# flight-recorder hook (``utils.flightrec.enable()`` pokes the module in,
-# ``disable()`` clears it): every staged collective is seq-stamped at the
-# ``_account_bytes`` choke point below.  Disabled cost: one module-global
-# load at staging time.  Module bottom re-arms against import-order races
-# exactly like the two hooks above.
-_FLIGHTREC = None
-
-# device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
-# in): resplit outputs are registration choke points, the ``mem.alloc``
-# fault site fires ahead of each transfer's allocation, donated sources
-# are consumed, and a RESOURCE_EXHAUSTED out of the transfer renders the
-# ledger dump into the flight ring before re-raising.  Disabled cost: one
-# module-global load per resplit.  Module bottom re-arms.
-_MEMLEDGER = None
+# The observers read here, through their slots in ``core._hooks``: the
+# sanitizer checks every eager resplit's output for the canonical sharding
+# of its target split (``resplit_check``); the flight recorder seq-stamps
+# every staged collective at ``_account_bytes`` (``flightrec``); the memory
+# ledger registers resplit outputs, fires the ``mem.alloc`` fault site
+# ahead of each transfer, consumes donated sources and dumps itself into
+# the flight ring on an allocation failure (``memledger``).
 
 
 def _telemetry():
@@ -522,7 +504,7 @@ class Communication:
             tiles=1,
             path="device_put" if prog is None else "program",
         ):
-            ml = _MEMLEDGER
+            ml = _hooks.memledger
             src_cat = ml.category_of(array) if ml is not None else None
             try:
                 if ml is not None:
@@ -549,8 +531,9 @@ class Communication:
                 # the output inherits the source's category (a resplit moves
                 # a buffer, it does not change what the buffer IS)
                 ml.register(out, op="resplit", site="resplit", category=src_cat)
-            if _RESPLIT_CHECK is not None:
-                _RESPLIT_CHECK(out, self, split, where="comm.resplit")
+            chk = _hooks.resplit_check
+            if chk is not None:
+                chk(out, self, split, where="comm.resplit")
             return out
 
     def resplit_tiled(
@@ -593,7 +576,7 @@ class Communication:
             tile_axis=plan.tile_axis,
             budget=plan.budget,
         ):
-            ml = _MEMLEDGER
+            ml = _hooks.memledger
             src_cat = ml.category_of(array) if ml is not None else None
             try:
                 out = _redist.execute_plan(self, array, plan, donate=donate)
@@ -611,8 +594,9 @@ class Communication:
                     out, op="resplit",
                     category=src_cat or "activation", site="resplit",
                 )
-            if _RESPLIT_CHECK is not None:
-                _RESPLIT_CHECK(out, self, split, where="comm.resplit_tiled")
+            chk = _hooks.resplit_check
+            if chk is not None:
+                chk(out, self, split, where="comm.resplit_tiled")
             return out
 
     def _already_placed(self, array, split: Optional[int]) -> bool:
@@ -725,8 +709,9 @@ class Communication:
         injected (or suffered) at staging leaves the collective it hung on
         as the rank's last ring record — "stuck AT seq N op X", which is
         exactly what ``scripts/postmortem.py`` names."""
-        if _FLIGHTREC is not None:
-            _FLIGHTREC.record_collective(name, wire_bytes, x, src_split, dst_split)
+        fr = _hooks.flightrec
+        if fr is not None:
+            fr.record_collective(name, wire_bytes, x, src_split, dst_split)
         from ..utils import faults as _flt  # lazy: core imports before utils
 
         hlth = _health()
@@ -1079,31 +1064,3 @@ def __getattr__(name):
 
         return Communication(Mesh(np.asarray(jax.devices()[:1]), ("x",)), "x")
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
-# the sanitizer may have been armed while this module was still importing
-# (if any import path ever makes sanitation load first, its poke would hit
-# the half-initialized module and the `_RESPLIT_CHECK = None` line above
-# would clobber it) — re-read the flag now that the body is done, same
-# defensive pattern as core._operations
-import sys as _sys  # noqa: E402
-
-# getattr default: in the hypothetical sanitation-loads-first ordering,
-# sanitation would be MID-import here (this import triggered by its own
-# top-of-module imports) and checks_enabled not yet defined — treat that as
-# "not armed"; sanitation's own env-arming poke runs once it finishes
-_san = _sys.modules.get("heat_tpu.core.sanitation")
-if _san is not None and getattr(_san, "checks_enabled", lambda: False)():
-    _RESPLIT_CHECK = _san.check_placement
-# same defensive re-arm for the flight recorder: if utils.flightrec was
-# env-armed before this module finished importing, its poke hit the
-# half-initialized module and the `_FLIGHTREC = None` line clobbered it
-_fr = _sys.modules.get("heat_tpu.utils.flightrec")
-if _fr is not None and getattr(_fr, "enabled", lambda: False)():
-    _FLIGHTREC = _fr
-# and for the memory ledger (HEAT_TPU_MEMLEDGER=1 arms at utils.memledger
-# import time, which may precede or follow this module)
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and getattr(_ml, "enabled", lambda: False)():
-    _MEMLEDGER = _ml
-del _sys, _san, _fr, _ml

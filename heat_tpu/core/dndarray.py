@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import types
+from . import _hooks, types
 from .communication import Communication
 from .devices import Device
 from .stride_tricks import sanitize_axis
@@ -37,14 +37,6 @@ from .stride_tricks import sanitize_axis
 __all__ = ["DNDarray"]
 
 Scalar = Union[int, float, bool, complex]
-
-# device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
-# in, ``disable()`` clears it): ``_from_parts`` is the zero-copy wrap every
-# cached dispatch output and linalg fast path passes through, so it is a
-# registration choke point of the ledger.  Disabled cost: one module-global
-# load (the telemetry-hook pattern; module bottom re-arms against
-# import-order races).
-_MEMLEDGER = None
 
 
 class LocalIndex:
@@ -142,12 +134,13 @@ class DNDarray:
         self._DNDarray__pad = 0
         self._DNDarray__unpadded = None
         self._DNDarray__array = array
-        if _MEMLEDGER is not None:
+        ml = _hooks.memledger
+        if ml is not None:
             # ledger choke point, hot-tier recorder: one lean call —
             # under-threshold buffers coalesce into a counter, buffers of
             # consequence get the full provenance entry (op name resolved
             # by frame peek: the public wrapper above the dispatch tail)
-            _MEMLEDGER.register_dispatch(array)
+            ml.register_dispatch(array)
         return self
 
     @staticmethod
@@ -777,12 +770,3 @@ def _dnd_unflatten(aux, children):
 
 jax.tree_util.register_pytree_node(DNDarray, _dnd_flatten, _dnd_unflatten)
 
-# the memory ledger may have been env-armed (HEAT_TPU_MEMLEDGER=1) while
-# this module was still importing — re-read the flag now, the defensive
-# module-bottom pattern every hot-path hook here follows
-import sys as _sys  # noqa: E402
-
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and _ml.enabled():
-    _MEMLEDGER = _ml
-del _sys, _ml

@@ -16,16 +16,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import devices, sanitation, types
+from . import _hooks, devices, sanitation, types
 from .communication import Communication, sanitize_comm
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis, sanitize_shape
 
-# device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
-# in): ``_finalize``/``_filled`` are where every factory's buffer becomes
-# live, so they are registration choke points.  Disabled cost: one
-# module-global load (telemetry-hook pattern; module bottom re-arms).
-_MEMLEDGER = None
 
 __all__ = [
     "arange",
@@ -69,11 +64,12 @@ def _finalize(
     dtype = types.canonical_heat_type(jarr.dtype)
     jarr = comm.shard(jarr, split)
     ret = DNDarray(jarr, tuple(jarr.shape), dtype, split, device, comm, True)
-    if _MEMLEDGER is not None:
+    ml = _hooks.memledger
+    if ml is not None:
         # ledger choke point: op=None -> the ledger's frame walk names the
         # public factory up-stack (arange/linspace/eye/..., skipping
         # comprehension frames — meshgrid/ix_ call from list comps)
-        _MEMLEDGER.register(ret._parray, op=None, site="factory")
+        ml.register(ret._parray, op=None, site="factory")
     # factory boundary of the runtime sanitizer (HEAT_TPU_CHECKS=1):
     # no-op unless armed, metadata-only when armed
     return sanitation.check(ret, "factory")
@@ -137,8 +133,9 @@ def _filled(shape, value, dtype, split, device, comm, like=None) -> DNDarray:
     except (TypeError, ValueError):
         jarr = comm_s.shard(jnp.full(shape, value, dtype=jdt), split_s)
     ret = DNDarray(jarr, shape, dtype, split_s, devices.sanitize_device(device), comm_s, True)
-    if _MEMLEDGER is not None:
-        _MEMLEDGER.register(ret._parray, op=None, site="factory")
+    ml = _hooks.memledger
+    if ml is not None:
+        ml.register(ret._parray, op=None, site="factory")
     return ret
 
 
@@ -450,16 +447,6 @@ def kaiser(M: int, beta: float) -> DNDarray:
     jarr = jnp.kaiser(int(M), beta)
     return _finalize(jarr, None, None, None, types.canonical_heat_type(jarr.dtype))
 
-
-# the memory ledger may have been env-armed (HEAT_TPU_MEMLEDGER=1) while
-# this module was still importing — re-read the flag now (defensive
-# module-bottom re-arm, same pattern as _operations/communication)
-import sys as _sys  # noqa: E402
-
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and _ml.enabled():
-    _MEMLEDGER = _ml
-del _sys, _ml
 
 __all__ += [
     "bartlett",

@@ -21,16 +21,11 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from . import devices, types
+from . import _hooks, devices, types
 from .communication import sanitize_comm
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_shape
 
-# device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
-# in): ``_generate`` mints every random factory's buffer, so it is a
-# registration choke point like ``factories._finalize``.  Disabled cost:
-# one module-global load (module bottom re-arms).
-_MEMLEDGER = None
 
 __all__ = [
     "derive_seed",
@@ -131,10 +126,11 @@ def _generate(sampler, shape, dtype, split, device, comm, **kw) -> DNDarray:
         jarr = sampler(key, shape, dtype=dtype.jax_dtype(), **kw)
         jarr = comm.shard(jarr, split)
     ret = DNDarray(jarr, shape, dtype, split, device, comm, True)
-    if _MEMLEDGER is not None:
+    ml = _hooks.memledger
+    if ml is not None:
         # ledger choke point: op=None -> the ledger's frame walk names the
         # public factory up-stack (rand/randn/randint/normal/...)
-        _MEMLEDGER.register(ret._parray, op=None, site="factory")
+        ml.register(ret._parray, op=None, site="factory")
     return ret
 
 
@@ -228,12 +224,3 @@ def randperm(n: int, dtype=types.int32, split=None, device=None, comm=None) -> D
 seed()
 
 
-# the memory ledger may have been env-armed (HEAT_TPU_MEMLEDGER=1) while
-# this module was still importing — re-read the flag now (defensive
-# module-bottom re-arm, the established hot-path-hook pattern)
-import sys as _sys  # noqa: E402
-
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and getattr(_ml, "enabled", lambda: False)():
-    _MEMLEDGER = _ml
-del _sys, _ml

@@ -5,9 +5,7 @@ reference framework's signature data movement (SURVEY §3.3).  The monolithic
 realization — one :func:`identity_program` with the target sharding, lowered
 by XLA to a single all-to-all — materializes source and destination WHOLE:
 peak memory is ~2× the array plus collective staging, and donation recovers
-almost nothing
-because the transfer itself holds both copies (``BENCH_DISPATCH.json``:
-in-place resplit peaked at 751 MB vs 774 MB for the copy path).  Following
+almost nothing because the transfer itself holds both copies.  Following
 "Memory-efficient array redistribution through portable collective
 communication" (arXiv 2112.01075), any split→split transition decomposes into
 a *sequence of tiled collectives* with bounded peak memory.  This module is
@@ -32,10 +30,10 @@ that decomposition:
   buffer is additionally ``delete()``-ed the moment the last tile has been
   sliced out of it.
 
-**Peak-memory model** (documented contract, gated by ``benchmarks/dispatch.py
---resplit-gate``): beyond source + destination, the transient working set is
-at most ``budget + one tile`` (one tile staged out of the source plus its
-resharded copy in flight).  The monolithic path's transient is O(array).
+**Peak-memory model** (documented contract, which the memory ledger observes
+from inside in ``tests/test_memledger.py``): beyond source + destination, the
+transient working set is at most ``budget + one tile`` (one tile staged out
+of the source plus its resharded copy in flight).  The monolithic path's transient is O(array).
 
 **Budget semantics**: ``memory_budget`` bounds the bytes MOVED PER STEP.  The
 resolution order is: explicit ``memory_budget=`` kwarg → process-wide default
@@ -61,14 +59,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-# device-memory-ledger hook (``utils.memledger.enable()`` pokes the module
-# in): the streaming executor registers every staged tile (category
-# ``transient``), fires the ``mem.alloc`` fault site ahead of each tile's
-# allocation, consumes donated buffers at donation, and transfers the
-# aliased accumulator entry — so ``mem.live_bytes`` observes the
-# budget + one-tile transient contract FROM INSIDE.  Disabled cost: one
-# module-global load per plan.  Module bottom re-arms.
-_MEMLEDGER = None
+from . import _hooks
 
 __all__ = [
     "ResplitPlan",
@@ -386,7 +377,7 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
 
     from ..utils import profiler as _prof
 
-    ml = _MEMLEDGER
+    ml = _hooks.memledger
     out = launch(_program("init", 0, _build_init))
     if ml is not None:
         # the preallocated destination: a transient until the finished plan
@@ -433,7 +424,7 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
             # in-flight staged tile in the dump.  The ledger briefly holds
             # both tile stages — still within budget + one tile whenever a
             # tile fits the budget (the floor-at-one-slice case overcounts
-            # transiently; the RSS gate owns that bound physically).
+            # transiently).
             ml.consume(staged)
             ml.register(tile, op="resplit.tile", site="resplit.tile")
         prev = out
@@ -456,12 +447,3 @@ def execute_plan(comm, array, plan: ResplitPlan, donate: bool = False):
     return out
 
 
-# the memory ledger may have been env-armed (HEAT_TPU_MEMLEDGER=1) while
-# this module was still importing — re-read the flag now (defensive
-# module-bottom re-arm, the established hot-path-hook pattern)
-import sys as _sys  # noqa: E402
-
-_ml = _sys.modules.get("heat_tpu.utils.memledger")
-if _ml is not None and getattr(_ml, "enabled", lambda: False)():
-    _MEMLEDGER = _ml
-del _sys, _ml

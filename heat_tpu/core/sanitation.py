@@ -12,7 +12,6 @@ Value-dependent validation belongs behind explicit materialization points
 from __future__ import annotations
 
 import os
-import sys
 import warnings
 import zlib
 from typing import Optional, Sequence, Union
@@ -21,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import types
+from . import _hooks, types
 from .dndarray import DNDarray
 
 __all__ = [
@@ -149,10 +148,9 @@ def scalar_to_1d(x: DNDarray) -> DNDarray:
 # array data — so arming the sanitizer cannot introduce a host sync.
 #
 # Arming: ``sanitation.enable_checks()`` in-process, or HEAT_TPU_CHECKS=1
-# in the environment (checked once at import).  Like telemetry, the
-# disabled cost at the dispatch tails is ONE module-global load:
-# enable/disable poke ``_operations._CHECKS`` and
-# ``communication._RESPLIT_CHECK`` directly.
+# in the environment (checked once at import).  enable/disable set the
+# ``checks`` and ``resplit_check`` slots of ``core._hooks``, which the
+# dispatch tails and the resplit boundary read: disarmed, one attribute load.
 # ---------------------------------------------------------------------- #
 
 _CHECKS_ENABLED = False
@@ -166,29 +164,19 @@ def checks_enabled() -> bool:
     return _CHECKS_ENABLED
 
 
-def _poke_hooks(on: bool) -> None:
-    """Arm/disarm the hot-path hooks: the dispatch tails and the resplit
-    boundary read ONE module global each, so the disabled overhead stays at
-    a single load (the telemetry-hook pattern, ISSUE 3)."""
-    ops = sys.modules.get("heat_tpu.core._operations")
-    if ops is not None:
-        ops._CHECKS = validate_dispatch if on else None
-    com = sys.modules.get("heat_tpu.core.communication")
-    if com is not None:
-        com._RESPLIT_CHECK = check_placement if on else None
-
-
 def enable_checks() -> None:
     """Arm the runtime metadata sanitizer (equivalent: HEAT_TPU_CHECKS=1)."""
     global _CHECKS_ENABLED
     _CHECKS_ENABLED = True
-    _poke_hooks(True)
+    _hooks.checks = validate_dispatch
+    _hooks.resplit_check = check_placement
 
 
 def disable_checks() -> None:
     global _CHECKS_ENABLED
     _CHECKS_ENABLED = False
-    _poke_hooks(False)
+    _hooks.checks = None
+    _hooks.resplit_check = None
 
 
 def _is_tracer(arr) -> bool:
@@ -261,7 +249,7 @@ def validate_metadata(x, where: str = "") -> DNDarray:
 
 
 def validate_dispatch(x, where: str = "") -> DNDarray:
-    """Dispatch-tail hook target (``_operations._CHECKS``)."""
+    """Dispatch-tail hook target (the ``checks`` slot of ``core._hooks``)."""
     return validate_metadata(x, where)
 
 
@@ -275,8 +263,8 @@ def check(x, where: str = "") -> DNDarray:
 
 def check_placement(array, comm, split: Optional[int], where: str = ""):
     """Raise unless a concrete array carries the canonical sharding of
-    ``split`` over ``comm`` (resplit-boundary hook target,
-    ``communication._RESPLIT_CHECK``).  Tracers and ragged extents are
+    ``split`` over ``comm`` (resplit-boundary hook target, the
+    ``resplit_check`` slot of ``core._hooks``).  Tracers and ragged extents are
     skipped — their placement is legitimately not the canonical one.
     Returns ``array``."""
     if _is_tracer(array) or not isinstance(array, jax.Array):
@@ -327,7 +315,6 @@ def assert_cross_rank_consistent(x, tag: str = "") -> DNDarray:
     return x
 
 
-# env arming (checked once at import, like HEAT_TPU_TELEMETRY): core modules
-# that import later than this one re-arm themselves at their module bottom
+# env arming (checked once at import, like HEAT_TPU_TELEMETRY)
 if os.environ.get("HEAT_TPU_CHECKS", "").strip().lower() in ("1", "true", "on", "yes"):
     enable_checks()

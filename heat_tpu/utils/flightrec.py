@@ -32,10 +32,9 @@ reader never sees a half-initialized header.
   every local dispatch since the previous full record, flushed immediately
   before the next collective/span/checkpoint append (and on ``sync()``) —
   the "last healthy local operations" context around the collectives.
-  Coalescing keeps the per-dispatch hot path to ONE dict increment (the
-  same cost class as the telemetry hook); the window of local op names
-  since the last full record is the only thing a SIGKILL can lose, never
-  a collective stamp.
+  Coalescing keeps the per-dispatch hot path to ONE dict increment; the
+  window of local op names since the last full record is the only thing a
+  SIGKILL can lose, never a collective stamp.
 - ``span`` / ``span_end`` — telemetry span open/close (named phases).
 - ``ckpt`` / ``resume`` / ``shutdown`` — checkpoint IO, restart-resume,
   and clean teardown markers (the analyzer's "clean" evidence).
@@ -45,11 +44,10 @@ ring slot is ``e % n_slots``) and an epoch timestamp ``t``.
 
 **Arming.**  ``flightrec.enable(directory)`` (ring file
 ``{dir}/flight_rank{k}.ring``) or ``HEAT_TPU_FLIGHTREC_DIR`` in the
-environment.  Like the telemetry module, enabling pokes module globals
-*into* the hot-path modules (``core._operations._FLIGHTREC``,
-``core.communication._FLIGHTREC``, ``utils.telemetry._FLIGHTREC``), so
-the recorder-off cost on the dispatch path is ONE module-global load —
-gated in CI via ``benchmarks/dispatch.py --flightrec-gate``.
+environment.  Enabling sets the ``flightrec`` slot of ``core._hooks``,
+which the dispatch launch, the collective choke point and telemetry's
+spans read: disarmed, one attribute load.  What the whole dispatch layer
+costs on the chip is ``dispatch_py_ms_per_job`` (``chipbench``).
 
 Stdlib-only and standalone-loadable on purpose: ``scripts/postmortem.py``
 and ``scripts/telemetry_report.py`` load this file via
@@ -317,9 +315,7 @@ class FlightRecorder:
 
     def record_dispatch(self, op_name: str) -> None:
         """The ONE recorder call on the per-op hot path, so it is a single
-        dict increment — the same cost class as the telemetry dispatch
-        hook, and what keeps the recorder-on cost inside the ±5%
-        ``--flightrec-gate``.  The counts coalesce into one ``d`` summary
+        dict increment.  The counts coalesce into one ``d`` summary
         record at the next full append (:meth:`_flush_dispatch`): a ring
         write per dispatch measured ~10× that, because any main-thread
         Python burns GIL time the async XLA workers are bidding for.  No
@@ -413,18 +409,13 @@ def _rank() -> int:
     )
 
 
-def _poke_hooks(on: bool) -> None:
-    """Arm/disarm the hot-path hooks: each consumer module reads its OWN
-    ``_FLIGHTREC`` global (one load, no call) to decide whether to record."""
-    me = sys.modules.get(__name__) if on else None
-    for name in (
-        "heat_tpu.core._operations",
-        "heat_tpu.core.communication",
-        "heat_tpu.utils.telemetry",
-    ):
-        mod = sys.modules.get(name)
-        if mod is not None:
-            mod._FLIGHTREC = me
+def _set_slot(on: bool) -> None:
+    """Point the runtime's ``flightrec`` observer slot at this module, or
+    clear it.  A standalone load (tooling) has no runtime to observe."""
+    if __package__:
+        from ..core import _hooks
+
+        _hooks.flightrec = sys.modules[__name__] if on else None
 
 
 def enabled() -> bool:
@@ -458,7 +449,7 @@ def enable(
         old.close()
     path = os.path.join(directory, f"flight_rank{r}.ring")
     _RECORDER = FlightRecorder(path, slots=slots, slot_size=slot_size, rank=r)
-    _poke_hooks(True)
+    _set_slot(True)
     # graceful kills (SIGTERM/SIGINT) flush the telemetry ring AND msync
     # this one — the satellite hardening; in-package only (a standalone
     # load is tooling that must not install process-wide handlers)
@@ -476,7 +467,7 @@ def disable() -> None:
     """Disarm and close the ring (the file stays on disk for the analyzer)."""
     global _RECORDER
     old, _RECORDER = _RECORDER, None
-    _poke_hooks(False)
+    _set_slot(False)
     if old is not None:
         old.close()
 

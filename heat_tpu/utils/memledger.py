@@ -47,14 +47,11 @@ error re-raises.  ``scripts/postmortem.py`` turns those records into a
 live buffers; ``scripts/telemetry_report.py`` renders the per-rank
 watermark timeline and top-buffers table from the same records.
 
-**Overhead contract.**  Disarmed (the default), every instrumentation site
-reduces to ONE module-global load — :func:`enable`/:func:`disable` poke
-``_MEMLEDGER`` *into* the consumer modules (``core._operations``,
-``core.dndarray``, ``core.factories``, ``core.communication``,
-``core.redistribution``), the telemetry-hook pattern.  Armed, a dispatch
-registration is one weakref + one dict store + aval byte math; the CI
-bench lane gates the armed cost at <5% of dispatch overhead
-(``benchmarks/dispatch.py --memledger-gate``).
+**Cost.**  :func:`enable`/:func:`disable` set the ``memledger`` slot of
+``core._hooks``, which every instrumentation site reads: disarmed, one
+attribute load.  Armed, a dispatch registration is one weakref + one dict
+store + aval byte math.  What the whole dispatch layer costs on the chip is
+``dispatch_py_ms_per_job`` (``chipbench``).
 
 Arming: ``memledger.enable()`` in-process or ``HEAT_TPU_MEMLEDGER=1`` in
 the environment (checked once at import; ``core.io`` imports this module
@@ -817,25 +814,15 @@ def dump_to_ring() -> None:
 
 
 # ---------------------------------------------------------------------- #
-# enable / disable — the telemetry-hook poking pattern
+# enable / disable — the runtime's ``memledger`` observer slot
 # ---------------------------------------------------------------------- #
-_CONSUMER_MODULES = (
-    "heat_tpu.core._operations",
-    "heat_tpu.core.factories",
-    "heat_tpu.core.dndarray",
-    "heat_tpu.core.communication",
-    "heat_tpu.core.redistribution",
-    "heat_tpu.core.collectives",
-    "heat_tpu.core.random",
-)
+def _set_slot(on: bool) -> None:
+    """Point the runtime's ``memledger`` observer slot at this module, or
+    clear it.  A standalone load (tooling) has no runtime to observe."""
+    if __package__:
+        from ..core import _hooks
 
-
-def _poke_hooks(on: bool) -> None:
-    me = sys.modules.get(__name__) if on else None
-    for name in _CONSUMER_MODULES:
-        mod = sys.modules.get(name)
-        if mod is not None:
-            mod._MEMLEDGER = me
+        _hooks.memledger = sys.modules[__name__] if on else None
 
 
 def _ensure_provider() -> None:
@@ -856,8 +843,8 @@ def enabled() -> bool:
 
 
 def enable() -> None:
-    """Arm the ledger: resolve the jax classes, poke the consumer-module
-    hooks, register the profiler gauge provider."""
+    """Arm the ledger: resolve the jax classes, set the observer slot,
+    register the profiler gauge provider."""
     global _ENABLED, _JAX_ARRAY, _JAX_TRACER
     jax_mod = sys.modules.get("jax")
     if jax_mod is None:
@@ -872,16 +859,16 @@ def enable() -> None:
         except Exception:
             _JAX_TRACER = None
     _ENABLED = True
-    _poke_hooks(True)
+    _set_slot(True)
     _ensure_provider()
 
 
 def disable() -> None:
     """Disarm: the registry keeps its entries (a re-enable resumes), but
-    every hook reverts to the one-global-load no-op."""
+    every site reverts to the one-attribute-load no-op."""
     global _ENABLED
     _ENABLED = False
-    _poke_hooks(False)
+    _set_slot(False)
 
 
 def _reset_for_tests() -> None:
@@ -903,7 +890,7 @@ def _reset_for_tests() -> None:
 # env arming: one check at import (``core.io`` imports this module at
 # package import, so HEAT_TPU_MEMLEDGER takes effect process-wide).  Gated
 # on __package__ like telemetry/flightrec: a STANDALONE load of this file
-# is tooling and must not resolve jax or poke hooks.
+# is tooling and must not resolve jax or set the slot.
 try:
     _dispatch_min = int(
         os.environ.get("HEAT_TPU_MEMLEDGER_DISPATCH_MIN", "")

@@ -45,9 +45,7 @@ hook to poke, so the off-cost AND the armed-idle cost are both zero
 Python on the hot path.  A scrape reads the registries at that moment
 (the same reporting-boundary semantics as ``telemetry.report()``: counter
 providers may sync device-resident counters, so point scrapers at a
-sane interval, not a busy loop).  The bench lane's ``--monitor-gate``
-measures a concurrently-scraped dispatch loop against the unarmed one
-and holds the same ≤5% contract as the telemetry gate.
+sane interval, not a busy loop).
 
 **Security posture.**  Binds ``127.0.0.1`` by default — the endpoint
 exposes operational metadata (op names, tenant names, queue depths) and

@@ -24,13 +24,12 @@ perun only); this module is the TPU port's first-class story.  Three layers:
   files into one timeline/summary.  :func:`report` returns the in-process
   merged view (counters ∪ histograms ∪ top spans by self-time).
 
-**Overhead contract.**  Disabled (the default), the dispatch tails in
-``core._operations`` check a flag that :func:`enable`/:func:`disable` poke
-*into that module*, so the hot path never even calls into here, and a
-:func:`span` site costs one flag check and one ``is_enabled()``.  Enabled,
-a span costs two clock reads, a ring append and (optionally) a
-TraceAnnotation; the CI telemetry lane gates the enabled cost at <5% of
-dispatch overhead (``benchmarks/dispatch.py --telemetry-gate``).
+**Cost.**  The eager dispatch path never calls into here: the profile's
+``ht.dispatch.<kind>`` and ``ht.dispatch.launch`` spans time it, and what
+the whole layer costs on the chip is ``dispatch_py_ms_per_job``
+(``chipbench``).  Disabled (the default), a :func:`span` site costs one
+flag check and one ``is_enabled()``; enabled, two clock reads, a ring
+append and (optionally) a TraceAnnotation.
 
 Arming: ``telemetry.enable()`` in-process, or ``HEAT_TPU_TELEMETRY=1`` in
 the environment (checked once at import).  ``HEAT_TPU_TELEMETRY_DIR``
@@ -108,12 +107,14 @@ _atexit_registered = False
 _trace_annotation = None  # jax.profiler.TraceAnnotation, resolved by _annotation()
 _profiler = None  # utils.profiler, resolved on first counter touch
 
-# flight-recorder hook (``utils.flightrec.enable()`` pokes the module in):
-# armed, context-manager span open/close boundaries are mirrored into the
-# crash-durable ring — the named phases around the seq-stamped collectives.
-# The leaf-record fast paths (record_dispatch/record_event) are NOT hooked
-# here; the dispatch tails have their own hook in ``core._operations``.
-_FLIGHTREC = None
+# the observers' slots (``core._hooks``): an armed flight recorder mirrors
+# context-manager span boundaries into its crash-durable ring, the named
+# phases around the seq-stamped collectives.  A standalone load of this
+# file (tooling, no jax) has no runtime to observe.
+if __package__:
+    from ..core import _hooks
+else:
+    _hooks = None
 
 # wall-clock anchor: span timestamps are perf_counter-based for precision
 # but exported in epoch seconds so multi-rank timelines merge on one axis
@@ -141,6 +142,11 @@ def _annotation():
     return _trace_annotation
 
 
+def _flightrec():
+    """The armed flight recorder's module, or None."""
+    return None if _hooks is None else _hooks.flightrec
+
+
 def _stack() -> list:
     s = getattr(_tls, "stack", None)
     if s is None:
@@ -151,8 +157,7 @@ def _stack() -> list:
 def _ring_push(rec: tuple) -> None:
     """Append into the bounded span ring, counting an eviction under
     ``telemetry.ring.dropped`` first — ring truncation is always visible
-    in the export.  (``record_dispatch`` inlines this with identical
-    semantics: the hottest recorder cannot afford the call frame.)"""
+    in the export."""
     global _ring_dropped
     if len(_ring) == _ring.maxlen:
         _ring_dropped += 1
@@ -170,12 +175,12 @@ def ring_dropped() -> int:
 # The contextvar carries ``(trace_id, parent_span_id)``.  It is set by
 # :func:`tracing` (the ONE sanctioned way to adopt or mint trace identity —
 # heatlint HT109 flags manual trace_id fiddling in library code) and read
-# by every recording site below: spans, leaf events and dispatch records
-# stamp the ambient trace into their attrs, and the flight recorder reads
+# by every recording site below: spans and leaf events stamp the ambient
+# trace into their attrs, and the flight recorder reads
 # :func:`current_trace_id` at the ``_account_bytes`` choke point so staged
 # collectives carry the same id into the crash-durable ring.  Contextvars
 # flow into ``health.guard_blocking`` worker threads and ``faults``
-# retries automatically, so one job's whole causal path — dispatch spans,
+# retries automatically, so one job's whole causal path — spans,
 # collective stamps, retry attempts — shares one id without any plumbing.
 _TRACE: contextvars.ContextVar = contextvars.ContextVar(
     "heat_tpu_trace", default=None
@@ -230,7 +235,7 @@ def current_span_id() -> Optional[str]:
 @contextlib.contextmanager
 def tracing(trace_id: Optional[str] = None, name: str = "trace",
             parent_id: Optional[str] = None):
-    """Arm a trace context for the block: every span/event/dispatch record
+    """Arm a trace context for the block: every span and event record
     (and every flight-recorder collective stamp) inside it carries
     ``trace_id``.  Minted via :func:`mint_trace_id` when not given;
     ``parent_id`` links into an enclosing trace from another process (a
@@ -249,7 +254,7 @@ def tracing(trace_id: Optional[str] = None, name: str = "trace",
 def _trace_attrs(attrs: Optional[dict], span_id: Optional[str] = None,
                  parent_id: Optional[str] = None) -> Optional[dict]:
     """Fold the ambient trace identity into a record's attrs (shared by
-    spans, leaf events and dispatch records).  No active trace: attrs pass
+    spans and leaf events).  No active trace: attrs pass
     through untouched — zero cost added to untraced recording."""
     t = _TRACE.get()
     if t is None:
@@ -272,15 +277,6 @@ def enabled() -> bool:
     return _ENABLED
 
 
-def _poke_dispatch_hook(on: bool) -> None:
-    """Arm/disarm the dispatch hot-path hook: ``core._operations`` reads its
-    own module global (one load, no call) to decide whether to record —
-    set from here so the disabled cost stays at that single load."""
-    mod = sys.modules.get("heat_tpu.core._operations")
-    if mod is not None:
-        mod._TELEMETRY = sys.modules[__name__] if on else None
-
-
 def enable(directory: Optional[str] = None, ring_size: Optional[int] = None) -> None:
     """Arm telemetry.  ``directory`` (or ``HEAT_TPU_TELEMETRY_DIR``) also
     registers an atexit :func:`flush` of this process's rank file."""
@@ -300,13 +296,11 @@ def enable(directory: Optional[str] = None, ring_size: Optional[int] = None) -> 
         # runs when a supervisor tears the world down with signals
         install_signal_flush()
     _ENABLED = True
-    _poke_dispatch_hook(True)
 
 
 def disable() -> None:
     global _ENABLED
     _ENABLED = False
-    _poke_dispatch_hook(False)
 
 
 def reset() -> None:
@@ -461,8 +455,9 @@ class _Span:
         stack.append(self)
         if self._ta is not None:
             self._ta.__enter__()
-        if _FLIGHTREC is not None:
-            _FLIGHTREC.record_event("span", name=self.name)
+        fr = _flightrec()
+        if fr is not None:
+            fr.record_event("span", name=self.name)
         self.t0 = time.perf_counter()
         return self
 
@@ -470,8 +465,9 @@ class _Span:
         t1 = time.perf_counter()
         if self._ta is not None:
             self._ta.__exit__(et, ev, tb)
-        if _FLIGHTREC is not None:
-            _FLIGHTREC.record_event(
+        fr = _flightrec()
+        if fr is not None:
+            fr.record_event(
                 "span_end", name=self.name, dur=round(t1 - self.t0, 6),
                 **({"error": et.__name__} if et is not None else {}),
             )
@@ -573,38 +569,6 @@ def record_event(name: str, dur_s: float, attrs: Optional[dict] = None) -> None:
             dur_s,
             len(stack),
             attrs or None,
-        )
-    )
-
-
-def record_dispatch(name: str, t0: float, t1: float, op_name: str, cache_hit: bool) -> None:
-    """The dispatch tails' recorder — the leanest path here: the caller
-    supplies both perf_counter readings and the pre-resolved span name, so
-    one call records a leaf span with the op/cache attributes and nothing
-    else happens on the hot path."""
-    if not _ENABLED:
-        return
-    global _ring_dropped
-    dur = t1 - t0
-    attrs = {"op": op_name, "cache": "hit" if cache_hit else "miss"}
-    if _TRACE.get() is not None:  # the leanest-path tax when untraced is
-        attrs = _trace_attrs(attrs, None, current_span_id())  # this ONE load
-    stack = _stack()
-    if stack:
-        stack[-1].child += dur
-    # _ring_push inlined (same eviction-count semantics): this is the
-    # hottest recorder and an extra call frame is measurable against the
-    # telemetry-gate budget
-    if len(_ring) == _ring.maxlen:
-        _ring_dropped += 1
-    _ring.append(
-        (
-            name,
-            _T0_WALL + (t0 - _T0_PERF),
-            dur,
-            dur,
-            len(stack),
-            attrs,
         )
     )
 
@@ -901,13 +865,3 @@ if __package__ and os.environ.get(
     "HEAT_TPU_TELEMETRY", ""
 ).strip().lower() in ("1", "true", "on", "yes"):
     enable()
-
-# the flight recorder may have been env-armed while this module was still
-# importing (flightrec's poke would hit the half-initialized module and the
-# `_FLIGHTREC = None` line above clobbered it) — re-read the flag now, same
-# defensive pattern as core._operations / core.communication
-if __package__:
-    _fr_mod = sys.modules.get("heat_tpu.utils.flightrec")
-    if _fr_mod is not None and _fr_mod.enabled():
-        _FLIGHTREC = _fr_mod
-    del _fr_mod

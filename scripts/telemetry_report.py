@@ -4,8 +4,7 @@
                                        [--top N] [--timeline N]
 
 Each rank of a run writes ``rank<k>.jsonl`` (``heat_tpu.utils.telemetry
-.flush``; the multiprocess lane and the CI telemetry job arm this via
-``HEAT_TPU_TELEMETRY_DIR``).  This CLI reads any mix of directories
+.flush``; the multiprocess lane arms this via ``HEAT_TPU_TELEMETRY_DIR``).  This CLI reads any mix of directories
 (``rank*.jsonl`` inside) and explicit files and prints:
 
 - a cross-rank **span summary** aggregated by name, sorted by self-time —

@@ -376,21 +376,20 @@ class TestStamping:
         assert "seq" not in rec and "collective" not in rec
 
     def test_disabled_is_noop_and_unhooked(self, tmp_path):
-        from heat_tpu.core import _operations, communication
+        from heat_tpu.core import _hooks
 
-        assert _operations._FLIGHTREC is None
-        assert communication._FLIGHTREC is None
+        assert _hooks.flightrec is None
         flightrec.record_event("coll", seq=1)  # must not raise
         flightrec.record_dispatch("add")
         flightrec.record_collective("Allreduce", 1)
         assert flightrec.last_collective() is None
         assert not flightrec.enabled() and flightrec.recorder() is None
         flightrec.enable(str(tmp_path), rank=0)
-        assert _operations._FLIGHTREC is flightrec
-        assert communication._FLIGHTREC is flightrec
-        assert telemetry._FLIGHTREC is flightrec
+        assert _hooks.flightrec is flightrec
+        assert telemetry._flightrec() is flightrec
         flightrec.disable()
-        assert _operations._FLIGHTREC is None
+        assert _hooks.flightrec is None
+        assert telemetry._flightrec() is None
 
     def test_env_arm_failure_warns_not_silent(self, tmp_path, monkeypatch):
         # a silently-disarmed black box is the exact failure this module

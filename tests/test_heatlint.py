@@ -798,6 +798,24 @@ class TestHT111:
         """)
         assert fs == []
 
+    @pytest.mark.parametrize("register", [
+        "_hooks.memledger.register(placed, op='init', category='param')",
+        "ml = _hooks.memledger\n                ml.register(placed, op='init', category='param')",
+    ])
+    def test_slot_registrar_function_exempt(self, register):
+        # the runtime reaches the ledger through its observer slot
+        # (``core._hooks.memledger``), read in place or into a local
+        fs = run_rule(UnledgeredDeviceBufferRule(), f"""
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from heat_tpu.core import _hooks
+            def f(mesh, p):
+                placed = jax.device_put(p, NamedSharding(mesh, P("dcn")))
+                {register}
+                return placed
+        """)
+        assert fs == []
+
     def test_registration_layer_sanctioned(self):
         src = """
             import jax

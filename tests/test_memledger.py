@@ -12,8 +12,7 @@ import numpy as np
 import pytest
 
 import heat_tpu as ht
-from heat_tpu.core import _operations, communication, dndarray, factories
-from heat_tpu.core import redistribution
+from heat_tpu.core import _hooks, redistribution
 from heat_tpu.utils import faults, flightrec, memledger, profiler, telemetry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -104,15 +103,11 @@ class TestRegistry:
 
     def test_disabled_register_is_noop_and_hooks_cleared(self):
         memledger.disable()
-        assert _operations._MEMLEDGER is None
-        assert dndarray._MEMLEDGER is None
-        assert factories._MEMLEDGER is None
-        assert communication._MEMLEDGER is None
-        assert redistribution._MEMLEDGER is None
+        assert _hooks.memledger is None
         _x = ht.zeros((128,), dtype=ht.float32)
         assert memledger.live_bytes() == 0
         memledger.enable()
-        assert _operations._MEMLEDGER is memledger
+        assert _hooks.memledger is memledger
 
 
 # ---------------------------------------------------------------------- #
@@ -206,6 +201,7 @@ class TestDispatchTier:
         assert out.shape == (1024, 512)
 
     def test_donated_dunder_consumes_left_operand(self):
+        assert _hooks.memledger is memledger
         prev = memledger.set_dispatch_threshold(0)
         try:
             z = ht.zeros((64,), dtype=ht.float32, split=0)

@@ -350,7 +350,7 @@ class TestRobustness:
         sanitation.enable_checks()
         try:
             x = _fresh((16, 6, 8), 0)
-            got = x.resplit(2, memory_budget=512)  # _RESPLIT_CHECK runs on out
+            got = x.resplit(2, memory_budget=512)  # the resplit_check slot runs on out
             assert got.split == 2
             got2 = sanitation.check(got, "test")
             assert got2 is got

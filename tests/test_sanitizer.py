@@ -3,7 +3,7 @@ metadata-only contract (ISSUE 4).
 
 Three tiers:
 
-1. the sanitizer itself: arming pokes the dispatch/resplit hooks, armed
+1. the sanitizer itself: arming sets the dispatch/resplit slots, armed
    dispatch passes on healthy arrays (all split shapes incl. ragged),
    corrupted metadata is caught with a precise error;
 2. the no-value-reads contract: every ``sanitize_*`` function (and the new
@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 import heat_tpu as ht
-from heat_tpu.core import _operations, communication, sanitation
+from heat_tpu.core import _hooks, sanitation
 from heat_tpu.core.communication import Communication
 from heat_tpu.core.dndarray import DNDarray
 
@@ -53,20 +53,20 @@ class TestArming:
             "1", "true", "on", "yes",
         )
         assert sanitation.checks_enabled() == want
-        assert (_operations._CHECKS is not None) == want
-        assert (communication._RESPLIT_CHECK is not None) == want
+        assert (_hooks.checks is not None) == want
+        assert (_hooks.resplit_check is not None) == want
 
     def test_poke_roundtrip(self):
         was_on = sanitation.checks_enabled()
         try:
             sanitation.enable_checks()
             assert sanitation.checks_enabled()
-            assert _operations._CHECKS is sanitation.validate_dispatch
-            assert communication._RESPLIT_CHECK is sanitation.check_placement
+            assert _hooks.checks is sanitation.validate_dispatch
+            assert _hooks.resplit_check is sanitation.check_placement
             sanitation.disable_checks()
             assert not sanitation.checks_enabled()
-            assert _operations._CHECKS is None
-            assert communication._RESPLIT_CHECK is None
+            assert _hooks.checks is None
+            assert _hooks.resplit_check is None
         finally:
             (sanitation.enable_checks if was_on else sanitation.disable_checks)()
 
@@ -83,10 +83,10 @@ class TestArming:
         out = subprocess.run(
             [sys.executable, "-c", (
                 "import heat_tpu as ht\n"
-                "from heat_tpu.core import _operations, sanitation, communication\n"
+                "from heat_tpu.core import _hooks, sanitation\n"
                 "assert sanitation.checks_enabled()\n"
-                "assert _operations._CHECKS is sanitation.validate_dispatch\n"
-                "assert communication._RESPLIT_CHECK is sanitation.check_placement\n"
+                "assert _hooks.checks is sanitation.validate_dispatch\n"
+                "assert _hooks.resplit_check is sanitation.check_placement\n"
                 "x = ht.arange(16, dtype=ht.float32, split=0)\n"
                 "y = ((x + 1.0) * 2.0).sum()\n"
                 "r = ht.arange(101, dtype=ht.float32, split=0) * 3.0\n"

@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 import heat_tpu as ht
-from heat_tpu.core import _operations
 from heat_tpu.utils import profiler, telemetry
 
 # NOT mp-marked: these tests toggle global telemetry state and write rank
@@ -98,41 +97,6 @@ class TestSpans:
         for i in range(telemetry._ring.maxlen + 100):
             telemetry.record_event("e", 1e-6)
         assert len(telemetry._ring) == telemetry._ring.maxlen
-
-
-class TestDispatchInstrumentation:
-    def test_dispatch_tail_records_op_and_cache_state(self):
-        x = ht.random.randn(16, 16, split=0)
-        y = ht.random.randn(16, 16, split=0)
-        _ = x + y  # compile outside the armed window
-        telemetry.enable()
-        telemetry.reset()
-        _ = x + y
-        _ = ht.sum(x, axis=0)
-        recs = list(telemetry._ring)
-        kinds = {r[0]: r[5] for r in recs}
-        assert kinds["dispatch.binary"]["op"] == "add"
-        assert kinds["dispatch.binary"]["cache"] == "hit"
-        assert "dispatch.reduce" in kinds
-        # a fresh signature through the armed window records a miss
-        # (mesh-divisible leading extent: ragged shapes take the pad path)
-        z = ht.random.randn(8 * ht.communication.get_comm().size, 4, split=0)
-        telemetry.reset()
-        _ = z + z
-        (rec,) = [r for r in telemetry._ring if r[0] == "dispatch.binary"]
-        assert rec[5]["cache"] == "miss"
-
-    def test_disabled_dispatch_adds_nothing(self):
-        """The telemetry-off contract: the hot-path hook is None and no
-        record is ever created."""
-        assert _operations._TELEMETRY is None
-        x = ht.random.randn(8, 8, split=0)
-        _ = x + x
-        assert len(telemetry._ring) == 0
-        telemetry.enable()
-        assert _operations._TELEMETRY is telemetry
-        telemetry.disable()
-        assert _operations._TELEMETRY is None
 
 
 class TestCollectiveAccounting:
@@ -651,17 +615,6 @@ class TestTracing:
         b = telemetry.mint_trace_id("x")
         assert a == b and len(a) == 16
         assert telemetry.mint_trace_id("x") != a  # counter advanced
-
-    def test_dispatch_records_inherit_the_ambient_trace(self):
-        x = ht.random.randn(16, 16, split=0)
-        _ = x + x  # compile outside
-        telemetry.enable()
-        telemetry.reset()
-        with telemetry.tracing(name="d") as tid:
-            _ = x + x
-        (rec,) = [r for r in telemetry._ring if r[0] == "dispatch.binary"]
-        assert rec[5]["trace_id"] == tid
-        assert rec[5]["op"] == "add"  # the op attrs still ride along
 
     def test_record_event_inherits_and_parents_on_open_span(self):
         telemetry.enable()
