@@ -9,7 +9,7 @@ from .padshuffle import *
 from .extended import *
 from . import activations, extended, losses, padshuffle, spatial
 from .attention import LatentAttention, MultiheadAttention, apply_rope
-from .linear_attention import KimiDeltaAttention
+from .linear_attention import KimiDeltaAttention, LightningAttention
 from .moe import MoE
 from .pipelined import Pipelined
 from .recurrent import GRU, GRUCell, LSTM, LSTMCell, RNN, RNNCell

@@ -123,6 +123,7 @@ class MultiheadAttention(Module):
         head_dim: int = None,
         window: int = None,
         gate: bool = False,
+        sparse=None,
     ):
         if head_dim is None:
             if embed_dim % num_heads:
@@ -148,6 +149,9 @@ class MultiheadAttention(Module):
         self.kv_dim = num_kv_heads * self.head_dim
         self.window = window
         self.gate = gate  # sigmoid(x W_g) on the merged heads, ahead of out_proj
+        if sparse is not None and (comm is not None or window is not None):
+            raise ValueError("sparse= is causal self-attention off the ring, without a window")
+        self.sparse = sparse  # ops.sparse_attention.SparseConfig: the blocks a token attends
         self.bias = bias
         self.comm = comm
         self.rope = rope  # rotary positions on SELF-attention q/k (not cross)
@@ -385,6 +389,10 @@ class MultiheadAttention(Module):
         gqa = self.num_kv_heads != self.num_heads
         if self.window is not None and kv is not None:
             raise ValueError("window is defined for self-attention only")
+        if self.sparse is not None and not (ring or masked or need_weights) and kv is None and causal:
+            from ..ops.sparse_attention import sparse_attention
+
+            return sparse_attention(qh, kh, vh, self.sparse), probs
         if ring:
             # the ring rotates full-head K/V blocks — broadcast the groups
             # (training-time copy; the GQA memory win is the DECODE cache)
@@ -415,12 +423,18 @@ class MultiheadAttention(Module):
         else:
             out = _global_attention(qh, *self._repeat_kv(kh, vh), causal,
                                     1.0 / (self.head_dim**0.5))
-        return out, probs
+        return (out, None), probs
 
-    def apply(self, params, x, *, kv=None, causal: bool = False,
-              key_padding_mask=None, attn_mask=None,
-              need_weights: bool = False, average_attn_weights: bool = True,
-              train: bool = False, key=None):
+    def apply(self, params, x, **kw):
+        return self.apply_with_stats(params, x, **kw)[0]
+
+    def apply_with_stats(self, params, x, *, kv=None, causal: bool = False,
+                         key_padding_mask=None, attn_mask=None,
+                         need_weights: bool = False, average_attn_weights: bool = True,
+                         train: bool = False, key=None):
+        """``(apply's result, stats)``: ``stats`` is what the sparse attention
+        counted (``ops.sparse_attention.sparse_attention``), ``None`` on every
+        other path."""
         E = self.q_dim  # the query rows of the packed projection
         if need_weights and self.comm is not None and self.comm.size > 1 and kv is None:
             raise ValueError(
@@ -479,8 +493,8 @@ class MultiheadAttention(Module):
         # trace of one fused training step can tell it from the projections,
         # and a windowed layer's from a global one's
         with jax.named_scope("ht.attention" if self.window is None else "ht.attention.window"):
-            out, probs = self._attend(qh, kh, vh, kv, causal, ring, masked, need_weights,
-                                      key_padding_mask, attn_mask)
+            (out, stats), probs = self._attend(qh, kh, vh, kv, causal, ring, masked, need_weights,
+                                               key_padding_mask, attn_mask)
         B, H, S, d = out.shape
         merged = out.transpose(0, 2, 1, 3).reshape(B, S, E)
         y = self._gate_project(params, merged, x)
@@ -489,8 +503,8 @@ class MultiheadAttention(Module):
             # (B, H, S_q, S_k) with average_attn_weights=False
             if average_attn_weights:
                 probs = probs.mean(axis=1)
-            return y, probs
-        return y
+            return (y, probs), stats
+        return y, stats
 
 
 def _pairs_to_halves(w, width: int, groups: int):

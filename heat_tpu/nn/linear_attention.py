@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from .modules import Linear, Module, rms_normalize
 
-__all__ = ["KimiDeltaAttention"]
+__all__ = ["KimiDeltaAttention", "LightningAttention", "lightning_slopes"]
 
 
 class KimiDeltaAttention(Module):
@@ -106,3 +106,91 @@ class KimiDeltaAttention(Module):
             o = jax.checkpoint(self._gated_norm)(o, x @ w["g_a"].T, w["g_b"], params["o_norm"]["weight"])
         with jax.named_scope("ht.kda.proj"):
             return o @ w["out_proj"].T
+
+
+def lightning_slopes(total_heads: int, layer: int, layers: int):
+    """MiniMax-01's decay schedule: ``s_h = 2^(-8 (h + 1) / total_heads) (1 -
+    layer / (layers - 1) + 1e-5)`` for every head ``h`` of ``total_heads``,
+    ``lambda_h = exp(-s_h)``; ``layer`` of ``layers`` counts from 0."""
+    depth = 1.0 - layer / max(layers - 1, 1) + 1e-5
+    return [2.0 ** (-8.0 * (h + 1) / total_heads) * depth for h in range(total_heads)]
+
+
+class LightningAttention(Module):
+    """Lightning attention (MiniMax-01's linear attention with a constant decay
+    a head) over ``num_heads`` heads of ``head_dim`` (``P = num_heads *
+    head_dim``), causal by construction:
+
+    - ``q, k, v = SiLU(x W_in)`` as heads (one input projection to ``3 P``);
+      with ``qk_norm`` ``q`` and ``k`` are RMS-normalised a head (a weight
+      vector each), then, with ``rope``, rotated by halves (base
+      ``rope_base``); ``q`` is scaled by ``head_dim ** -0.5``;
+    - ``S_t = lambda_h S_{t-1} + k_t^T v_t``, ``o_t = q_t S_t``
+      (:func:`heat_tpu.ops.lightning.lightning_attention`, ``chunk`` tokens at
+      a time), ``lambda_h = exp(-s_h)`` from :func:`lightning_slopes` of the
+      head's index among ``total_heads`` (this layer holds ``head_offset ..
+      head_offset + num_heads - 1`` of them) and of ``layer`` among
+      ``layers``;
+    - ``out = (RMSNorm_head(o) * sigmoid(x W_g)) W_o``: the norm's statistic is
+      a head's, its weight a channel's (``P`` of them).
+
+    No bias.  Scopes: ``ht.lightning`` (the kernel), ``ht.lightning.gate``
+    (output norm and gate); the projections, the feature map, the QK norms and
+    the rotation run in the caller's scope.  Norms and gates are float32;
+    matrices are brought to ``x``'s dtype where they are used.
+    """
+
+    def __init__(self, embed_dim: int, num_heads: int, head_dim: int, *, layer: int, layers: int,
+                 total_heads: int = None, head_offset: int = 0, qk_norm: bool = True, rope: bool = True,
+                 rope_base: float = 10000.0, eps: float = 1e-6, chunk: int = 128):
+        total_heads = num_heads if total_heads is None else total_heads
+        if not 0 <= head_offset <= total_heads - num_heads:
+            raise ValueError(f"heads {head_offset}..{head_offset + num_heads - 1} are not among {total_heads}")
+        self.embed_dim, self.num_heads, self.head_dim = embed_dim, num_heads, head_dim
+        self.qk_norm, self.rope, self.rope_base, self.eps, self.chunk = qk_norm, rope, rope_base, eps, chunk
+        self.slopes = lightning_slopes(total_heads, layer, layers)[head_offset:head_offset + num_heads]
+
+    def init(self, key):
+        e, p = self.embed_dim, self.num_heads * self.head_dim
+        shapes = {"in_proj": (e, 3 * p), "gate_proj": (e, p), "out_proj": (p, e)}
+        out = {name: Linear(*shape, bias=False).init(jax.random.fold_in(key, i))
+               for i, (name, shape) in enumerate(shapes.items())}
+        if self.qk_norm:
+            out["q_norm"] = {"weight": jnp.ones((self.head_dim,))}
+            out["k_norm"] = {"weight": jnp.ones((self.head_dim,))}
+        out["o_norm"] = {"weight": jnp.ones((p,))}
+        return out
+
+    def _heads(self, params, proj):
+        """``(q, k, v)`` as ``(B, H, S, d)`` heads from the input projection."""
+        from .attention import apply_rope
+
+        b, s, _ = proj.shape
+        q, k, v = (t.reshape(b, s, self.num_heads, self.head_dim).transpose(0, 2, 1, 3)
+                   for t in jnp.split(jax.nn.silu(proj), 3, axis=-1))
+        if self.qk_norm:
+            q = rms_normalize(q, params["q_norm"]["weight"], self.eps)
+            k = rms_normalize(k, params["k_norm"]["weight"], self.eps)
+        if self.rope:
+            positions = jnp.arange(s)
+            q = apply_rope(q, positions, self.rope_base, "half")
+            k = apply_rope(k, positions, self.rope_base, "half")
+        return (q * self.head_dim ** -0.5).astype(q.dtype), k, v
+
+    def _gated_norm(self, o, x, w_g, weight):
+        """``RMSNorm_head(o) * sigmoid(x W_g)`` as ``(B, S, P)`` in ``x``'s dtype."""
+        b, s, _ = x.shape
+        o = rms_normalize(o.astype(jnp.float32), None, self.eps).transpose(0, 2, 1, 3).reshape(b, s, -1)
+        gate = jax.nn.sigmoid((x @ w_g.T).astype(jnp.float32))
+        return (o * weight * gate).astype(x.dtype)
+
+    def apply(self, params, x, **kw):
+        from ..ops.lightning import lightning_attention
+
+        w = {n: params[n]["weight"].astype(x.dtype) for n in ("in_proj", "gate_proj", "out_proj")}
+        q, k, v = self._heads(params, x @ w["in_proj"].T)
+        o = lightning_attention(q, k, v, -jnp.asarray(self.slopes, jnp.float32), chunk=self.chunk)
+        with jax.named_scope("ht.lightning.gate"):
+            # rematerialised: its float32 intermediates are several times what goes in and out
+            o = jax.checkpoint(self._gated_norm)(o, x, w["gate_proj"], params["o_norm"]["weight"])
+        return o @ w["out_proj"].T

@@ -25,7 +25,7 @@ from __future__ import annotations
 from typing import Sequence
 
 from . import modules as nn
-from .linear_attention import KimiDeltaAttention
+from .linear_attention import KimiDeltaAttention, LightningAttention
 
 __all__ = ["resnet", "resnet18", "resnet34", "resnet50", "resnet50_ish", "mlp", "transformer_encoder", "transformer_decoder", "TransformerLM", "Seq2SeqTransformer", "PatternLM"]
 
@@ -155,16 +155,19 @@ def _remat_jit(cache: dict, train: bool, block_fn):
     REQUIRED (checkpoint's closed_call cannot evaluate eagerly inside the
     ring path's shard_map) and cached per train flag so repeat applies
     reuse one traced wrapper.  The one thing it keeps is what the flash
-    kernels name (``ops.flash_attention.KEPT``: their output and
-    log-sum-exp), so the backward reads those and never runs an attention
-    forward again; a block without a flash call keeps nothing."""
+    and the sparse kernels name (``ops.flash_attention.KEPT``: their output
+    and log-sum-exp; ``ops.sparse_attention.KEPT``: the same and the
+    selection), so the backward reads those and never runs an attention
+    forward or a selection again; a block without such a call keeps
+    nothing."""
     fn = cache.get(train)
     if fn is None:
         import jax
 
+        from ..ops import sparse_attention
         from ..ops.flash_attention import KEPT
 
-        policy = jax.checkpoint_policies.save_only_these_names(*KEPT)
+        policy = jax.checkpoint_policies.save_only_these_names(*KEPT, *sparse_attention.KEPT)
         fn = cache[train] = jax.jit(jax.checkpoint(block_fn, policy=policy))
     return fn
 
@@ -1154,10 +1157,14 @@ class _PatternBlock(nn.Module):
     with ``route_on_input`` its router scores ``x`` itself, not ``RMSNorm(h)``.
     With ``output_norms`` each sublayer's output goes through an RMSNorm of
     its own before the residual sum: ``h = x + RMSNorm(Op(RMSNorm(x)))``,
-    ``y = h + RMSNorm(FFN(RMSNorm(h)))``."""
+    ``y = h + RMSNorm(FFN(RMSNorm(h)))``.  With ``residual_scale`` each
+    sublayer's output is multiplied by it ahead of the residual sum:
+    ``h = x + c Op(RMSNorm(x))``, ``y = h + c FFN(RMSNorm(h))``.  An operator
+    that counts what it did (sparse attention: ``apply_with_stats``) adds
+    its counts to the block's stats."""
 
     def __init__(self, embed_dim: int, operator: nn.Module, ffn: nn.Module, eps: float,
-                 route_on_input: bool = False, output_norms: bool = False):
+                 route_on_input: bool = False, output_norms: bool = False, residual_scale: float = None):
         self.operator_norm = nn.RMSNorm(embed_dim, eps=eps)
         self.operator = operator
         self.ffn_norm = nn.RMSNorm(embed_dim, eps=eps)
@@ -1168,6 +1175,8 @@ class _PatternBlock(nn.Module):
         self.routed = hasattr(ffn, "apply_with_stats")
         # the router scores the block's input as it enters, ahead of the operator
         self.route_on_input = route_on_input and self.routed
+        self.residual_scale = residual_scale
+        self.counted = getattr(operator, "sparse", None) is not None
         # the operator's projections, beside the scope the operator gives its core
         self.scope = ("ht.shortconv.proj" if isinstance(operator, _ShortConvOperator)
                       else "ht.kda.proj" if isinstance(operator, KimiDeltaAttention) else "ht.attention.proj")
@@ -1201,7 +1210,12 @@ class _PatternBlock(nn.Module):
         with jax.named_scope("ht.lm.norm"):
             z = self.operator_norm.apply(params["operator_norm"], x)
         with jax.named_scope(self.scope):
-            h = self.operator.apply(params["operator"], z, causal=True)
+            if self.counted:
+                h, counts = self.operator.apply_with_stats(params["operator"], z, causal=True)
+            else:
+                h, counts = self.operator.apply(params["operator"], z, causal=True), None
+            if self.residual_scale is not None:
+                h = h * self.residual_scale
             if plain:
                 h = x + h
         if not plain:
@@ -1211,12 +1225,18 @@ class _PatternBlock(nn.Module):
         if self.routed:
             routed_on = {"router_input": x} if self.route_on_input else {}
             out, stats = self.ffn.apply_with_stats(params["ffn"], z, **routed_on)
+            if self.residual_scale is not None:
+                out = out * self.residual_scale
+            if counts is not None:
+                stats = {**counts, **stats}
             return (h + out if plain else self._normed_sum(params, "ffn_out_norm", h, out)), stats
         with jax.named_scope("ht.mlp"):
             out = self.ffn.apply(params["ffn"], z)
+            if self.residual_scale is not None:
+                out = out * self.residual_scale
             if plain:
-                return h + out, None
-        return self._normed_sum(params, "ffn_out_norm", h, out), None
+                return h + out, counts
+        return self._normed_sum(params, "ffn_out_norm", h, out), counts
 
 
 class PatternLM(nn.Module):
@@ -1239,7 +1259,17 @@ class PatternLM(nn.Module):
     ``kv_norm_eps``, by default ``norm_eps``, with values of ``v_dim``:
     :class:`~heat_tpu.nn.LatentAttention`; without positions unless
     ``rope_kinds`` names ``"mla"``, and then its shared key part and the
-    matching part of every query head are rotated with base ``rope_base``).
+    matching part of every query head are rotated with base ``rope_base``),
+    ``"sparse_attention"`` (the grouped-query attention of the kinds above
+    over the key blocks each token selects, ``sparse`` an
+    :class:`~heat_tpu.ops.sparse_attention.SparseConfig`:
+    ``MultiheadAttention(sparse=)``; its block's stats hold the pairs it kept)
+    or ``"lightning"`` (linear attention with a constant decay a head,
+    ``lightning_heads`` heads of ``lightning_head_dim``, chunks of
+    ``lightning_chunk`` tokens, the decays of the layer's index among
+    ``published_layers`` (absent: the layers here), rotated where
+    ``rope_kinds`` names it, QK-normed with ``qk_norm``:
+    :class:`~heat_tpu.nn.LightningAttention`).
     The first ``num_dense_layers`` layers have a SwiGLU
     feed-forward of width ``ffn_dim``; with ``num_experts`` set, every later
     layer has ``num_experts`` SwiGLU experts of width ``expert_dim``,
@@ -1264,7 +1294,17 @@ class PatternLM(nn.Module):
     block an RMSNorm on each sublayer's output ahead of the residual sum as
     well as the one on its input, and ``embedding_scale`` multiplies the
     embedded tokens as they enter the first layer (``sqrt(embed_dim)`` in
-    models that scale it so).
+    models that scale it so).  ``residual_scale`` multiplies every
+    sublayer's output ahead of its residual sum, ``head_input_scale`` the
+    final normalised states ahead of the output head.
+
+    A layer may hold its tensor-parallel share of a wider one (ranges, as
+    ``experts_held`` is): ``heads_held`` of the ``num_heads`` query heads and
+    ``kv_heads_held`` of the ``num_kv_heads`` (whole groups),
+    ``lightning_heads_held`` of the ``lightning_heads``, ``ffn_held`` of the
+    ``ffn_dim`` columns of a dense feed-forward.  The layer computes its share
+    alone, and its output projection gives the partial sum that the other
+    shares' all-reduce would complete.
 
     Parameters are float32, drawn ``N(0, init_std)`` (norm weights 1, the
     selection bias ``N(0, bias_std)`` and then fixed, a ``"kda"`` layer's
@@ -1302,30 +1342,55 @@ class PatternLM(nn.Module):
                  rope_kinds: Sequence[str] = ("full_attention", "sliding_attention"),
                  router_scoring: str = "sigmoid", expert_activation: str = "silu",
                  route_before_operator: bool = False, attention_gate: bool = False,
-                 output_norms: bool = False, embedding_scale: float = None, kv_norm_eps: float = None):
+                 output_norms: bool = False, embedding_scale: float = None, kv_norm_eps: float = None,
+                 sparse=None, lightning_heads: int = None, lightning_head_dim: int = None,
+                 lightning_chunk: int = 128, published_layers: int = None, heads_held=None,
+                 kv_heads_held=None, lightning_heads_held=None, ffn_held=None,
+                 residual_scale: float = None, head_input_scale: float = None):
         from .attention import LatentAttention, MultiheadAttention
         from .moe import MoE
 
-        attention_kinds = ("full_attention", "global_attention", "sliding_attention")
-        unknown = sorted(set(layer_types) - {"conv", "kda", "mla", *attention_kinds})
+        attention_kinds = ("full_attention", "global_attention", "sliding_attention", "sparse_attention")
+        unknown = sorted(set(layer_types) - {"conv", "kda", "mla", "lightning", *attention_kinds})
         if unknown:
-            raise ValueError(
-                f"layer_types may hold 'conv', 'kda' and 'mla' or an attention kind {attention_kinds}, got {unknown}")
+            raise ValueError(f"layer_types may hold 'conv', 'lightning', 'kda' and 'mla' or an attention "
+                             f"kind {attention_kinds}, got {unknown}")
         if "sliding_attention" in layer_types and window is None:
             raise ValueError("a 'sliding_attention' layer needs window=")
+        if "sparse_attention" in layer_types and sparse is None:
+            raise ValueError("a 'sparse_attention' layer needs sparse=")
+        n_heads, n_kv_heads = num_heads, num_kv_heads
+        if heads_held is not None or kv_heads_held is not None:
+            kv_total = num_heads if num_kv_heads is None else num_kv_heads
+            heads_held = range(num_heads) if heads_held is None else heads_held
+            kv_heads_held = range(kv_total) if kv_heads_held is None else kv_heads_held
+            group = num_heads // kv_total
+            if (kv_heads_held.stop > kv_total or kv_heads_held.step != 1
+                    or heads_held != range(kv_heads_held.start * group, kv_heads_held.stop * group)):
+                raise ValueError("heads_held must be the query heads of the groups kv_heads_held names")
+            n_heads, n_kv_heads = len(heads_held), len(kv_heads_held)
+        l_held = range(lightning_heads or 0) if lightning_heads_held is None else lightning_heads_held
+        ffn_width = ffn_dim if ffn_held is None else len(ffn_held)
 
         def attention(kind):
             return lambda: MultiheadAttention(
-                embed_dim, num_heads, bias=False, rope=kind in rope_kinds, rope_base=rope_base,
-                rope_pairing="half", num_kv_heads=num_kv_heads, qk_norm=qk_norm, qk_norm_eps=norm_eps,
+                embed_dim, n_heads, bias=False, rope=kind in rope_kinds, rope_base=rope_base,
+                rope_pairing="half", num_kv_heads=n_kv_heads, qk_norm=qk_norm, qk_norm_eps=norm_eps,
                 head_dim=head_dim, window=window if kind == "sliding_attention" else None,
-                gate=attention_gate)
+                gate=attention_gate, sparse=sparse if kind == "sparse_attention" else None)
+
+        def lightning(layer):
+            return LightningAttention(
+                embed_dim, len(l_held), lightning_head_dim, layer=layer,
+                layers=published_layers or len(layer_types), total_heads=lightning_heads,
+                head_offset=l_held.start, qk_norm=qk_norm, rope="lightning" in rope_kinds,
+                rope_base=rope_base, eps=norm_eps, chunk=lightning_chunk)
 
         n_dense = len(layer_types) if num_dense_layers is None or not num_experts else num_dense_layers
         self.vocab_size, self.embed_dim = vocab_size, embed_dim
         self.layer_types = tuple(layer_types)
         self.init_std, self.bias_std, self.dtype = init_std, bias_std, dtype
-        self.embedding_scale = embedding_scale
+        self.embedding_scale, self.head_input_scale = embedding_scale, head_input_scale
         self.embed = nn.Embedding(vocab_size, embed_dim)
         self.head = None if tie_embedding else nn.Linear(embed_dim, vocab_size, bias=False)
         operators = {
@@ -1341,15 +1406,16 @@ class PatternLM(nn.Module):
         }
         self.blocks = []
         for i, kind in enumerate(self.layer_types):
-            ffn = nn.SwiGLU(embed_dim, ffn_dim) if i < n_dense else MoE(
+            ffn = nn.SwiGLU(embed_dim, ffn_width) if i < n_dense else MoE(
                 embed_dim, num_experts, hidden_dim=expert_dim, top_k=experts_per_token,
                 gated=True, scoring=router_scoring, expert_bias=router_scoring == "sigmoid",
                 norm_topk=norm_topk, routed_scaling=routed_scaling, dispatch="sorted",
                 experts_held=experts_held, shared_dim=shared_expert_dim, rows_bound=expert_rows_bound,
                 activation=expert_activation)
-            self.blocks.append(_PatternBlock(embed_dim, operators[kind](), ffn, norm_eps,
+            operator = lightning(i) if kind == "lightning" else operators[kind]()
+            self.blocks.append(_PatternBlock(embed_dim, operator, ffn, norm_eps,
                                              route_on_input=route_before_operator,
-                                             output_norms=output_norms))
+                                             output_norms=output_norms, residual_scale=residual_scale))
         self.norm = nn.RMSNorm(embed_dim, eps=norm_eps)
         self._remat_fns = [{} for _ in self.blocks]
 
@@ -1437,9 +1503,13 @@ class PatternLM(nn.Module):
 
         h, head, stats = self._states(params, tokens, train)
         with jax.named_scope("ht.lm.head_loss"):
-            h = self.norm.apply(params["norm"], h)
-            logits = h @ head.T
+            logits = self._head_input(params, h) @ head.T
         return logits, stats
+
+    def _head_input(self, params, h):
+        """The final norm, times ``head_input_scale`` where there is one."""
+        h = self.norm.apply(params["norm"], h)
+        return h if self.head_input_scale is None else h * self.head_input_scale
 
     def next_token_loss(self, params, tokens, *, train: bool = False, key=None, block_rows: int = 8192):
         """``(mean next-token cross-entropy of tokens (B, S), stats)``: what
@@ -1453,5 +1523,5 @@ class PatternLM(nn.Module):
 
         h, head, stats = self._states(params, tokens, train)
         loss = next_token_cross_entropy_by_rows(
-            h, head, tokens, norm=lambda rows: self.norm.apply(params["norm"], rows), block_rows=block_rows)
+            h, head, tokens, norm=lambda rows: self._head_input(params, rows), block_rows=block_rows)
         return loss, stats

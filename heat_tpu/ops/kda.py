@@ -423,27 +423,29 @@ def _chunk_first(tree, lead: int):
     return jax.tree.map(lambda t: jnp.moveaxis(t, lead, 0), tree)
 
 
-def _recur(parts, dtype):
-    """``(o by chunk, final state, state at the start of each chunk)``."""
+def _recur(parts, dtype, chunk_step=_step):
+    """``(o by chunk, final state, state at the start of each chunk)``;
+    ``chunk_step`` is one chunk of the recurrence (another operator's, whose
+    parts hold ``q``'s width first and ``v``'s fourth, as these do)."""
     lead = parts[0].ndim - 3
     shape = parts[0].shape[:lead] + (parts[0].shape[-1], parts[3].shape[-1])
 
     def step(state, chunk_parts):
-        new, o = _step(state, chunk_parts, dtype)
+        new, o = chunk_step(state, chunk_parts, dtype)
         return new, (o, state)
 
     final, (o, starts) = jax.lax.scan(step, jnp.zeros(shape, jnp.float32), _chunk_first(parts, lead))
     return jnp.moveaxis(o, 0, lead), final, starts
 
 
-def _recur_transposed(parts, starts, d_o, d_final, dtype):
+def _recur_transposed(parts, starts, d_o, d_final, dtype, chunk_step=_step):
     """Cotangents of ``parts``: the recurrence backwards, a chunk at a time,
     each chunk's step recomputed from the state it started with."""
     lead = parts[0].ndim - 3
 
     def back(d_state, xs):
         chunk_parts, start, d_out = xs
-        _, pull = jax.vjp(lambda s, p: _step(s, p, dtype), start, chunk_parts)
+        _, pull = jax.vjp(lambda s, p: chunk_step(s, p, dtype), start, chunk_parts)
         return pull((d_state, d_out))
 
     _, d_parts = jax.lax.scan(

@@ -116,6 +116,43 @@ def test_kda_kernels_lower_at_the_training_cell_shape(monkeypatch, dtype):
     assert exported.mlir_module().count("tpu_custom_call") >= 3
 
 
+def test_sparse_attention_kernels_lower_at_the_training_cell_shape(monkeypatch):
+    """``minicpm_sala_9b_train_1x32k``: one sequence, 16 query heads over one
+    key/value head of 128, S = 32,768, bfloat16, the selection as
+    ``sparse_attention`` makes it: the forward kernel and the one-sweep
+    backward kernel, with the unions' lengths as scalar-prefetch operands."""
+    sa = importlib.import_module("heat_tpu.ops.sparse_attention")
+    monkeypatch.setattr(sa, "platform_of", lambda q: "tpu")  # the kernels, not their interpreter
+    q = jax.ShapeDtypeStruct((1, 16, 32768, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.bfloat16)
+    before = dict(sa.path_counts)
+
+    def f(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(sa.sparse_attention(*a)[0].astype(jnp.float32)),
+                                  argnums=(0, 1, 2))(q, k, v)
+
+    exported = jax.export.export(jax.jit(f), platforms=["tpu"])(q, kv, kv)
+    assert exported.mlir_module().count("tpu_custom_call") == 2
+    assert sa.path_counts["pallas"] == before["pallas"] + 1
+
+
+def test_lightning_kernels_lower_at_the_training_cell_shape(monkeypatch):
+    """The same cell's lightning layers: 16 heads of 128, S = 32,768, chunk
+    128, bfloat16: the chunk-local forward and backward kernels (the state's
+    scan is XLA's)."""
+    la = importlib.import_module("heat_tpu.ops.lightning")
+    monkeypatch.setattr(la, "platform_of", lambda q: "tpu")
+    wide = jax.ShapeDtypeStruct((1, 16, 32768, 128), jnp.bfloat16)
+    assert la._pallas_gate(wide, wide, 128) == 4
+
+    def f(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(la.lightning_attention(
+            *a, -jnp.linspace(0.01, 0.8, 16)).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    exported = jax.export.export(jax.jit(f), platforms=["tpu"])(wide, wide, wide)
+    assert exported.mlir_module().count("tpu_custom_call") >= 2
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_kda_convolution_kernels_lower_at_the_training_cell_shape(monkeypatch, dtype):
     """``kimi_linear_48b_a3b_train_2x8k``: a layer's ``qkv`` (2, 8192, 3 x 32

@@ -16,6 +16,7 @@ import pytest
 import heat_tpu as ht
 from heat_tpu.cluster.kmeans import KMeans
 from heat_tpu.nn.models import PatternLM
+from heat_tpu.ops.sparse_attention import SparseConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -51,6 +52,17 @@ PATTERNS = {
                           expert_rows_bound=64, kv_rank=24, qk_nope_dim=16, qk_shared_dim=8, v_dim=16,
                           rope_kinds=("mla",), rope_base=5e4, kv_norm_eps=1e-6, **EXPERTS),
         {"ht.attention.proj", "ht.attention.rope", "ht.attention", "ht.mlp", "ht.moe.shared"}),
+    "sparse_lightning_held_shares_scaled": (
+        lambda: PatternLM(96, 64, ["sparse_attention", "lightning", "lightning"], num_heads=4, num_kv_heads=2,
+                          head_dim=16, ffn_dim=96, num_dense_layers=2, experts_per_token=2, expert_dim=48,
+                          rope_kinds=("lightning",), attention_gate=True, tie_embedding=False,
+                          sparse=SparseConfig(kernel_size=4, kernel_stride=2, block_size=8, window_size=8,
+                                              topk=2, dense_len=16),
+                          lightning_heads=4, lightning_head_dim=16, lightning_chunk=16, heads_held=range(2, 4),
+                          kv_heads_held=range(1, 2), lightning_heads_held=range(2, 4), ffn_held=range(0, 48),
+                          residual_scale=0.25, head_input_scale=0.5, embedding_scale=12.0, **EXPERTS),
+        {"ht.attention.proj", "ht.attention", "ht.attention.gate", "ht.sparse_attention.select",
+         "ht.sparse_attention", "ht.lightning", "ht.lightning.gate", "ht.mlp"}),
 }
 EVERY_PATTERN = {"ht.lm.cast", "ht.lm.embed", "ht.lm.block", "ht.lm.norm", "ht.lm.head_loss",
                  "ht.moe.route", "ht.moe.dispatch", "ht.moe.experts", "ht.moe.combine", "ht.optim.update"}
